@@ -6,19 +6,17 @@ reference docs/quick_start.md:94-108, BASELINE.md).  The server is the
 in-process tpuserver HTTP frontend with the `simple` add/sub model; the
 driver is this framework's C++ perf_analyzer (built on the raw-socket
 client library) — a full wire round-trip per request over a real socket,
-measured with the reference's stability-window methodology.  Falls back to
-the Python client loop when the native toolchain is unavailable.
+measured with the reference's stability-window methodology.  A native
+build or run failure fails the bench: the Python client loop is a
+different measurement and is never reported under this metric's name.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", "device"}.
 """
 
 import json
 import os
-import shutil
-import statistics
 import subprocess
 import sys
-import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(REPO, "src", "python"))
@@ -28,40 +26,30 @@ BASELINE_P50_USEC = 690  # reference quick_start.md:96
 
 
 def _build_cc():
-    if shutil.which("cmake") is None or shutil.which("ninja") is None:
-        return None
+    """Build the native perf_analyzer; any failure raises."""
     build = os.path.join(REPO, "build", "cc")
-    try:
-        subprocess.run(
-            ["cmake", "-S", os.path.join(REPO, "src", "c++"), "-B", build,
-             "-G", "Ninja"],
-            check=True, capture_output=True, timeout=300,
-        )
-        subprocess.run(
-            ["ninja", "-C", build, "perf_analyzer"],
-            check=True, capture_output=True, timeout=600,
-        )
-    except Exception:
-        return None
-    path = os.path.join(build, "perf_analyzer")
-    return path if os.path.exists(path) else None
+    subprocess.run(
+        ["cmake", "-S", os.path.join(REPO, "src", "c++"), "-B", build,
+         "-G", "Ninja"],
+        check=True, capture_output=True, timeout=300,
+    )
+    subprocess.run(
+        ["ninja", "-C", build, "perf_analyzer"],
+        check=True, capture_output=True, timeout=600,
+    )
+    return os.path.join(build, "perf_analyzer")
 
 
 def _native_once(perf_analyzer, url, window_ms):
-    """One perf_analyzer run; returns (infer/sec, p50_usec) or None."""
+    """One perf_analyzer run; returns (infer/sec, p50_usec)."""
     csv_path = os.path.join(REPO, "build", "bench_simple.csv")
-    result = subprocess.run(
+    subprocess.run(
         [perf_analyzer, "-m", "simple", "-u", url, "-p", str(window_ms),
          "--max-trials", "10", "-f", csv_path],
-        capture_output=True, text=True, timeout=180,
+        check=True, capture_output=True, text=True, timeout=180,
     )
-    if result.returncode != 0:
-        return None
     with open(csv_path) as f:
-        lines = f.read().strip().splitlines()
-    if len(lines) < 2:
-        return None
-    cols = lines[1].split(",")
+        cols = f.read().strip().splitlines()[1].split(",")
     return float(cols[1]), float(cols[9])
 
 
@@ -73,75 +61,25 @@ def _bench_native(perf_analyzer, url):
     shared host; the reported figure is the median of 5 independent
     measurements with 3 s windows, after one discarded warmup run.
     """
-    if _native_once(perf_analyzer, url, 1000) is None:  # warmup/smoke
-        return None
-    runs = []
-    for _ in range(5):
-        r = _native_once(perf_analyzer, url, 3000)
-        if r is not None:
-            runs.append(r)
-    if len(runs) < 3:
-        return None
+    _native_once(perf_analyzer, url, 1000)  # warmup
+    runs = [_native_once(perf_analyzer, url, 3000) for _ in range(5)]
     rates = sorted(r[0] for r in runs)
     p50s = sorted(r[1] for r in runs)
     return rates[len(rates) // 2], p50s[len(p50s) // 2]
 
 
-def _bench_python(url):
-    import numpy as np
-
-    import tritonclient.http as httpclient
-
-    client = httpclient.InferenceServerClient(url)
-    in0 = httpclient.InferInput("INPUT0", [1, 16], "INT32")
-    in1 = httpclient.InferInput("INPUT1", [1, 16], "INT32")
-    a = np.arange(16, dtype=np.int32).reshape(1, 16)
-    b = np.ones((1, 16), dtype=np.int32)
-    in0.set_data_from_numpy(a)
-    in1.set_data_from_numpy(b)
-    outputs = [
-        httpclient.InferRequestedOutput("OUTPUT0", binary_data=True),
-        httpclient.InferRequestedOutput("OUTPUT1", binary_data=True),
-    ]
-    for _ in range(100):
-        result = client.infer("simple", [in0, in1], outputs=outputs)
-    assert (result.as_numpy("OUTPUT0") == a + b).all()
-    rates = []
-    lat = []
-    for _ in range(5):
-        n = 0
-        t0 = time.perf_counter()
-        while True:
-            t1 = time.perf_counter()
-            client.infer("simple", [in0, in1], outputs=outputs)
-            lat.append(time.perf_counter() - t1)
-            n += 1
-            dt = time.perf_counter() - t0
-            if dt >= 1.5:
-                break
-        rates.append(n / dt)
-    client.close()
-    lat.sort()
-    p50_usec = lat[len(lat) // 2] * 1e6
-    return statistics.median(rates), p50_usec
-
-
 def main():
+    import tpuserver
     from tpuserver.core import InferenceServer
     from tpuserver.http_frontend import HttpFrontend
     from tpuserver.models import default_models
 
+    device = tpuserver.require_tpu()
     core = InferenceServer(default_models())
     frontend = HttpFrontend(core, port=0).start()
     url = frontend.url.replace("http://", "")
     try:
-        measured = None
-        perf_analyzer = _build_cc()
-        if perf_analyzer is not None:
-            measured = _bench_native(perf_analyzer, url)
-        if measured is None:
-            measured = _bench_python(url)
-        value, p50_usec = measured
+        value, p50_usec = _bench_native(_build_cc(), url)
         print(
             json.dumps(
                 {
@@ -151,6 +89,10 @@ def main():
                     "vs_baseline": round(value / BASELINE_INFER_PER_SEC, 4),
                     "p50_usec": round(p50_usec, 1),
                     "p50_vs_baseline": round(p50_usec / BASELINE_P50_USEC, 4),
+                    "device": {
+                        "platform": device.platform,
+                        "kind": device.device_kind,
+                    },
                 }
             )
         )

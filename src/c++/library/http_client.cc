@@ -1390,7 +1390,19 @@ InferenceServerHttpClient::Infer(
   if (!err.IsOk()) {
     return err;
   }
+  return SendInfer(
+      result, options, std::move(body), header_length, timer,
+      request_compression_algorithm, response_compression_algorithm);
+}
 
+Error
+InferenceServerHttpClient::SendInfer(
+    InferResult** result, const InferOptions& options,
+    std::vector<uint8_t> body, size_t header_length, RequestTimers timer,
+    const std::string& request_compression_algorithm,
+    const std::string& response_compression_algorithm)
+{
+  Error err;
   std::string extra_headers;
   if (!request_compression_algorithm.empty()) {
     if (request_compression_algorithm != "gzip" &&
@@ -1478,11 +1490,19 @@ InferenceServerHttpClient::AsyncInfer(
   if (callback == nullptr) {
     return Error("callback must not be null for AsyncInfer");
   }
-  // Inputs reference user buffers; per the API contract (same as the
-  // reference) the caller must keep them alive until the callback fires.
+  // The body is serialized here, on the caller's thread, as the
+  // reference does: the inputs' read cursors are not safe to walk from
+  // several workers at once when requests share an InferInput.
+  RequestTimers timer;
+  timer.CaptureTimestamp(RequestTimers::Kind::REQUEST_START);
+  std::vector<uint8_t> body;
+  size_t header_length;
+  Error build_err = GenerateRequestBody(
+      &body, &header_length, options, inputs, outputs);
+  if (!build_err.IsOk()) {
+    return build_err;
+  }
   InferOptions opts = options;
-  std::vector<InferInput*> ins = inputs;
-  std::vector<const InferRequestedOutput*> outs = outputs;
   std::string req_comp = request_compression_algorithm;
   std::string resp_comp = response_compression_algorithm;
   {
@@ -1490,10 +1510,13 @@ InferenceServerHttpClient::AsyncInfer(
     if (exiting_) {
       return Error("client is shutting down");
     }
-    async_queue_.emplace_back([this, callback, opts, ins, outs, req_comp,
-                               resp_comp] {
+    async_queue_.emplace_back([this, callback, opts,
+                               body = std::move(body), header_length,
+                               timer, req_comp, resp_comp]() mutable {
       InferResult* result = nullptr;
-      Error err = Infer(&result, opts, ins, outs, req_comp, resp_comp);
+      Error err = SendInfer(
+          &result, opts, std::move(body), header_length, timer, req_comp,
+          resp_comp);
       if (!err.IsOk() && result == nullptr) {
         // surface transport failure through a result-less sentinel: the
         // reference delivers a result whose RequestStatus is the error

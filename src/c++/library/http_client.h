@@ -157,6 +157,14 @@ class InferenceServerHttpClient : public InferenceServerClient {
       size_t* response_header_length, uint64_t timeout_us,
       const std::string& extra_headers = "",
       std::string* response_content_encoding = nullptr);
+  // The send half of Infer. AsyncInfer serializes the body on the
+  // caller's thread (an InferInput carries a read cursor, and one input
+  // may be shared by several in-flight requests); its workers only send.
+  Error SendInfer(
+      InferResult** result, const InferOptions& options,
+      std::vector<uint8_t> body, size_t header_length, RequestTimers timer,
+      const std::string& request_compression_algorithm,
+      const std::string& response_compression_algorithm);
 
   std::string host_;
   int port_;

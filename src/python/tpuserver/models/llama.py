@@ -673,10 +673,9 @@ def prefill(params, cache, tokens, cfg):
 def decode_chunk(params, cache, logits, pos, cfg, chunk):
     """Greedy-decode ``chunk`` tokens in ONE device dispatch.
 
-    Steady-state decode is dispatch-latency-bound when the host is far
-    from the chip (each per-token round trip costs a full host<->device
-    hop); scanning a fixed chunk of argmax+decode_step pairs inside one
-    jitted call amortizes that hop over ``chunk`` tokens.  Greedy
+    A per-token dispatch pays the host's dispatch+fetch cost once per
+    token; scanning a fixed chunk of argmax+decode_step pairs inside one
+    jitted call amortizes it over ``chunk`` tokens.  Greedy
     sampling keeps the result bit-identical to per-token decode.
 
     logits: [B, vocab] for the NEXT position (from prefill or the prior

@@ -68,10 +68,10 @@ class LlamaGenerateModel(Model):
         TensorSpec("LOGPROB", "FP32", [1]),
     )
 
-    # tokens greedy-decoded per device dispatch: the steady state is
-    # dispatch-latency-bound on remote chips, so a scanned chunk
-    # amortizes the host<->device hop over several tokens (each token
-    # still streams as its own decoupled response)
+    # tokens greedy-decoded per device dispatch on the single-stream
+    # path: a scanned chunk amortizes the per-dispatch host cost over
+    # several tokens (each token still streams as its own decoupled
+    # response)
     decode_chunk = 8
 
     def __init__(self, cfg=None, max_seq=512, server=None,
@@ -164,7 +164,9 @@ class LlamaGenerateModel(Model):
                     # quantize-on-load: init + quantize on HOST so the
                     # bf16 weights never exist in HBM — the point for
                     # the 8B preset, whose 16 GB of bf16 exceeds a v5e
-                    # chip but whose ~8 GB int8 form fits
+                    # chip but whose ~8 GB int8 form fits.  Needs the
+                    # cpu backend BESIDE the chip: JAX_PLATFORMS unset
+                    # or "tpu,cpu" (plain "tpu" raises here)
                     cpu = jax.devices("cpu")[0]
                     with jax.default_device(cpu):
                         params = llama.quantize_params(
@@ -497,8 +499,8 @@ class LlamaGenerateModel(Model):
         # Software-pipelined emission: decode chunks are CHAINED on
         # device (each consumes the previous dispatch's logits/cache
         # futures), so the device→host fetch of chunk i overlaps chunk
-        # i+1's compute — a remote chip's dispatch/fence round trip is
-        # paid once, not per chunk.  The first token is fetched straight
+        # i+1's compute — the dispatch/fence round trip is paid once,
+        # not per chunk.  The first token is fetched straight
         # from the prefill logits (a tiny argmax dispatched BEFORE the
         # first chunk), so time-to-first-token is prefill + one round
         # trip instead of prefill + a whole chunk.
@@ -566,8 +568,8 @@ class LlamaGenerateModel(Model):
             if isinstance(tokens_res, np.ndarray):
                 tokens_host, logps_host = tokens_res, logps_res
             else:
-                # one device->host transfer for both arrays: on remote
-                # chips each fetch costs a full round trip
+                # one device->host transfer for both arrays: each
+                # fetch is a sync
                 tokens_all, logps_all = jax.device_get(
                     (tokens_res, logps_res))
                 start = 1 if skip_first else 0

@@ -10,9 +10,13 @@ steps; accumulation is fp32 (MXU-native via preferred_element_type)
 regardless of input dtype, and causal query blocks skip fully-masked
 K/V blocks via predication.
 
-On non-TPU backends the kernel runs in interpret mode (same math,
-Python-level execution) so tests pin it against the dense reference on
-the CPU mesh; on TPU it compiles through Mosaic.
+Kernel mode (Mosaic or the Pallas interpreter) is decided in one place,
+:func:`kernel_interpret`: a process states it with
+:func:`set_kernel_mode` (chip entry points state Mosaic through
+``tpuserver.require_tpu``; an AOT pre-flight lowering for a TPU topology
+from a CPU process must state it too), and only a process that stated
+nothing gets the mode of its default backend — interpret off-TPU, so
+tests pin the kernels against the dense reference on the CPU mesh.
 """
 
 import functools
@@ -21,6 +25,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# the process-wide kernel mode: None = not stated (follow the default
+# backend); True/False = stated by set_kernel_mode
+_stated_interpret = None
+
+
+def set_kernel_mode(interpret):
+    """State the Pallas kernel mode for this process: ``False`` =
+    Mosaic (a non-TPU backend then fails to lower instead of quietly
+    interpreting), ``True`` = the interpreter, ``None`` = follow the
+    default backend again.  Kernels read it at trace time, so state it
+    before the first trace."""
+    global _stated_interpret
+    _stated_interpret = interpret
+
+
+def kernel_interpret(interpret=None):
+    """Whether a kernel traced now runs in the Pallas interpreter: the
+    call's own ``interpret`` argument if given, else the mode the
+    process stated, else by default backend."""
+    if interpret is not None:
+        return interpret
+    if _stated_interpret is not None:
+        return _stated_interpret
+    return jax.default_backend() != "tpu"
 
 
 def _online_softmax_fold(s, m_scr, l_scr, acc_scr, pv):
@@ -113,12 +142,11 @@ def flash_attention(
 
     Drop-in for the XLA attention paths; T must be divisible by
     ``block_q`` and ``block_k`` (pick smaller blocks for short or odd
-    sequences).  ``interpret=None`` auto-selects interpret mode off-TPU.
+    sequences).  ``interpret=None`` defers to :func:`kernel_interpret`.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = kernel_interpret(interpret)
     b, t, h, d = q.shape
     t_kv = k.shape[1]
     block_q = min(block_q, t)
@@ -218,8 +246,7 @@ def decode_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = kernel_interpret(interpret)
     b, h, d = q.shape
     s = k_cache.shape[1]
     h_kv = k_cache.shape[2]

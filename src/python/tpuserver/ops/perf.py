@@ -35,16 +35,26 @@ CHIP_SPECS = {
 
 
 def chip_spec(device=None):
-    """Spec for ``device`` (default: jax's first device), or None when
-    the platform isn't a known TPU (CPU test meshes)."""
+    """Spec for ``device`` (default: jax's first device).  ``None`` on
+    the CPU (test meshes have no peaks to report against); an
+    accelerator whose ``device_kind`` is not in ``CHIP_SPECS`` raises —
+    a row computed against a guessed peak is worse than no row."""
     import jax
 
     if device is None:
-        devices = jax.devices()
-        if not devices:
-            return None
-        device = devices[0]
-    return CHIP_SPECS.get(getattr(device, "device_kind", ""))
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    try:
+        return CHIP_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            "no peak FLOP/s / bandwidth entry for {} device kind {!r}; "
+            "add it to tpuserver.ops.perf.CHIP_SPECS with its source "
+            "(known: {})".format(
+                device.platform, device.device_kind,
+                ", ".join(sorted(CHIP_SPECS)))
+        ) from None
 
 
 def param_count(cfg):

@@ -34,8 +34,6 @@ import numpy as np  # noqa: E402
 
 import tpuserver  # noqa: E402
 
-tpuserver.enable_compile_cache(os.path.join(REPO, ".jax_cache"))
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
@@ -107,11 +105,13 @@ def main():
     ap.add_argument("--decode-only", action="store_true")
     args = ap.parse_args()
 
+    tpuserver.enable_compile_cache()
+    spec = perf.chip_spec(tpuserver.require_tpu())
     base = getattr(llama, args.config)()
-    spec = perf.chip_spec()
     params = llama.init_params(jax.random.PRNGKey(0), base)
     jax.block_until_ready(params)
     pf = perf.prefill_flops(base, args.t)
+    failed = 0  # arms that raised: reported as rows, and in the exit code
 
     if not args.decode_only:
         # decomposition arm: attention replaced by identity (patched
@@ -147,17 +147,17 @@ def main():
                     cfg, params, args.t, args.max_seq, args.rounds,
                     seed0=1000 * (i + 1))
             except Exception as e:  # noqa: BLE001 — report arm failures
+                failed += 1
                 print(json.dumps({
                     "phase": "prefill", "arm": name,
                     "error": str(e)[:200]}), flush=True)
                 continue
             finally:
                 ops_mod.flash_attention = real_flash
-            mfu = perf.mfu(pf, dt, spec) if spec else None
             print(json.dumps({
                 "phase": "prefill", "config": args.config, "T": args.t,
                 "arm": name, "ms": round(dt * 1e3, 2),
-                "mfu": round(mfu, 4) if mfu is not None else None,
+                "mfu": round(perf.mfu(pf, dt, spec), 4),
             }), flush=True)
 
     if not args.prefill_only:
@@ -176,6 +176,7 @@ def main():
                                 seed0=hash((wname, impl, chunk, ctx))
                                 % 100000)
                         except Exception as e:  # noqa: BLE001
+                            failed += 1
                             print(json.dumps({
                                 "phase": "decode", "arm": impl,
                                 "weights": wname, "chunk": chunk,
@@ -184,18 +185,16 @@ def main():
                             continue
                         bpt = perf.decode_bytes_per_token(
                             base, ctx_mid, weight_bytes_per_param=wbytes)
-                        mbu = (
-                            perf.mbu(bpt * rate, 1.0, spec)
-                            if spec else None
-                        )
+                        mbu = perf.mbu(bpt * rate, 1.0, spec)
                         print(json.dumps({
                             "phase": "decode", "config": args.config,
                             "weights": wname, "impl": impl,
                             "chunk": chunk, "ctx": ctx_mid,
                             "tokens_per_sec": round(rate, 1),
-                            "mbu": round(mbu, 4) if mbu else None,
+                            "mbu": round(mbu, 4),
                         }), flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

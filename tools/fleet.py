@@ -28,6 +28,11 @@ fleet.  Signal dispositions split with it:
 The hidden ``--serve-replica`` mode is the replica entry point the
 supervisor spawns: one InferenceServer + HttpFrontend on ``--port``
 with ``install_sigterm_drain`` installed, exiting once drained.
+
+Every replica is its own JAX process, and a chip belongs to one process
+at a time: this is a CPU tier (the replica model is the ``tiny`` llama)
+until one process drives the replicas of a host (ROADMAP R6).  The
+platform comes from the environment — run it with ``JAX_PLATFORMS=cpu``.
 """
 
 import argparse
@@ -46,9 +51,12 @@ def build_models(names, slots, spec_tokens=0):
 
     models = []
     if "llama" in names:
+        import tpuserver
         from tpuserver.models import llama
         from tpuserver.models.llama_serving import LlamaGenerateModel
 
+        # only a replica that compiles needs the cache (and jax at all)
+        tpuserver.enable_compile_cache()
         models.append(LlamaGenerateModel(
             cfg=llama.tiny(vocab=512), max_seq=64, max_slots=slots,
             restart_backoff_s=0.01, spec_tokens=spec_tokens))
@@ -63,7 +71,6 @@ def serve_replica(args):
     """Child mode: one replica server process.  SIGTERM drains first
     (in-flight generations finish, the prober rotates the replica out)
     and the process exits once the server reaches ``stopped``."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from tpuserver.core import InferenceServer, install_sigterm_drain
     from tpuserver.http_frontend import HttpFrontend
 

@@ -210,9 +210,12 @@ def build_inprocess_core(args, levels):
     from tpuserver.core import InferenceServer
 
     if args.generation or args.model == "llama_generate":
+        import tpuserver
         from tpuserver.models import llama
         from tpuserver.models.llama_serving import LlamaGenerateModel
 
+        # the one in-process profile that compiles anything
+        tpuserver.enable_compile_cache()
         slots = args.llama_slots or max(levels)
         need = (args.shared_prefix_tokens + args.prompt_len
                 + args.max_tokens + 8)
@@ -639,7 +642,6 @@ def main(argv=None):
 
     core = None
     if args.backend == "inprocess":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         core = build_inprocess_core(args, levels)
     backend = create_backend(
         args.backend,
