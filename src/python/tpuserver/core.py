@@ -1082,6 +1082,9 @@ class InferenceServer:
         # other stats value is a count or 0/1 flag)
         float_families = {"tpu_spec_accept_per_step"}
         samples = {name: [] for name in per_family}
+        # the decode loop's seconds by phase: the one family with a
+        # second label, float like every *_seconds
+        loop_seconds = samples["tpu_scheduler_loop_seconds_total"] = []
         for model_name, model in items:
             stats_fn = getattr(model, "scheduler_stats", None)
             stats = stats_fn() if callable(stats_fn) else None
@@ -1093,6 +1096,10 @@ class InferenceServer:
                     ({"model": model_name},
                      float(val) if fam_name in float_families
                      else int(val)))
+            loop_seconds.extend(
+                ({"model": model_name, "phase": phase}, float(seconds))
+                for phase, seconds in (stats.get("loop_seconds")
+                                       or {}).items())
         families.extend(
             (name, rows) for name, rows in samples.items() if rows)
         return families

@@ -14,6 +14,7 @@ import numpy as np
 
 import grpc
 
+from tpuserver._trace import span
 from tpuserver.core import (
     InferRequest,
     RequestedOutput,
@@ -433,8 +434,10 @@ class _CoreBridge:
                 for resp in self._core.infer_stream(core_request):
                     if cancelled.is_set() or not context.is_active():
                         break  # stop generating for a gone client
-                    if not emit(pb.ModelStreamInferResponse(
-                            infer_response=self._response_to_proto(resp))):
+                    with span("frontend.emit"):
+                        sent = emit(pb.ModelStreamInferResponse(
+                            infer_response=self._response_to_proto(resp)))
+                    if not sent:
                         break
             except ServerError as e:
                 emit(pb.ModelStreamInferResponse(error_message=str(e)))

@@ -23,6 +23,7 @@ from urllib.parse import unquote
 import numpy as np
 
 from tpuserver._http_base import BaseHttpHandler, ClientGone
+from tpuserver._trace import span
 from tpuserver.tensor_io import (
     array_from_binary as _array_from_binary,
     binary_from_array as _binary_from_array,
@@ -379,30 +380,31 @@ class _Handler(BaseHttpHandler):
 
         try:
             for resp in core.infer_stream(request):
-                self._ensure_started()
-                payload = response_json(resp)
-                event = b""
-                if resp.parameters:
-                    wire = {k: v for k, v in resp.parameters.items()
-                            if not k.startswith("triton_")}
-                    if wire:
-                        payload["parameters"] = wire
-                    gen_id = resp.parameters.get("generation_id")
-                    seq = resp.parameters.get("seq")
-                    if gen_id is not None and seq is not None:
-                        # the SSE id the browser/client hands back as
-                        # Last-Event-ID on reconnect
-                        event += "id: {}/{}\n".format(
-                            gen_id, seq).encode("utf-8")
-                # chaos hook: sever the connection mid-stream (no
-                # terminal chunk) so client auto-resume is drivable
-                # end-to-end; skip=N drops after the Nth event
-                _faults.fire("http.generate_stream", core.fault_scope)
-                self._send_chunk(
-                    event + b"data: "
-                    + json.dumps(payload).encode("utf-8")
-                    + b"\n\n"
-                )
+                with span("frontend.emit"):
+                    self._ensure_started()
+                    payload = response_json(resp)
+                    event = b""
+                    if resp.parameters:
+                        wire = {k: v for k, v in resp.parameters.items()
+                                if not k.startswith("triton_")}
+                        if wire:
+                            payload["parameters"] = wire
+                        gen_id = resp.parameters.get("generation_id")
+                        seq = resp.parameters.get("seq")
+                        if gen_id is not None and seq is not None:
+                            # the SSE id the browser/client hands back
+                            # as Last-Event-ID on reconnect
+                            event += "id: {}/{}\n".format(
+                                gen_id, seq).encode("utf-8")
+                    # chaos hook: sever the connection mid-stream (no
+                    # terminal chunk) so client auto-resume is drivable
+                    # end-to-end; skip=N drops after the Nth event
+                    _faults.fire("http.generate_stream", core.fault_scope)
+                    self._send_chunk(
+                        event + b"data: "
+                        + json.dumps(payload).encode("utf-8")
+                        + b"\n\n"
+                    )
         except _faults.FaultInjected:
             try:
                 self.connection.close()

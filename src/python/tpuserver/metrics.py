@@ -119,11 +119,33 @@ CATALOG = {
         "gauge", "Generations waiting for a slot, per model."),
     "tpu_scheduler_queue_wait_seconds": (
         "histogram",
-        "Time from submit to slot admission (the scheduler queue "
-        "bucket), per model, seconds."),
+        "Time from submit to admission complete (slot and pages "
+        "reserved, prefill and admit dispatched), per model, seconds: "
+        "the wait for the decode loop plus the host cost of the "
+        "admission itself (tpu_scheduler_admit_seconds)."),
     "tpu_scheduler_step_seconds": (
         "histogram",
         "Batched decode-step dispatch latency, per model, seconds."),
+    "tpu_scheduler_admit_seconds": (
+        "histogram",
+        "Host time of one piece of admission work on the decode loop "
+        "(one start_admission or one prefill chunk: page reservation, "
+        "radix match, dispatch of prefill and admit), entry to return, "
+        "shed or not, per model, seconds.  It holds the loop and every "
+        "stream on it."),
+    "tpu_scheduler_first_token_seconds": (
+        "histogram",
+        "Server-side time to first token: submit to the stream's first "
+        "token put on its queue, once per fresh generation (resumes, "
+        "replays and re-admissions after a restart do not observe), "
+        "per model, seconds."),
+    "tpu_scheduler_loop_seconds_total": (
+        "counter",
+        "Seconds of the decode loop thread's life by phase (idle / "
+        "sweep / admit / dispatch / fetch / deliver), per model.  The "
+        "phases tile the thread's time: they sum to its wall time, "
+        "fetch is the host waiting for the device, and all but idle "
+        "and fetch are host work."),
     "tpu_scheduler_codel_sheds_total": (
         "counter",
         "Admissions shed by the adaptive (CoDel-style) queue "

@@ -251,6 +251,16 @@ def _flash_blocks(T, cfg):
     return bq, bk
 
 
+def named_partial(fn, **kwargs):
+    """``functools.partial`` that keeps ``fn``'s name.  ``jax.jit`` names
+    an executable after its function's ``__name__`` and a bare partial
+    has none, so a profile's ``XLA Modules`` line (and the host's
+    ``PjitFunction`` span) would read ``jit__unknown`` for every one."""
+    bound = functools.partial(fn, **kwargs)
+    bound.__name__ = fn.__name__
+    return bound
+
+
 def _mm(x, w):
     """Matmul against a plain or int8-quantized weight leaf."""
     from tpuserver.ops import quant
@@ -261,10 +271,11 @@ def _mm(x, w):
 def _embed_rows(params, tokens, cfg=None):
     from tpuserver.ops import quant
 
-    return quant.gather_rows(
-        params["embed"], tokens,
-        dtype=cfg.dtype if cfg is not None else None,
-    )
+    with jax.named_scope("embed"):
+        return quant.gather_rows(
+            params["embed"], tokens,
+            dtype=cfg.dtype if cfg is not None else None,
+        )
 
 
 def _rms_norm(x, w, eps):
@@ -311,17 +322,22 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
     nh = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     red = reduce if reduce is not None else (lambda y: y)
-    h = _rms_norm(x, params["attn_norm"], cfg.norm_eps)
-    q = _mm(h, params["wq"]).reshape(B, T, nh, hd)
-    k = _mm(h, params["wk"]).reshape(B, T, nkv, hd)
-    v = _mm(h, params["wv"]).reshape(B, T, nkv, hd)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn.qkv"):
+        h = _rms_norm(x, params["attn_norm"], cfg.norm_eps)
+        q = _mm(h, params["wq"]).reshape(B, T, nh, hd)
+        k = _mm(h, params["wk"]).reshape(B, T, nkv, hd)
+        v = _mm(h, params["wv"]).reshape(B, T, nkv, hd)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    # the caller's closure: attn.kv_write / attn.page_gather / attn.kernel
     attn = attn_fn(q, k, v)
-    x = x + red(_mm(attn.reshape(B, T, nh * hd), params["wo"]))
-    h = _rms_norm(x, params["mlp_norm"], cfg.norm_eps)
-    gated = jax.nn.silu(_mm(h, params["w_gate"])) * _mm(h, params["w_up"])
-    return x + red(_mm(gated, params["w_down"]))
+    with jax.named_scope("attn.out"):
+        x = x + red(_mm(attn.reshape(B, T, nh * hd), params["wo"]))
+    with jax.named_scope("ffn"):
+        h = _rms_norm(x, params["mlp_norm"], cfg.norm_eps)
+        gated = (jax.nn.silu(_mm(h, params["w_gate"]))
+                 * _mm(h, params["w_up"]))
+        return x + red(_mm(gated, params["w_down"]))
 
 
 def forward(params, tokens, cfg):
@@ -544,72 +560,74 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg):
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
             nonlocal new_cache
-            new_cache = new_cache.at[i, 0].set(
-                lax.dynamic_update_slice_in_dim(
-                    new_cache[i, 0], k.astype(new_cache.dtype), write_pos,
-                    axis=1,
+            with jax.named_scope("attn.kv_write"):
+                new_cache = new_cache.at[i, 0].set(
+                    lax.dynamic_update_slice_in_dim(
+                        new_cache[i, 0], k.astype(new_cache.dtype),
+                        write_pos, axis=1,
+                    )
                 )
-            )
-            new_cache = new_cache.at[i, 1].set(
-                lax.dynamic_update_slice_in_dim(
-                    new_cache[i, 1], v.astype(new_cache.dtype), write_pos,
-                    axis=1,
+                new_cache = new_cache.at[i, 1].set(
+                    lax.dynamic_update_slice_in_dim(
+                        new_cache[i, 1], v.astype(new_cache.dtype),
+                        write_pos, axis=1,
+                    )
                 )
-            )
-            max_seq = cache.shape[3]
-            pallas_block = next(
-                (b for b in (256, 128) if max_seq % b == 0), None
-            )
-            impl = cfg.decode_impl
-            if impl == "auto" and q.shape[1] == 1:
-                impl = _select_decode_impl(max_seq, lengths)
-            if (
-                impl == "pallas"
-                and q.shape[1] == 1
-                and pallas_block is not None
-            ):
-                # the serving hot op: hand-tiled single-query decode
-                # attention (GQA expansion stays in VMEM, dead cache
-                # tail blocks never stream from HBM).  Equivalent mask:
-                # with q_pos == lengths-1, "k > q_pos" == "k >= lengths".
-                # max_seq without a tileable block falls through to the
-                # dense path (like the prefill gate above) instead of
-                # erroring at trace time.
-                from tpuserver.ops import decode_attention
+            with jax.named_scope("attn.kernel"):
+                max_seq = cache.shape[3]
+                pallas_block = next(
+                    (b for b in (256, 128) if max_seq % b == 0), None
+                )
+                impl = cfg.decode_impl
+                if impl == "auto" and q.shape[1] == 1:
+                    impl = _select_decode_impl(max_seq, lengths)
+                if (
+                    impl == "pallas"
+                    and q.shape[1] == 1
+                    and pallas_block is not None
+                ):
+                    # the serving hot op: hand-tiled single-query decode
+                    # attention (GQA expansion stays in VMEM, dead cache
+                    # tail blocks never stream from HBM).  Equivalent mask:
+                    # with q_pos == lengths-1, "k > q_pos" == "k >= lengths".
+                    # max_seq without a tileable block falls through to the
+                    # dense path (like the prefill gate above) instead of
+                    # erroring at trace time.
+                    from tpuserver.ops import decode_attention
 
-                out = decode_attention(
-                    q[:, 0],
-                    new_cache[i, 0],
-                    new_cache[i, 1],
-                    jnp.full((q.shape[0],), lengths, jnp.int32),
-                    block_k=pallas_block,
-                )
-                return out[:, None]
-            pf_bq, pf_bk = _flash_blocks(q.shape[1], cfg)
-            if (
-                cfg.attn_impl == "pallas"
-                and q.shape[1] > 1
-                and pf_bq is not None
-                and pf_bk is not None
-                and isinstance(write_pos, int)
-                and write_pos == 0
-            ):
-                # prefill from position 0: the cached attention is
-                # exactly causal self-attention over the prompt, so the
-                # flash kernel applies (K/V still land in the cache via
-                # the updates above).  Only MXU-tileable lengths — the
-                # TPU lowering needs (8, 128)-aligned blocks, so odd
-                # prompt lengths take the dense path.
-                from tpuserver.ops import flash_attention
+                    out = decode_attention(
+                        q[:, 0],
+                        new_cache[i, 0],
+                        new_cache[i, 1],
+                        jnp.full((q.shape[0],), lengths, jnp.int32),
+                        block_k=pallas_block,
+                    )
+                    return out[:, None]
+                pf_bq, pf_bk = _flash_blocks(q.shape[1], cfg)
+                if (
+                    cfg.attn_impl == "pallas"
+                    and q.shape[1] > 1
+                    and pf_bq is not None
+                    and pf_bk is not None
+                    and isinstance(write_pos, int)
+                    and write_pos == 0
+                ):
+                    # prefill from position 0: the cached attention is
+                    # exactly causal self-attention over the prompt, so the
+                    # flash kernel applies (K/V still land in the cache via
+                    # the updates above).  Only MXU-tileable lengths — the
+                    # TPU lowering needs (8, 128)-aligned blocks, so odd
+                    # prompt lengths take the dense path.
+                    from tpuserver.ops import flash_attention
 
-                return flash_attention(
-                    q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
-                    causal=True, block_q=pf_bq, block_k=pf_bk,
+                    return flash_attention(
+                        q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
+                        causal=True, block_q=pf_bq, block_k=pf_bk,
+                    )
+                return _attend_cached(
+                    q, new_cache[i, 0], new_cache[i, 1], positions, lengths,
+                    n_rep,
                 )
-            return _attend_cached(
-                q, new_cache[i, 0], new_cache[i, 1], positions, lengths,
-                n_rep,
-            )
 
         x = _block(layer, x, positions, cfg, attn_fn)
     return x, new_cache
@@ -650,8 +668,9 @@ def decode_step(params, cache, tokens, pos, cfg):
     x, new_cache = _run_cached(
         params, cache, x, positions, pos, pos + 1, cfg
     )
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -665,8 +684,9 @@ def prefill(params, cache, tokens, cfg):
     positions = jnp.tile(jnp.arange(T)[None, :], (B, 1))
     x = _embed_rows(params, tokens, cfg)
     x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg)
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = _mm(x[:, -1, :], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = _mm(x[:, -1, :], params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -685,10 +705,11 @@ def decode_chunk(params, cache, logits, pos, cfg, chunk):
 
     def body(carry, _):
         logits, cache, pos = carry
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        tok_logp = jnp.take_along_axis(
-            logp, token[:, None], axis=-1)[:, 0]
+        with jax.named_scope("sample"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok_logp = jnp.take_along_axis(
+                logp, token[:, None], axis=-1)[:, 0]
         next_logits, cache = decode_step(params, cache, token, pos, cfg)
         return (next_logits, cache, pos + 1), (token, tok_logp)
 
@@ -743,9 +764,10 @@ def prefill_to_length(params, cache, tokens, true_len, cfg):
     positions = jnp.tile(jnp.arange(T)[None, :], (B, 1))
     x = _embed_rows(params, tokens, cfg)
     x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg)
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    last = lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)[:, 0]
-    logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        last = lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)[:, 0]
+        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -790,32 +812,36 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
             nonlocal new_cache
-            new_cache = new_cache.at[i, 0, rows, positions].set(
-                k[:, 0].astype(new_cache.dtype), mode="drop"
-            )
-            new_cache = new_cache.at[i, 1, rows, positions].set(
-                v[:, 0].astype(new_cache.dtype), mode="drop"
-            )
-            if impl == "pallas" and pallas_block is not None:
-                # the decode-attention kernel already takes per-row
-                # lengths — continuous batching is its natural shape
-                from tpuserver.ops import decode_attention
-
-                out = decode_attention(
-                    q[:, 0],
-                    new_cache[i, 0],
-                    new_cache[i, 1],
-                    lengths.astype(jnp.int32),
-                    block_k=pallas_block,
+            with jax.named_scope("attn.kv_write"):
+                new_cache = new_cache.at[i, 0, rows, positions].set(
+                    k[:, 0].astype(new_cache.dtype), mode="drop"
                 )
-                return out[:, None]
-            return _attend_cached(
-                q, new_cache[i, 0], new_cache[i, 1], q_pos, lengths, n_rep
-            )
+                new_cache = new_cache.at[i, 1, rows, positions].set(
+                    v[:, 0].astype(new_cache.dtype), mode="drop"
+                )
+            with jax.named_scope("attn.kernel"):
+                if impl == "pallas" and pallas_block is not None:
+                    # the decode-attention kernel already takes per-row
+                    # lengths — continuous batching is its natural shape
+                    from tpuserver.ops import decode_attention
+
+                    out = decode_attention(
+                        q[:, 0],
+                        new_cache[i, 0],
+                        new_cache[i, 1],
+                        lengths.astype(jnp.int32),
+                        block_k=pallas_block,
+                    )
+                    return out[:, None]
+                return _attend_cached(
+                    q, new_cache[i, 0], new_cache[i, 1], q_pos, lengths,
+                    n_rep,
+                )
 
         x = _block(layer, x, q_pos, cfg, attn_fn)
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -834,10 +860,12 @@ def scheduler_step(params, cache, logits_all, positions, active,
 
     Returns (tokens [S], logprobs [S], next logits [S, vocab], cache).
     """
-    logp = jax.nn.log_softmax(logits_all, axis=-1)
-    greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(forced_mask, forced, greedy)
-    tok_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
+    with jax.named_scope("sample"):
+        logp = jax.nn.log_softmax(logits_all, axis=-1)
+        greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
+        tokens = jnp.where(forced_mask, forced, greedy)
+        tok_logp = jnp.take_along_axis(
+            logp, tokens[:, None], axis=-1)[:, 0]
     new_logits, new_cache = batched_decode_step(
         params, cache, tokens, positions, cfg
     )
@@ -932,30 +960,35 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
             nonlocal new_pages
-            new_pages = new_pages.at[i, 0, phys, offs].set(
-                k[:, 0].astype(new_pages.dtype), mode="drop"
-            )
-            new_pages = new_pages.at[i, 1, phys, offs].set(
-                v[:, 0].astype(new_pages.dtype), mode="drop"
-            )
-            tail = new_pages.shape[4:]
-            k_seq = new_pages[i, 0][tbl].reshape(S, max_seq, *tail)
-            v_seq = new_pages[i, 1][tbl].reshape(S, max_seq, *tail)
-            if impl == "pallas" and pallas_block is not None:
-                # the gathered view is a standard contiguous cache:
-                # the decode-attention kernel applies unchanged
-                from tpuserver.ops import decode_attention
-
-                out = decode_attention(
-                    q[:, 0], k_seq, v_seq, lengths.astype(jnp.int32),
-                    block_k=pallas_block,
+            with jax.named_scope("attn.kv_write"):
+                new_pages = new_pages.at[i, 0, phys, offs].set(
+                    k[:, 0].astype(new_pages.dtype), mode="drop"
                 )
-                return out[:, None]
-            return _attend_cached(q, k_seq, v_seq, q_pos, lengths, n_rep)
+                new_pages = new_pages.at[i, 1, phys, offs].set(
+                    v[:, 0].astype(new_pages.dtype), mode="drop"
+                )
+            with jax.named_scope("attn.page_gather"):
+                tail = new_pages.shape[4:]
+                k_seq = new_pages[i, 0][tbl].reshape(S, max_seq, *tail)
+                v_seq = new_pages[i, 1][tbl].reshape(S, max_seq, *tail)
+            with jax.named_scope("attn.kernel"):
+                if impl == "pallas" and pallas_block is not None:
+                    # the gathered view is a standard contiguous cache:
+                    # the decode-attention kernel applies unchanged
+                    from tpuserver.ops import decode_attention
+
+                    out = decode_attention(
+                        q[:, 0], k_seq, v_seq, lengths.astype(jnp.int32),
+                        block_k=pallas_block,
+                    )
+                    return out[:, None]
+                return _attend_cached(
+                    q, k_seq, v_seq, q_pos, lengths, n_rep)
 
         x = _block(layer, x, q_pos, cfg, attn_fn)
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
     return logits, new_pages
 
 
@@ -965,10 +998,12 @@ def paged_scheduler_step(params, pages, logits_all, page_tables,
     token per row, then one :func:`paged_batched_decode_step`.  Same
     sampling math as the slotted form — the page indirection changes
     where K/V bytes live, never what they are."""
-    logp = jax.nn.log_softmax(logits_all, axis=-1)
-    greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
-    tokens = jnp.where(forced_mask, forced, greedy)
-    tok_logp = jnp.take_along_axis(logp, tokens[:, None], axis=-1)[:, 0]
+    with jax.named_scope("sample"):
+        logp = jax.nn.log_softmax(logits_all, axis=-1)
+        greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
+        tokens = jnp.where(forced_mask, forced, greedy)
+        tok_logp = jnp.take_along_axis(
+            logp, tokens[:, None], axis=-1)[:, 0]
     new_logits, new_pages = paged_batched_decode_step(
         params, pages, tokens, page_tables, positions, cfg
     )
@@ -1019,10 +1054,11 @@ def paged_spec_step(params, pages, logits_all, page_tables, positions,
     S, K = draft.shape
     page = pages.shape[3]
     max_seq = page_tables.shape[1] * page
-    logp = jax.nn.log_softmax(logits_all, axis=-1)
-    greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
-    t0 = jnp.where(forced_mask, forced, greedy)
-    lp0 = jnp.take_along_axis(logp, t0[:, None], axis=-1)[:, 0]
+    with jax.named_scope("sample"):
+        logp = jax.nn.log_softmax(logits_all, axis=-1)
+        greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
+        t0 = jnp.where(forced_mask, forced, greedy)
+        lp0 = jnp.take_along_axis(logp, t0[:, None], axis=-1)[:, 0]
     cur, new_pages = paged_batched_decode_step(
         params, pages, t0, page_tables, positions, cfg
     )
@@ -1033,12 +1069,13 @@ def paged_spec_step(params, pages, logits_all, page_tables, positions,
     for j in range(1, K + 1):
         cand = draft[:, j - 1]
         fed = j <= draft_len
-        logp_j = jax.nn.log_softmax(cur, axis=-1)
-        g = jnp.argmax(cur, axis=-1).astype(jnp.int32)
-        matches.append((g == cand) & fed)
-        lps.append(
-            jnp.take_along_axis(logp_j, cand[:, None], axis=-1)[:, 0]
-        )
+        with jax.named_scope("sample"):
+            logp_j = jax.nn.log_softmax(cur, axis=-1)
+            g = jnp.argmax(cur, axis=-1).astype(jnp.int32)
+            matches.append((g == cand) & fed)
+            lps.append(
+                jnp.take_along_axis(logp_j, cand[:, None], axis=-1)[:, 0]
+            )
         toks.append(cand)
         pos_j = jnp.where(fed, positions + j, max_seq)
         cur, new_pages = paged_batched_decode_step(
@@ -1130,9 +1167,10 @@ def prefill_span(params, cache, tokens, start, logits_at, cfg):
     x, new_cache = _run_cached(
         params, cache, x, positions, start, start + T, cfg
     )
-    x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    last = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)[:, 0]
-    logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        last = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)[:, 0]
+        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -1216,18 +1254,18 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         )
     if mesh is None:
         step = jax.jit(
-            functools.partial(paged_scheduler_step, cfg=cfg),
+            named_partial(paged_scheduler_step, cfg=cfg),
             donate_argnums=(1, 2),
         )
         spec_step = jax.jit(
-            functools.partial(paged_spec_step, cfg=cfg),
+            named_partial(paged_spec_step, cfg=cfg),
             donate_argnums=(1, 2),
         )
         admit = jax.jit(paged_admit, donate_argnums=(0, 1))
         gather = jax.jit(paged_gather)
-        prefill_fn = jax.jit(functools.partial(prefill_to_length, cfg=cfg))
+        prefill_fn = jax.jit(named_partial(prefill_to_length, cfg=cfg))
         prefill_span_fn = jax.jit(
-            functools.partial(prefill_span, cfg=cfg),
+            named_partial(prefill_span, cfg=cfg),
         )
 
         def init_cache():
@@ -1244,14 +1282,14 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             mesh, cfg, quantized=quantized
         )
         step = jax.jit(
-            functools.partial(paged_scheduler_step, cfg=cfg),
+            named_partial(paged_scheduler_step, cfg=cfg),
             in_shardings=(param_sh, cache_sh, repl, repl, repl, repl,
                           repl, repl),
             out_shardings=(repl, repl, repl, cache_sh),
             donate_argnums=(1, 2),
         )
         spec_step = jax.jit(
-            functools.partial(paged_spec_step, cfg=cfg),
+            named_partial(paged_spec_step, cfg=cfg),
             in_shardings=(param_sh, cache_sh, repl, repl, repl, repl,
                           repl, repl, repl, repl),
             out_shardings=(repl, repl, repl, repl, cache_sh),
@@ -1269,12 +1307,12 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             out_shardings=cache_sh,
         )
         prefill_fn = jax.jit(
-            functools.partial(prefill_to_length, cfg=cfg),
+            named_partial(prefill_to_length, cfg=cfg),
             in_shardings=(param_sh, cache_sh, repl, repl),
             out_shardings=(repl, cache_sh),
         )
         prefill_span_fn = jax.jit(
-            functools.partial(prefill_span, cfg=cfg),
+            named_partial(prefill_span, cfg=cfg),
             in_shardings=(param_sh, cache_sh, repl, repl, repl),
             out_shardings=(repl, cache_sh),
         )
@@ -1350,12 +1388,12 @@ def make_tp_serving(mesh, cfg, chunk=8, donate=True, quantized=False):
     )
 
     prefill_fn = jax.jit(
-        functools.partial(prefill, cfg=cfg),
+        named_partial(prefill, cfg=cfg),
         in_shardings=(param_sh, cache_sh, repl),
         out_shardings=(repl, cache_sh),
     )
     decode_fn = jax.jit(
-        functools.partial(decode_chunk, cfg=cfg, chunk=chunk),
+        named_partial(decode_chunk, cfg=cfg, chunk=chunk),
         in_shardings=(param_sh, cache_sh, repl, repl),
         out_shardings=(repl, repl, repl, cache_sh),
         donate_argnums=(1,) if donate else (),
@@ -1392,7 +1430,7 @@ def make_tp_step(mesh, cfg, donate=True, quantized=False):
         mesh, cfg, quantized=quantized
     )
     return jax.jit(
-        functools.partial(decode_step, cfg=cfg),
+        named_partial(decode_step, cfg=cfg),
         in_shardings=(param_sh, cache_sh, repl, repl),
         out_shardings=(repl, cache_sh),
         donate_argnums=(1,) if donate else (),

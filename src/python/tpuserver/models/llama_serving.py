@@ -156,8 +156,6 @@ class LlamaGenerateModel(Model):
             return
         with self._lock:
             if self._params is None:
-                import functools
-
                 import jax
 
                 if self._quantize:
@@ -254,15 +252,15 @@ class LlamaGenerateModel(Model):
                         self._cfg, 1, self._max_seq
                     )
                     self._prefill = jax.jit(
-                        functools.partial(llama.prefill, cfg=self._cfg)
+                        llama.named_partial(llama.prefill, cfg=self._cfg)
                     )
                     self._decode = jax.jit(
-                        functools.partial(
+                        llama.named_partial(
                             llama.decode_step, cfg=self._cfg),
                         donate_argnums=(1,),
                     )
                     self._decode_chunk = jax.jit(
-                        functools.partial(
+                        llama.named_partial(
                             llama.decode_chunk, cfg=self._cfg,
                             chunk=self.decode_chunk),
                         donate_argnums=(1,),

@@ -88,6 +88,7 @@ Self-healing (tests/test_self_healing.py, docs/resilience.md):
   same-endpoint only.
 """
 
+import contextlib
 import math
 import os
 import threading
@@ -97,6 +98,7 @@ from collections import OrderedDict, deque
 import numpy as np
 
 from tpuserver import faults
+from tpuserver._trace import span
 from tpuserver.paging import PageAllocator, RadixPrefixCache, pages_for
 from tpuserver.speculative import NgramDrafter
 
@@ -326,6 +328,53 @@ class _PrefillTask:
         self.total = len(padded)
 
 
+# The decode loop's phases; every moment of the loop thread's life
+# falls in exactly one (docs/observability.md "Tracing").
+LOOP_PHASES = ("idle", "sweep", "admit", "dispatch", "fetch", "deliver")
+
+
+class _LoopClock:
+    """The decode loop's account of its own time, by phase.
+
+    ``with phase("fetch"):`` opens the profiler span ``sched.fetch``
+    (inert unless a profiler session runs, and then on the device
+    trace's clock) and, on the way out, adds the elapsed
+    ``time.monotonic()`` to the scheduler's ``fetch`` float.  The
+    phases TILE the thread's life: a phase is charged from the moment
+    the one before it closed (the few statements between two ``with``
+    blocks belong to the later one; the loop's start-up, pool
+    allocation included, to its first ``sweep``), and a nested phase
+    (``idle`` inside ``sweep``) stops its parent's clock.  So the
+    floats sum to the thread's wall time, and the host's milliseconds
+    per step are a ratio of counters.  ONE loop thread owns a clock
+    and its floats: plain adds, no lock, like the loop's histograms.
+    """
+
+    __slots__ = ("_seconds", "_mark", "_open")
+
+    def __init__(self, seconds):
+        self._seconds = seconds  # phase -> float, this thread's to add to
+        self._mark = time.monotonic()
+        self._open = []          # enclosing phases, innermost last
+
+    def _charge(self, phase):
+        now = time.monotonic()
+        self._seconds[phase] += now - self._mark
+        self._mark = now
+
+    @contextlib.contextmanager
+    def __call__(self, phase):
+        if self._open:
+            self._charge(self._open[-1])
+        self._open.append(phase)
+        try:
+            with span("sched." + phase):
+                yield
+        finally:
+            self._open.pop()
+            self._charge(phase)
+
+
 class DecodeScheduler:
     """The per-model continuous-batching loop.
 
@@ -508,17 +557,26 @@ class DecodeScheduler:
         # lock-free (exact, and never a lock acquisition in _loop)
         self._queue_hist = None
         self._step_hist = None
+        self._admit_hist = None
+        self._first_token_hist = None
         if metrics is not None:
             labels = dict(metric_labels or {})
             names = tuple(sorted(labels))
-            self._queue_hist = metrics.histogram(
-                "tpu_scheduler_queue_wait_seconds", labelnames=names,
-                single_writer=True,
-            ).labels(**labels)
-            self._step_hist = metrics.histogram(
-                "tpu_scheduler_step_seconds", labelnames=names,
-                single_writer=True,
-            ).labels(**labels)
+
+            def loop_histogram(name):
+                return metrics.histogram(
+                    name, labelnames=names, single_writer=True,
+                ).labels(**labels)
+
+            self._queue_hist = loop_histogram(
+                "tpu_scheduler_queue_wait_seconds")
+            self._step_hist = loop_histogram("tpu_scheduler_step_seconds")
+            self._admit_hist = loop_histogram("tpu_scheduler_admit_seconds")
+            self._first_token_hist = loop_histogram(
+                "tpu_scheduler_first_token_seconds")
+        # seconds of the decode loop thread's life by phase (_LoopClock;
+        # stats()["loop_seconds"], tpu_scheduler_loop_seconds_total)
+        self._loop_seconds = dict.fromkeys(LOOP_PHASES, 0.0)
 
     # -- frontend side -----------------------------------------------------
 
@@ -580,7 +638,7 @@ class DecodeScheduler:
             # back to the prefill path (token-identical, just slower)
             stream.attach_cache = attach_cache
             stream.attach_pos = int(attach_pos)
-        with self._cond:
+        with span("sched.submit"), self._cond:
             if self._closed:
                 raise SchedulerClosed("scheduler is shut down")
             if self._tripped:
@@ -896,6 +954,7 @@ class DecodeScheduler:
                 "pages_total": pages_total,
                 "pages_free": pages_free,
                 "pages_cached": pages_cached,
+                "loop_seconds": dict(self._loop_seconds),
             }
 
     # -- supervisor --------------------------------------------------------
@@ -1171,6 +1230,12 @@ class DecodeScheduler:
     def _loop(self, slots, epoch):
         import jax.numpy as jnp
 
+        with self._cond:
+            # this loop thread's OWN copy of the totals so far: a
+            # demoted zombie that wakes keeps adding to its orphaned
+            # copy, never under its successor's feet
+            self._loop_seconds = seconds = dict(self._loop_seconds)
+        phase = _LoopClock(seconds)
         fns = self._fns
         page = fns["page_size"]
         ppseq = fns["pages_per_seq"]
@@ -1512,10 +1577,11 @@ class DecodeScheduler:
                     bucket = min(bucket, self._max_seq - shared_len)
                     padded = np.zeros((bucket,), np.int32)
                     padded[:suffix_len] = suffix
-                    slot_logits, slot_cache = fns["prefill_span"](
-                        self._params, slot_cache,
-                        jnp.asarray(padded)[None, :], shared_len,
-                        suffix_len - 1)
+                    with span("sched.prefill", prompt_len=suffix_len):
+                        slot_logits, slot_cache = fns["prefill_span"](
+                            self._params, slot_cache,
+                            jnp.asarray(padded)[None, :], shared_len,
+                            suffix_len - 1)
                     if superseded():
                         return  # demoted mid-dispatch: mutate nothing
                 else:
@@ -1538,9 +1604,11 @@ class DecodeScheduler:
                         padded = np.zeros((bucket,), np.int32)
                         padded[:suffix_len] = suffix
                         tokens_in = jnp.asarray(padded)[None, :]
-                    slot_cache = fns["init_slot_cache"]()
-                    slot_logits, slot_cache = fns["prefill"](
-                        self._params, slot_cache, tokens_in, suffix_len)
+                    with span("sched.prefill", prompt_len=suffix_len):
+                        slot_cache = fns["init_slot_cache"]()
+                        slot_logits, slot_cache = fns["prefill"](
+                            self._params, slot_cache, tokens_in,
+                            suffix_len)
                     if superseded():
                         return  # demoted mid-dispatch: mutate nothing
                 stream.pos = prefill_len
@@ -1571,9 +1639,10 @@ class DecodeScheduler:
             t = self._step_timeout_s
             self._beat(epoch, time.monotonic() + 9 * t if t else None)
             try:
-                chunk_logits, task.slot_cache = fns["prefill_span"](
-                    self._params, task.slot_cache, tok,
-                    task.start + task.done, rel)
+                with span("sched.prefill", prompt_len=n):
+                    chunk_logits, task.slot_cache = fns["prefill_span"](
+                        self._params, task.slot_cache, tok,
+                        task.start + task.done, rel)
                 if superseded():
                     return  # demoted mid-dispatch: mutate nothing
                 task.done += n
@@ -1592,6 +1661,31 @@ class DecodeScheduler:
                 clear_slot(slot)
             finally:
                 self._beat(epoch, None)
+
+        def timed_admission(fn, *args):
+            """One piece of admission work, entry to return, shed or
+            not: host time that holds the loop, and with it every
+            stream, so it has a histogram of its own (queue wait less
+            this is the wait for the loop to come round)."""
+            began = time.monotonic()
+            fn(*args)
+            if self._admit_hist is not None:
+                self._admit_hist.observe(time.monotonic() - began)
+
+        def emit(st, tok, lp):
+            """One token onto its stream's queue (``_cond`` held).  A
+            stream's first token on its first admission is the
+            server-side time to first token; resumes and re-admissions
+            after a restart (``incarnation`` > 1) restarted the stamp
+            and do not observe."""
+            if (st.emitted == 0 and st.incarnation == 1
+                    and self._first_token_hist is not None):
+                self._first_token_hist.observe(
+                    time.monotonic() - st.enqueued_at)
+            st.history.append((tok, lp))
+            st.queue.put(("tok", tok, lp))
+            st.emitted += 1
+            self._tokens_total += 1
 
         def step_chaos():
             """The ONE registered fire site (R6) for "scheduler.step":
@@ -1639,390 +1733,394 @@ class DecodeScheduler:
             clear_slot(slot)
 
         while True:
-            expired = []
-            with self._cond:
-                if self._epoch != epoch:
-                    return  # superseded by a watchdog restart
-                while (
-                    not self._closed
-                    and not self._draining
-                    and not self._pending
-                    and inflight is None
-                    and not any(s is not None for s in slots)
-                ):
-                    self._cond.wait()
+            with phase("sweep"):
+                expired = []
+                with self._cond:
                     if self._epoch != epoch:
-                        return
-                if self._closed:
-                    pending = list(self._pending)
-                    self._pending.clear()
-                    break
-                if (
-                    self._draining
-                    and not self._pending
-                    and inflight is None
-                    and not any(s is not None for s in slots)
-                ):
-                    # drain complete: every accepted generation finished;
-                    # exit cleanly so drain() sees a closed scheduler
-                    self._closed = True
-                    pending = []
-                    break
-                # reap cancelled streams first: their consumers are gone,
-                # so the slot (and its pages) free for waiting work (no
-                # park of the KV — resumable streams keep only their
-                # token history; their full pages donate to the radix
-                # cache, so the resume's re-prefill is mostly a hit)
-                for i, st in enumerate(slots):
-                    if st is not None and st.cancelled:
-                        prefilling.pop(i, None)
-                        if ready[i]:
-                            # park-export before the pages free: the
-                            # resumable stream's attach-resume rides it
-                            export_kv(st)
-                        release_pages(st)
-                        self._detach_locked(st)
-                        clear_slot(i)
-                # deadline sweep: a pending request past its deadline
-                # fails BEFORE prefill (no slot or compute is ever spent
-                # on it); an in-flight one retires mid-generation, its
-                # slot and pages freeing for waiting work this iteration
-                now = time.monotonic()
-                if self._shed_ctl is not None:
-                    # adaptive-shed sojourn signal: the head stream's
-                    # wait is the FIFO maximum, so "head under target"
-                    # means the whole queue is.  Noted inside the
-                    # already-held _cond region — the controller costs
-                    # the loop zero new lock acquisitions.
-                    self._shed_ctl.note_sojourn(
-                        (now - self._pending[0].enqueued_at)
-                        if self._pending else 0.0, now)
-                if self._pending:
-                    keep = deque()
-                    for st in self._pending:
-                        (expired if st.expired(now) else keep).append(st)
-                    self._pending = keep
-                for i, st in enumerate(slots):
-                    if st is not None and st.expired(now):
-                        expired.append(st)
-                        prefilling.pop(i, None)
-                        release_pages(st)
-                        clear_slot(i)
-                self._cond.notify_all()
-                admissions = []
-                free = [i for i, s in enumerate(slots) if s is None]
-                while self._pending and free:
-                    st = self._pending.popleft()
-                    if st.cancelled:
-                        self._detach_locked(st)
-                        continue  # abandoned while still queued
-                    slot = free.pop(0)
-                    # reserve NOW, under the lock: the cancel-reap and
-                    # the watchdog salvage must see prefilling streams
-                    # as slotted
-                    slots[slot] = st
-                    admissions.append((slot, st))
-            # deadline failures deliver OUTSIDE the lock (delivery
-            # re-takes it to retire the stream from the live registry)
-            for st in expired:
-                self._fail(st, DeadlineExceeded(
-                    "request deadline exceeded after {} emitted "
-                    "tokens".format(st.emitted)), epoch)
+                        return  # superseded by a watchdog restart
+                    while (
+                        not self._closed
+                        and not self._draining
+                        and not self._pending
+                        and inflight is None
+                        and not any(s is not None for s in slots)
+                    ):
+                        with phase("idle"):
+                            self._cond.wait()
+                        if self._epoch != epoch:
+                            return
+                    if self._closed:
+                        pending = list(self._pending)
+                        self._pending.clear()
+                        break
+                    if (
+                        self._draining
+                        and not self._pending
+                        and inflight is None
+                        and not any(s is not None for s in slots)
+                    ):
+                        # drain complete: every accepted generation finished;
+                        # exit cleanly so drain() sees a closed scheduler
+                        self._closed = True
+                        pending = []
+                        break
+                    # reap cancelled streams first: their consumers are gone,
+                    # so the slot (and its pages) free for waiting work (no
+                    # park of the KV — resumable streams keep only their
+                    # token history; their full pages donate to the radix
+                    # cache, so the resume's re-prefill is mostly a hit)
+                    for i, st in enumerate(slots):
+                        if st is not None and st.cancelled:
+                            prefilling.pop(i, None)
+                            if ready[i]:
+                                # park-export before the pages free: the
+                                # resumable stream's attach-resume rides it
+                                export_kv(st)
+                            release_pages(st)
+                            self._detach_locked(st)
+                            clear_slot(i)
+                    # deadline sweep: a pending request past its deadline
+                    # fails BEFORE prefill (no slot or compute is ever spent
+                    # on it); an in-flight one retires mid-generation, its
+                    # slot and pages freeing for waiting work this iteration
+                    now = time.monotonic()
+                    if self._shed_ctl is not None:
+                        # adaptive-shed sojourn signal: the head stream's
+                        # wait is the FIFO maximum, so "head under target"
+                        # means the whole queue is.  Noted inside the
+                        # already-held _cond region — the controller costs
+                        # the loop zero new lock acquisitions.
+                        self._shed_ctl.note_sojourn(
+                            (now - self._pending[0].enqueued_at)
+                            if self._pending else 0.0, now)
+                    if self._pending:
+                        keep = deque()
+                        for st in self._pending:
+                            (expired if st.expired(now) else keep).append(st)
+                        self._pending = keep
+                    for i, st in enumerate(slots):
+                        if st is not None and st.expired(now):
+                            expired.append(st)
+                            prefilling.pop(i, None)
+                            release_pages(st)
+                            clear_slot(i)
+                    self._cond.notify_all()
+                    admissions = []
+                    free = [i for i, s in enumerate(slots) if s is None]
+                    while self._pending and free:
+                        st = self._pending.popleft()
+                        if st.cancelled:
+                            self._detach_locked(st)
+                            continue  # abandoned while still queued
+                        slot = free.pop(0)
+                        # reserve NOW, under the lock: the cancel-reap and
+                        # the watchdog salvage must see prefilling streams
+                        # as slotted
+                        slots[slot] = st
+                        admissions.append((slot, st))
+                # deadline failures deliver OUTSIDE the lock (delivery
+                # re-takes it to retire the stream from the live registry)
+                for st in expired:
+                    self._fail(st, DeadlineExceeded(
+                        "request deadline exceeded after {} emitted "
+                        "tokens".format(st.emitted)), epoch)
             # device work runs OUTSIDE the lock: submitters must be able
             # to enqueue while the chip computes
-            for slot, stream in admissions:
-                start_admission(slot, stream)
-            if prefilling:
-                # exactly one bounded chunk per iteration: long
-                # prompts trickle in while decode keeps stepping
-                run_prefill_chunk()
+            if admissions or prefilling:
+                with phase("admit"):
+                    for slot, stream in admissions:
+                        timed_admission(start_admission, slot, stream)
+                    if prefilling:
+                        # exactly one bounded chunk per iteration: long
+                        # prompts trickle in while decode keeps stepping
+                        timed_admission(run_prefill_chunk)
 
             current = None
             active_ids = [i for i, s in enumerate(slots)
                           if s is not None and ready[i]]
             if active_ids and spec_k > 0:
-                # speculative multi-token step (ISSUE 19): draft up to
-                # spec_k candidates per slot from the radix cache, feed
-                # them all through ONE batched verify dispatch, keep
-                # the longest argmax-matching prefix plus the bonus
-                # token.  Variable per-slot advance makes the one-deep
-                # pipeline impossible (the NEXT step's positions depend
-                # on THIS step's acceptance), so the spec path
-                # dispatches and fetches in the same iteration;
-                # ``inflight`` stays None.
-                positions = np.full(
-                    (self._max_slots,), self._max_seq, np.int32)
-                active = np.zeros((self._max_slots,), bool)
-                forced_tok = np.zeros((self._max_slots,), np.int32)
-                forced_mask = np.zeros((self._max_slots,), bool)
-                draft = np.zeros((self._max_slots, spec_k), np.int32)
-                draft_len = np.zeros((self._max_slots,), np.int32)
-                snapshot = []
-                for i in active_ids:
-                    st = slots[i]
-                    positions[i] = st.pos
-                    active[i] = True
-                    was_forced = bool(st.forced)
-                    if was_forced:
-                        forced_tok[i] = st.forced.popleft()
-                        forced_mask[i] = True
-                    k_i = 0
-                    if not was_forced:
-                        if st.spec_skip > 0:
-                            # throttled: this step probes nothing
-                            st.spec_skip -= 1
-                        else:
-                            # never draft past the emission budget:
-                            # 1 bonus + k_i accepted must fit
-                            budget = min(
-                                spec_k, st.max_tokens - st.emitted - 1)
-                            if budget > 0:
-                                ctx = [int(t) for t in st.prompt]
-                                ctx.extend(t for t, _ in st.history)
-                                # the drafter's FIRST proposal predicts
-                                # this step's own next token — which the
-                                # verify step computes exactly — so it
-                                # drops and the rest feed as candidates
-                                d = drafter.draft(ctx, budget + 1)[1:]
-                                k_i = len(d)
-                                if k_i:
-                                    draft[i, :k_i] = d
-                                    draft_len[i] = k_i
-                    # pos does NOT advance at snapshot (unlike the
-                    # pipelined path): the fetch below advances it by
-                    # the tokens actually kept
-                    snapshot.append(
-                        (i, st, was_forced, st.incarnation, k_i))
-                action = step_chaos()
-                if action is not None and action[0] == "nan":
-                    row = min(max(0, action[1]), self._max_slots - 1)
-                    logits = logits.at[row].set(float("nan"))
-                step_start = time.monotonic()
-                self._beat(epoch, step_start)
-                if action is not None and action[0] == "hang":
-                    time.sleep(action[1])
-                if draft_len.any():
-                    toks_dev, lps_dev, acc_dev, logits, pages = fns[
-                        "spec_step"](
-                        self._params, pages, logits, tables, positions,
-                        active, forced_tok, forced_mask, draft,
-                        draft_len,
-                    )
-                else:
-                    # nobody drafted (cold caches, all throttled): a
-                    # plain sub-step costs spec_k fewer weight passes
-                    # and is bitwise-identical for the one token
-                    toks_dev, lps_dev, logits, pages = fns["step"](
+                with phase("dispatch"):
+                    # speculative multi-token step (ISSUE 19): draft up to
+                    # spec_k candidates per slot from the radix cache, feed
+                    # them all through ONE batched verify dispatch, keep
+                    # the longest argmax-matching prefix plus the bonus
+                    # token.  Variable per-slot advance makes the one-deep
+                    # pipeline impossible (the NEXT step's positions depend
+                    # on THIS step's acceptance), so the spec path
+                    # dispatches and fetches in the same iteration;
+                    # ``inflight`` stays None.
+                    positions = np.full(
+                        (self._max_slots,), self._max_seq, np.int32)
+                    active = np.zeros((self._max_slots,), bool)
+                    forced_tok = np.zeros((self._max_slots,), np.int32)
+                    forced_mask = np.zeros((self._max_slots,), bool)
+                    draft = np.zeros((self._max_slots, spec_k), np.int32)
+                    draft_len = np.zeros((self._max_slots,), np.int32)
+                    snapshot = []
+                    for i in active_ids:
+                        st = slots[i]
+                        positions[i] = st.pos
+                        active[i] = True
+                        was_forced = bool(st.forced)
+                        if was_forced:
+                            forced_tok[i] = st.forced.popleft()
+                            forced_mask[i] = True
+                        k_i = 0
+                        if not was_forced:
+                            if st.spec_skip > 0:
+                                # throttled: this step probes nothing
+                                st.spec_skip -= 1
+                            else:
+                                # never draft past the emission budget:
+                                # 1 bonus + k_i accepted must fit
+                                budget = min(
+                                    spec_k, st.max_tokens - st.emitted - 1)
+                                if budget > 0:
+                                    ctx = [int(t) for t in st.prompt]
+                                    ctx.extend(t for t, _ in st.history)
+                                    # the drafter's FIRST proposal predicts
+                                    # this step's own next token — which the
+                                    # verify step computes exactly — so it
+                                    # drops and the rest feed as candidates
+                                    d = drafter.draft(ctx, budget + 1)[1:]
+                                    k_i = len(d)
+                                    if k_i:
+                                        draft[i, :k_i] = d
+                                        draft_len[i] = k_i
+                        # pos does NOT advance at snapshot (unlike the
+                        # pipelined path): the fetch below advances it by
+                        # the tokens actually kept
+                        snapshot.append(
+                            (i, st, was_forced, st.incarnation, k_i))
+                    action = step_chaos()
+                    if action is not None and action[0] == "nan":
+                        row = min(max(0, action[1]), self._max_slots - 1)
+                        logits = logits.at[row].set(float("nan"))
+                    step_start = time.monotonic()
+                    self._beat(epoch, step_start)
+                    if action is not None and action[0] == "hang":
+                        time.sleep(action[1])
+                    if draft_len.any():
+                        toks_dev, lps_dev, acc_dev, logits, pages = fns[
+                            "spec_step"](
+                            self._params, pages, logits, tables, positions,
+                            active, forced_tok, forced_mask, draft,
+                            draft_len,
+                        )
+                    else:
+                        # nobody drafted (cold caches, all throttled): a
+                        # plain sub-step costs spec_k fewer weight passes
+                        # and is bitwise-identical for the one token
+                        toks_dev, lps_dev, logits, pages = fns["step"](
+                            self._params, pages, logits, tables, positions,
+                            active, forced_tok, forced_mask,
+                        )
+                        acc_dev = None
+                    self._beat(epoch, None)
+                    if self._step_hist is not None:
+                        self._step_hist.observe(
+                            time.monotonic() - step_start)
+                with phase("fetch"):
+                    # host-transfer chaos; a raise is loop death (restart)
+                    fetch_chaos()
+                    self._beat(epoch, time.monotonic())
+                    toks = np.asarray(toks_dev)
+                    lps = np.asarray(lps_dev)
+                    accs = (np.asarray(acc_dev) if acc_dev is not None else
+                            np.zeros((self._max_slots,), np.int32))
+                    self._beat(epoch, None)
+                with phase("deliver"):
+                    if toks.ndim == 1:
+                        # plain-step fallback: same emission code below,
+                        # one column, zero accepted drafts
+                        toks = toks[:, None]
+                        lps = lps[:, None]
+                    quarantined = []
+                    finished = []
+                    with self._cond:
+                        if self._epoch != epoch:
+                            return  # superseded mid-fetch: deliver nothing
+                        for i, st, was_forced, inc, k_i in snapshot:
+                            if slots[i] is not st or st.incarnation != inc:
+                                continue  # slot retired mid-step
+                            if st.cancelled:
+                                export_kv(st)
+                                release_pages(st)
+                                self._detach_locked(st)
+                                clear_slot(i)
+                                continue
+                            a = min(int(accs[i]), k_i)
+                            if k_i:
+                                self._spec_steps += 1
+                                self._spec_proposed += k_i
+                                self._spec_accepted += a
+                                if a < k_i:
+                                    self._spec_rollbacks += 1
+                                if a > 0:
+                                    st.spec_miss = 0
+                                else:
+                                    st.spec_miss += k_i
+                                    if (st.spec_miss
+                                            >= self._spec_throttle_after):
+                                        st.spec_skip = (
+                                            self._spec_probe_interval)
+                            if was_forced:
+                                st.pos += 1
+                                continue  # resumed-prompt feed, no emission
+                            fed = 0
+                            poisoned = False
+                            hit_eos = False
+                            for j in range(1 + a):
+                                tok = int(toks[i, j])
+                                lp = float(lps[i, j])
+                                if not np.isfinite(lp):
+                                    poisoned = True
+                                    break
+                                emit(st, tok, lp)
+                                fed += 1
+                                if (st.eos_id is not None
+                                        and tok == st.eos_id):
+                                    hit_eos = True
+                                    break
+                            if poisoned:
+                                # poisoned output: row-independent math, so
+                                # co-batched slots are untouched — retire
+                                # only the offender, never donating its KV
+                                quarantined.append((i, st))
+                                release_pages(st, insert=False)
+                                clear_slot(i)
+                                continue
+                            # rejected-position rollback is exactly this
+                            # cursor move: the next step re-feeds from
+                            # here, overwriting any speculative garbage
+                            # beyond it (still inside the reserved span,
+                            # and release_pages donates only up to pos —
+                            # nothing leaks or double-donates)
+                            st.pos += fed
+                            if st.emitted >= st.max_tokens or hit_eos:
+                                finished.append((st, i))
+                    for i, st in quarantined:
+                        with self._cond:
+                            self._quarantined += 1
+                        self._fail(st, SlotQuarantined(
+                            "generation produced non-finite logits after {} "
+                            "emitted tokens; its slot was quarantined (co-"
+                            "batched generations are unaffected)".format(
+                                st.emitted)), epoch)
+                    for st, i in finished:
+                        finish(st, i)
+            elif active_ids:
+                with phase("dispatch"):
+                    # sentinel position max_seq on inert rows: their cache
+                    # writes drop instead of corrupting a parked slot
+                    positions = np.full(
+                        (self._max_slots,), self._max_seq, np.int32)
+                    active = np.zeros((self._max_slots,), bool)
+                    forced_tok = np.zeros((self._max_slots,), np.int32)
+                    forced_mask = np.zeros((self._max_slots,), bool)
+                    snapshot = []
+                    for i in active_ids:
+                        st = slots[i]
+                        positions[i] = st.pos
+                        active[i] = True
+                        was_forced = bool(st.forced)
+                        if was_forced:
+                            forced_tok[i] = st.forced.popleft()
+                            forced_mask[i] = True
+                        snapshot.append((i, st, was_forced, st.incarnation))
+                        st.pos += 1
+                    # chaos hook: "scheduler.step" raise = loop death (the
+                    # supervised-restart path), sleep = slow step, nan =
+                    # poison one slot's logits row (the quarantine path),
+                    # hang = stall INSIDE the heartbeat window below so the
+                    # watchdog provably observes it.  A raise here may have
+                    # left the donated cache consumed — exactly what the
+                    # restart rebuilds.
+                    action = step_chaos()
+                    if action is not None and action[0] == "nan":
+                        row = min(max(0, action[1]), self._max_slots - 1)
+                        logits = logits.at[row].set(float("nan"))
+                    step_start = time.monotonic()
+                    self._beat(epoch, step_start)
+                    if action is not None and action[0] == "hang":
+                        time.sleep(action[1])
+                    tokens_dev, logps_dev, logits, pages = fns["step"](
                         self._params, pages, logits, tables, positions,
                         active, forced_tok, forced_mask,
                     )
-                    acc_dev = None
-                self._beat(epoch, None)
-                if self._step_hist is not None:
-                    self._step_hist.observe(
-                        time.monotonic() - step_start)
-                # host-transfer chaos; a raise is loop death (restart)
-                fetch_chaos()
-                self._beat(epoch, time.monotonic())
-                toks = np.asarray(toks_dev)
-                lps = np.asarray(lps_dev)
-                accs = (np.asarray(acc_dev) if acc_dev is not None else
-                        np.zeros((self._max_slots,), np.int32))
-                self._beat(epoch, None)
-                if toks.ndim == 1:
-                    # plain-step fallback: same emission code below,
-                    # one column, zero accepted drafts
-                    toks = toks[:, None]
-                    lps = lps[:, None]
-                quarantined = []
-                finished = []
-                with self._cond:
-                    if self._epoch != epoch:
-                        return  # superseded mid-fetch: deliver nothing
-                    for i, st, was_forced, inc, k_i in snapshot:
-                        if slots[i] is not st or st.incarnation != inc:
-                            continue  # slot retired mid-step
-                        if st.cancelled:
-                            export_kv(st)
-                            release_pages(st)
-                            self._detach_locked(st)
-                            clear_slot(i)
-                            continue
-                        a = min(int(accs[i]), k_i)
-                        if k_i:
-                            self._spec_steps += 1
-                            self._spec_proposed += k_i
-                            self._spec_accepted += a
-                            if a < k_i:
-                                self._spec_rollbacks += 1
-                            if a > 0:
-                                st.spec_miss = 0
-                            else:
-                                st.spec_miss += k_i
-                                if (st.spec_miss
-                                        >= self._spec_throttle_after):
-                                    st.spec_skip = (
-                                        self._spec_probe_interval)
-                        if was_forced:
-                            st.pos += 1
-                            continue  # resumed-prompt feed, no emission
-                        fed = 0
-                        poisoned = False
-                        hit_eos = False
-                        for j in range(1 + a):
-                            tok = int(toks[i, j])
-                            lp = float(lps[i, j])
-                            if not np.isfinite(lp):
-                                poisoned = True
-                                break
-                            st.history.append((tok, lp))
-                            st.queue.put(("tok", tok, lp))
-                            st.emitted += 1
-                            self._tokens_total += 1
-                            fed += 1
-                            if (st.eos_id is not None
-                                    and tok == st.eos_id):
-                                hit_eos = True
-                                break
-                        if poisoned:
-                            # poisoned output: row-independent math, so
-                            # co-batched slots are untouched — retire
-                            # only the offender, never donating its KV
-                            quarantined.append((i, st))
-                            release_pages(st, insert=False)
-                            clear_slot(i)
-                            continue
-                        # rejected-position rollback is exactly this
-                        # cursor move: the next step re-feeds from
-                        # here, overwriting any speculative garbage
-                        # beyond it (still inside the reserved span,
-                        # and release_pages donates only up to pos —
-                        # nothing leaks or double-donates)
-                        st.pos += fed
-                        if st.emitted >= st.max_tokens or hit_eos:
-                            finished.append((st, i))
-                for i, st in quarantined:
-                    with self._cond:
-                        self._quarantined += 1
-                    self._fail(st, SlotQuarantined(
-                        "generation produced non-finite logits after {} "
-                        "emitted tokens; its slot was quarantined (co-"
-                        "batched generations are unaffected)".format(
-                            st.emitted)), epoch)
-                for st, i in finished:
-                    finish(st, i)
-            elif active_ids:
-                # sentinel position max_seq on inert rows: their cache
-                # writes drop instead of corrupting a parked slot
-                positions = np.full(
-                    (self._max_slots,), self._max_seq, np.int32)
-                active = np.zeros((self._max_slots,), bool)
-                forced_tok = np.zeros((self._max_slots,), np.int32)
-                forced_mask = np.zeros((self._max_slots,), bool)
-                snapshot = []
-                for i in active_ids:
-                    st = slots[i]
-                    positions[i] = st.pos
-                    active[i] = True
-                    was_forced = bool(st.forced)
-                    if was_forced:
-                        forced_tok[i] = st.forced.popleft()
-                        forced_mask[i] = True
-                    snapshot.append((i, st, was_forced, st.incarnation))
-                    st.pos += 1
-                # chaos hook: "scheduler.step" raise = loop death (the
-                # supervised-restart path), sleep = slow step, nan =
-                # poison one slot's logits row (the quarantine path),
-                # hang = stall INSIDE the heartbeat window below so the
-                # watchdog provably observes it.  A raise here may have
-                # left the donated cache consumed — exactly what the
-                # restart rebuilds.
-                action = step_chaos()
-                if action is not None and action[0] == "nan":
-                    row = min(max(0, action[1]), self._max_slots - 1)
-                    logits = logits.at[row].set(float("nan"))
-                step_start = time.monotonic()
-                self._beat(epoch, step_start)
-                if action is not None and action[0] == "hang":
-                    time.sleep(action[1])
-                tokens_dev, logps_dev, logits, pages = fns["step"](
-                    self._params, pages, logits, tables, positions,
-                    active, forced_tok, forced_mask,
-                )
-                self._beat(epoch, None)
-                if self._step_hist is not None:
-                    # lock-free observe: the loop must never acquire a
-                    # lock per step just to be observable
-                    self._step_hist.observe(
-                        time.monotonic() - step_start)
-                current = (tokens_dev, logps_dev, snapshot)
+                    self._beat(epoch, None)
+                    if self._step_hist is not None:
+                        # lock-free observe: the loop must never acquire a
+                        # lock per step just to be observable
+                        self._step_hist.observe(
+                            time.monotonic() - step_start)
+                    current = (tokens_dev, logps_dev, snapshot)
 
             if inflight is not None:
                 tokens_dev, logps_dev, snapshot = inflight
-                # host-transfer chaos; a raise is loop death (restart)
-                fetch_chaos()
-                self._beat(epoch, time.monotonic())
-                toks = np.asarray(tokens_dev)
-                lps = np.asarray(logps_dev)
-                self._beat(epoch, None)
-                quarantined = []
-                finished = []
-                with self._cond:
-                    if self._epoch != epoch:
-                        return  # superseded mid-fetch: deliver nothing
-                    for i, st, was_forced, inc in snapshot:
-                        if slots[i] is not st or st.incarnation != inc:
-                            # slot retired (and possibly re-admitted —
-                            # even by the SAME stream, resumed after a
-                            # disconnect) after this step was
-                            # dispatched: its token is the one-deep
-                            # pipeline's wasted extra
-                            continue
-                        if st.cancelled:
-                            # consumer gone: free the slot (and its
-                            # pages — full ones donate to the radix
-                            # cache) AND retire the stream (parking
-                            # resumables, with their KV exported for
-                            # attach-resume)
-                            export_kv(st)
-                            release_pages(st)
-                            self._detach_locked(st)
-                            clear_slot(i)
-                            continue
-                        if was_forced:
-                            continue  # resumed-prompt feed, no emission
-                        tok = int(toks[i])
-                        lp = float(lps[i])
-                        if not np.isfinite(lp):
-                            # poisoned output: THIS slot's logits went
-                            # non-finite.  The batched step's math is
-                            # row-independent, so co-batched slots are
-                            # untouched — retire only the offender.
-                            quarantined.append((i, st))
-                            # poisoned KV must never enter the radix
-                            # cache: free without donating
-                            release_pages(st, insert=False)
-                            clear_slot(i)
-                            continue
-                        if st.emitted < st.max_tokens:
-                            st.history.append((tok, lp))
-                            st.queue.put(("tok", tok, lp))
-                            st.emitted += 1
-                            self._tokens_total += 1
-                        if st.emitted >= st.max_tokens or (
-                            st.eos_id is not None and tok == st.eos_id
-                        ):
-                            finished.append((st, i))
-                for i, st in quarantined:
+                with phase("fetch"):
+                    # host-transfer chaos; a raise is loop death (restart)
+                    fetch_chaos()
+                    self._beat(epoch, time.monotonic())
+                    toks = np.asarray(tokens_dev)
+                    lps = np.asarray(logps_dev)
+                    self._beat(epoch, None)
+                with phase("deliver"):
+                    quarantined = []
+                    finished = []
                     with self._cond:
-                        self._quarantined += 1
-                    self._fail(st, SlotQuarantined(
-                        "generation produced non-finite logits after {} "
-                        "emitted tokens; its slot was quarantined (co-"
-                        "batched generations are unaffected)".format(
-                            st.emitted)), epoch)
-                for st, i in finished:
-                    finish(st, i)
+                        if self._epoch != epoch:
+                            return  # superseded mid-fetch: deliver nothing
+                        for i, st, was_forced, inc in snapshot:
+                            if slots[i] is not st or st.incarnation != inc:
+                                # slot retired (and possibly re-admitted —
+                                # even by the SAME stream, resumed after a
+                                # disconnect) after this step was
+                                # dispatched: its token is the one-deep
+                                # pipeline's wasted extra
+                                continue
+                            if st.cancelled:
+                                # consumer gone: free the slot (and its
+                                # pages — full ones donate to the radix
+                                # cache) AND retire the stream (parking
+                                # resumables, with their KV exported for
+                                # attach-resume)
+                                export_kv(st)
+                                release_pages(st)
+                                self._detach_locked(st)
+                                clear_slot(i)
+                                continue
+                            if was_forced:
+                                continue  # resumed-prompt feed, no emission
+                            tok = int(toks[i])
+                            lp = float(lps[i])
+                            if not np.isfinite(lp):
+                                # poisoned output: THIS slot's logits went
+                                # non-finite.  The batched step's math is
+                                # row-independent, so co-batched slots are
+                                # untouched — retire only the offender.
+                                quarantined.append((i, st))
+                                # poisoned KV must never enter the radix
+                                # cache: free without donating
+                                release_pages(st, insert=False)
+                                clear_slot(i)
+                                continue
+                            if st.emitted < st.max_tokens:
+                                emit(st, tok, lp)
+                            if st.emitted >= st.max_tokens or (
+                                st.eos_id is not None and tok == st.eos_id
+                            ):
+                                finished.append((st, i))
+                    for i, st in quarantined:
+                        with self._cond:
+                            self._quarantined += 1
+                        self._fail(st, SlotQuarantined(
+                            "generation produced non-finite logits after {} "
+                            "emitted tokens; its slot was quarantined (co-"
+                            "batched generations are unaffected)".format(
+                                st.emitted)), epoch)
+                    for st, i in finished:
+                        finish(st, i)
             inflight = current
 
         # closed: fail whatever is still queued or running

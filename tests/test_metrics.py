@@ -206,6 +206,19 @@ def test_router_reserves_metrics_fleet_aggregated_with_token_counters():
                             model="llama_generate") == stats["restarts"]
         assert sample_value(rep, "tpu_scheduler_replay_hits_total",
                             model="llama_generate") == stats["replay_hits"]
+        # ... the loop's seconds by phase too (the loop is idle: its
+        # floats stand still between the scrape and the stats call)
+        assert rep_types["tpu_scheduler_loop_seconds_total"] == "counter"
+        assert stats["loop_seconds"]["fetch"] > 0
+        for phase, seconds in stats["loop_seconds"].items():
+            assert sample_value(rep, "tpu_scheduler_loop_seconds_total",
+                                model="llama_generate",
+                                phase=phase) == seconds
+        for family in ("tpu_scheduler_admit_seconds",
+                       "tpu_scheduler_first_token_seconds"):
+            check_histogram(rep, family, model="llama_generate")
+            assert sample_value(rep, family + "_count",
+                                model="llama_generate") == stats["admitted"]
 
         # router exposition: its own tier families + the replica's
         # families fleet-aggregated under their original names
