@@ -11,7 +11,9 @@ network beyond loopback, fixed seeds, random weights.  In order:
   platform   place the compile cache, initialise JAX, fail unless the
              first device is a TPU the peaks table knows
   kernels    flash_attention and decode_attention, Mosaic, at the
-             llama3_3b head geometry against float32 host references
+             llama3_3b head geometry, and paged_decode_attention at the
+             benchmark cell's (16 rows x 2560, pages of 16, 32/8 heads
+             of 128), against float32 host references
   setup      InferenceServer(default models + ResNet-50 + llama3_3b on
              the continuous-batching scheduler) behind real HTTP and
              gRPC frontends; warm-up requests carry the compiles
@@ -78,14 +80,18 @@ class Size:
     kv_heads: int
     head_dim: int
     seq: int            # kernel phase: flash T and decode cache length
+    paged: tuple        # kernel phase, paged decode: (rows, max_seq,
+                        # page, query heads) over kv_heads of head_dim
     max_seq: int        # served llama
     prompt_lens: tuple  # (flash, flash, dense) prompt lengths
     max_tokens: int
 
 
-CHIP = Size(heads=24, kv_heads=8, head_dim=128, seq=2048, max_seq=2048,
+CHIP = Size(heads=24, kv_heads=8, head_dim=128, seq=2048,
+            paged=(16, 2560, 16, 32), max_seq=2048,
             prompt_lens=(512, 1536, 200), max_tokens=32)
-DRY = Size(heads=4, kv_heads=2, head_dim=32, seq=512, max_seq=512,
+DRY = Size(heads=4, kv_heads=2, head_dim=32, seq=512,
+           paged=(10, 512, 16, 4), max_seq=512,
            prompt_lens=(128, 384, 50), max_tokens=8)
 MAX_SLOTS = 8
 
@@ -152,12 +158,13 @@ def _dense_attention_f32(q, k, v):
 
 
 def phase_kernels(size, interpret):
-    """Both Pallas kernels, called directly with the mode stated, at the
-    served head geometry, against float32 host references."""
+    """The Pallas kernels, called directly with the mode stated, at the
+    served geometries, against float32 host references."""
     import jax.numpy as jnp
     import numpy as np
 
-    from tpuserver.ops import decode_attention, flash_attention
+    from tpuserver.ops import (
+        decode_attention, flash_attention, paged_decode_attention)
 
     rng = np.random.RandomState(0)
     h, hkv, d, t = size.heads, size.kv_heads, size.head_dim, size.seq
@@ -191,12 +198,51 @@ def phase_kernels(size, interpret):
     got = np.asarray(
         decode_attention(qd, kc, vc, jnp.asarray(lengths),
                          interpret=interpret), np.float32)
-    check(np.isfinite(got).all(), "decode_attention: non-finite")
-    qf, kf, vf = (np.asarray(x, np.float32) for x in (qd, kc, vc))
+    kf, vf = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
+    _check_decode_rows(
+        "decode_attention over S={}".format(t), got, qd, lengths,
+        lambda b: (kf[b], vf[b]))
+
+    # the served decode attention: the same fold over a page pool read
+    # in place, at the benchmark cell's geometry; every row's pages are
+    # scattered over the pool, entries past a row's length are the
+    # clipped sentinel (the last page), layer 1 of 2 is attended
+    rows, seq, page, h = size.paged
+    ppseq = seq // page
+    n_pages = rows * ppseq
+    lengths = np.array(
+        ([1, page, page + 1, 255, 256, 257, seq // 2 - 24, seq - 1, seq]
+         + [int(n) for n in rng.randint(1, seq, rows)])[:rows], np.int32)
+    tables = rng.permutation(n_pages).reshape(rows, ppseq).astype(np.int32)
+    live = np.arange(ppseq)[None, :] * page < lengths[:, None]
+    tables = np.where(live, tables, n_pages - 1)
+    qd = bf16((rows, h, d))
+    pool = bf16((2, 2, n_pages, page, hkv, d))
+    got = np.asarray(
+        paged_decode_attention(qd, pool, 1, jnp.asarray(tables),
+                               jnp.asarray(lengths), interpret=interpret),
+        np.float32)
+    pf = np.asarray(pool[1], np.float32)
+    _check_decode_rows(
+        "paged_decode_attention over {} pages of {}".format(n_pages, page),
+        got, qd, lengths,
+        lambda b: (pf[0][tables[b]].reshape(seq, hkv, d),
+                   pf[1][tables[b]].reshape(seq, hkv, d)))
+
+
+def _check_decode_rows(name, got, q, lengths, row_kv):
+    """``got`` [rows, H, D] against single-query softmax attention on
+    the host in float32; ``row_kv(b)`` -> that row's K and V
+    [S, Hkv, D] float32, of which ``lengths[b]`` positions are valid."""
+    import numpy as np
+
+    check(np.isfinite(got).all(), "{}: non-finite".format(name))
+    qf = np.asarray(q, np.float32)
+    h, d = qf.shape[1:]
     worst = 0.0
     for b, n in enumerate(lengths):
-        kb = np.repeat(kf[b, :n], h // hkv, axis=1)   # [n, H, D]
-        vb = np.repeat(vf[b, :n], h // hkv, axis=1)
+        kb, vb = (np.repeat(x[:n], h // x.shape[1], axis=1)   # [n, H, D]
+                  for x in row_kv(b))
         s = np.einsum("hd,nhd->hn", qf[b], kb) / np.sqrt(d)
         p = np.exp(s - s.max(-1, keepdims=True))
         p /= p.sum(-1, keepdims=True)
@@ -204,10 +250,10 @@ def phase_kernels(size, interpret):
         worst = max(worst, float(np.abs(got[b] - want_b).max()))
         check(np.allclose(got[b], want_b, rtol=KERNEL_RTOL,
                           atol=KERNEL_ATOL),
-              "decode_attention row {} (valid {}) disagrees with the "
-              "float32 dense reference".format(b, n))
-    log("decode_attention {} rows over S={} Hkv={}: max|err| {:.4f}".format(
-        len(lengths), t, hkv, worst))
+              "{} row {} (valid {}) disagrees with the float32 dense "
+              "reference".format(name, b, n))
+    log("{}, {} rows, H={}: max|err| {:.4f}".format(
+        name, len(lengths), h, worst))
 
 
 def make_prompts(size, vocab):
@@ -480,8 +526,14 @@ def phase_mosaic(llama_model, llama_cfg, size, dry_run):
             fns["prefill"], params, slot_cache,
             jax.ShapeDtypeStruct((1, bucket), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32, weak_type=True))
-    log("tpu_custom_call counts (n_layers={}): {}".format(
-        llama_cfg.n_layers, counts))
+    log("tpu_custom_call counts (n_layers={}), decode attention {}: {}"
+        .format(llama_cfg.n_layers, fns["decode_attention"], counts))
+    # the served step reads the page pool in place; the dry run's tiny
+    # geometry resolves to dense attention over the gathered view
+    check(fns["decode_attention"]
+          == ("gather_dense" if dry_run else "paged_kernel"),
+          "the step was built with decode attention {}".format(
+              fns["decode_attention"]))
     expect = 0 if dry_run else llama_cfg.n_layers
     flash_a, flash_b, _dense = size.prompt_lens
     for name in ("step", "prefill_T{}".format(flash_a),

@@ -549,6 +549,19 @@ def _select_decode_impl(max_seq, lengths):
     return "pallas" if cross >= max_seq // 2 else "xla"
 
 
+def _decode_kernel_block(cfg, max_seq):
+    """The K/V block the batched decode steps hand the Pallas decode
+    kernel for rows of ``max_seq``, or None where they attend densely
+    (``decode_impl`` resolves to ``"xla"``, or no 128-multiple block
+    divides ``max_seq``)."""
+    impl = cfg.decode_impl
+    if impl == "auto":
+        impl = _select_decode_impl(max_seq, None)
+    if impl != "pallas":
+        return None
+    return next((b for b in (256, 128) if max_seq % b == 0), None)
+
+
 def _run_cached(params, cache, x, positions, write_pos, lengths, cfg):
     """Shared decode/prefill body: run all blocks, writing new K/V into the
     cache at ``write_pos`` and attending over cache[:lengths].
@@ -804,10 +817,7 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
     rows = jnp.arange(S)
     x = _embed_rows(params, tokens, cfg)[:, None, :]  # [S, 1, Dm]
     new_cache = cache
-    pallas_block = next((b for b in (256, 128) if max_seq % b == 0), None)
-    impl = cfg.decode_impl
-    if impl == "auto":
-        impl = _select_decode_impl(max_seq, None)
+    pallas_block = _decode_kernel_block(cfg, max_seq)
 
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
@@ -820,7 +830,7 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
                     v[:, 0].astype(new_cache.dtype), mode="drop"
                 )
             with jax.named_scope("attn.kernel"):
-                if impl == "pallas" and pallas_block is not None:
+                if pallas_block is not None:
                     # the decode-attention kernel already takes per-row
                     # lengths — continuous batching is its natural shape
                     from tpuserver.ops import decode_attention
@@ -910,6 +920,29 @@ def init_paged_kv_cache(cfg, n_pages, page_size, dtype=None):
     )
 
 
+def paged_decode_path(cfg, max_seq, page_size):
+    """Which decode attention :func:`paged_batched_decode_step` traces
+    for this geometry, and the kernel's K/V block (None without a
+    kernel).  A fact of the build, read from what the shapes allow:
+
+    - ``"paged_kernel"``: the Pallas kernel reads the pool in place
+      through the page table (``ops.paged_decode_attention``) — the
+      decode kernel is chosen, ``max_seq`` has a 128-multiple block and
+      the block holds whole pages;
+    - ``"gather_kernel"``: a block, but not of whole pages — the pages
+      gather into the contiguous view and ``ops.decode_attention`` runs
+      over it;
+    - ``"gather_dense"``: no kernel (``decode_impl`` resolves to
+      ``"xla"``, or no block divides ``max_seq``) — the gather, then
+      dense XLA attention.
+    """
+    block = _decode_kernel_block(cfg, max_seq)
+    if block is None:
+        return "gather_dense", None
+    return ("paged_kernel" if block % page_size == 0
+            else "gather_kernel"), block
+
+
 def paged_batched_decode_step(params, pages, tokens, page_tables,
                               positions, cfg):
     """:func:`batched_decode_step` over a paged pool: one decode token
@@ -920,13 +953,22 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     ``page_tables`` [S, pages_per_seq] int32 maps each row's logical
     pages to physical ids (entries may be the sentinel ``n_pages`` for
     unreserved logical pages — they are never read below the row's
-    valid length and never written).  Per layer the row's pages gather
-    into the same contiguous [S, max_seq] view the slotted step
-    attends over — identical values in identical order, so greedy
-    tokens are bitwise equal to the contiguous step's (A/B-pinned in
-    tests/test_paged_kv.py).  The gather is the CPU-sim functional
-    model of paged attention; a production TPU path would stream pages
-    inside a Pallas kernel instead of materializing the view.
+    valid length and never written).
+
+    How a row's pages reach the attention is :func:`paged_decode_path`'s
+    choice, made from the shapes at trace time.  The served path
+    (``"paged_kernel"``: every real preset) hands the kernel the pool
+    and the page table, and the kernel copies each row's LIVE pages into
+    VMEM itself (``ops.paged_decode_attention``): nothing the size of
+    the pool is read or written in HBM, and scope ``attn.kernel`` holds
+    the page reads.  The fallback paths (``tiny`` at a ``max_seq`` with
+    no 128-multiple block, ``decode_impl="xla"``) gather each layer's
+    pages into the contiguous [S, max_seq] view the slotted step attends
+    over (scope ``attn.page_gather``: two copies the size of a layer's
+    pool, fine at test sizes only).  Either way the attention sees
+    identical values in identical order, so greedy tokens are bitwise
+    equal to the contiguous step's (A/B-pinned in tests/test_paged_kv.py
+    on both paths).
 
     New K/V writes land at (``page_tables[s, positions[s] //
     page_size]``, ``positions[s] % page_size``); rows at the sentinel
@@ -950,12 +992,10 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     new_pages = pages
     # unreserved logical pages clip to a valid (arbitrary) physical
     # page: everything they contribute sits beyond the row's valid
-    # length and is masked
+    # length and is masked.  The gather would clamp by itself; the
+    # kernel's page copies would not (a DMA does not drop a wild index)
     tbl = jnp.clip(page_tables, 0, n_pages - 1)
-    pallas_block = next((b for b in (256, 128) if max_seq % b == 0), None)
-    impl = cfg.decode_impl
-    if impl == "auto":
-        impl = _select_decode_impl(max_seq, None)
+    path, pallas_block = paged_decode_path(cfg, max_seq, page)
 
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
@@ -967,12 +1007,21 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
                 new_pages = new_pages.at[i, 1, phys, offs].set(
                     v[:, 0].astype(new_pages.dtype), mode="drop"
                 )
+            if path == "paged_kernel":
+                with jax.named_scope("attn.kernel"):
+                    from tpuserver.ops import paged_decode_attention
+
+                    out = paged_decode_attention(
+                        q[:, 0], new_pages, i, tbl,
+                        lengths.astype(jnp.int32), block_k=pallas_block,
+                    )
+                    return out[:, None]
             with jax.named_scope("attn.page_gather"):
                 tail = new_pages.shape[4:]
                 k_seq = new_pages[i, 0][tbl].reshape(S, max_seq, *tail)
                 v_seq = new_pages[i, 1][tbl].reshape(S, max_seq, *tail)
             with jax.named_scope("attn.kernel"):
-                if impl == "pallas" and pallas_block is not None:
+                if path == "gather_kernel":
                     # the gathered view is a standard contiguous cache:
                     # the decode-attention kernel applies unchanged
                     from tpuserver.ops import decode_attention
@@ -1219,6 +1268,9 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
       dense chunk vs a one-shot flash pass could flip a near-tie
       greedy argmax, the same hazard :func:`prefill_bucket` guards,
       so the scheduler falls back to whole-prompt prefill there)
+    - ``decode_attention`` — which decode attention ``step`` and
+      ``spec_step`` were built with (:func:`paged_decode_path`):
+      ``"paged_kernel"``, ``"gather_kernel"`` or ``"gather_dense"``
 
     With a ``mesh`` the bundle is the GSPMD form: params
     Megatron-split, the page pool and slot cache kv-head-sharded over
@@ -1345,6 +1397,7 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "pages_per_seq": pages_per_seq,
         "n_pages": n_pages,
         "span_safe": cfg.attn_impl != "pallas",
+        "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
     }
 
 

@@ -4,4 +4,5 @@ for the rest" tier); XLA-composed fallbacks everywhere else."""
 from tpuserver.ops.flash import (  # noqa: F401
     decode_attention,
     flash_attention,
+    paged_decode_attention,
 )

@@ -70,6 +70,13 @@ def _online_softmax_fold(s, m_scr, l_scr, acc_scr, pv):
     m_scr[:, 0] = m_new
 
 
+def _fold_init(m_scr, l_scr, acc_scr):
+    """The carried state before a row's first block."""
+    m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
 def _fold_finish(o_ref, m_scr, l_scr, acc_scr):
     """Normalize the carried accumulator into the output block."""
     del m_scr
@@ -93,9 +100,7 @@ def _attn_kernel(
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _fold_init(m_scr, l_scr, acc_scr)
 
     # causal: K/V blocks wholly above the diagonal contribute nothing
     live = (
@@ -185,6 +190,32 @@ def flash_attention(
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
 
+def _decode_fold(
+    q_ref, k, v, ki, length, m_scr, l_scr, acc_scr, *, scale, block_k,
+    n_rep):
+    """Fold K/V block ``ki`` (``k``/``v`` [block_k, Hkv, D], already in
+    VMEM) of a row with ``length`` valid positions into the carried
+    softmax state.  The one body of both decode kernels: they differ
+    only in how the block got into VMEM.  GQA replication happens on
+    the in-VMEM block only."""
+    heads = q_ref.shape[0]
+    q = q_ref[:].astype(jnp.float32) * scale          # [H, D]
+    k = k.astype(jnp.float32)                         # [bk, Hkv, D]
+    v = v.astype(jnp.float32)
+    if n_rep > 1:  # GQA: expand kv heads inside VMEM only
+        k = jnp.repeat(k, n_rep, axis=1)              # [bk, H, D]
+        v = jnp.repeat(v, n_rep, axis=1)
+    # Mosaic-friendly batched vec-mat: elementwise multiply +
+    # reduce on the VPU (the head-batched dot_general does not lower)
+    s = jnp.sum(q[None, :, :] * k, axis=-1).T  # [H, bk]
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (heads, k.shape[0]), 1)
+    s = jnp.where(k_pos < length, s, -jnp.inf)
+    _online_softmax_fold(
+        s, m_scr, l_scr, acc_scr,
+        lambda p: jnp.sum(p.T[:, :, None] * v, axis=0))
+
+
 def _decode_kernel(
     len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
     block_k, n_rep):
@@ -193,39 +224,23 @@ def _decode_kernel(
     len_ref: scalar-prefetch [batch] int32 valid lengths; q_ref: [H, D]
     (every query head of this batch row); k_ref/v_ref: [block_k, Hkv, D]
     cache slices; scratch m/l: [H, 1] fp32, acc: [H, D] fp32 carried
-    across k blocks.  GQA replication happens on the in-VMEM block only.
+    across k blocks.
     """
     b = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
     length = len_ref[b]
-    heads = q_ref.shape[0]
-    block = k_ref.shape[0]
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _fold_init(m_scr, l_scr, acc_scr)
 
     # skip blocks entirely past the valid cache prefix
     @pl.when(ki * block_k < length)
     def _fold():
-        q = q_ref[:].astype(jnp.float32) * scale          # [H, D]
-        k = k_ref[:].astype(jnp.float32)                  # [bk, Hkv, D]
-        v = v_ref[:].astype(jnp.float32)
-        if n_rep > 1:  # GQA: expand kv heads inside VMEM only
-            k = jnp.repeat(k, n_rep, axis=1)              # [bk, H, D]
-            v = jnp.repeat(v, n_rep, axis=1)
-        # Mosaic-friendly batched vec-mat: elementwise multiply +
-        # reduce on the VPU (the head-batched dot_general does not lower)
-        s = jnp.sum(q[None, :, :] * k, axis=-1).T  # [H, bk]
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, block), 1)
-        s = jnp.where(k_pos < length, s, -jnp.inf)
-        _online_softmax_fold(
-            s, m_scr, l_scr, acc_scr,
-            lambda p: jnp.sum(p.T[:, :, None] * v, axis=0))
+        _decode_fold(
+            q_ref, k_ref[:], v_ref[:], ki, length, m_scr, l_scr, acc_scr,
+            scale=scale, block_k=block_k, n_rep=n_rep)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -294,3 +309,154 @@ def decode_attention(
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k_cache, v_cache)
     return out
+
+
+def _paged_decode_kernel(
+    len_ref, tbl_ref, layer_ref, q_ref, pages_ref, o_ref, k_buf, v_buf,
+    sems, slot_ref, m_scr, l_scr, acc_scr, *, scale, block_k, n_rep):
+    """One (row, k-block) program of decode attention over the page pool.
+
+    The fold is :func:`_decode_kernel`'s; only the way a K/V block gets
+    into VMEM differs.  len_ref [rows], tbl_ref [rows * pages_per_seq]
+    (row-major page table, entries in [0, n_pages)) and layer_ref [1]
+    are scalar-prefetched; pages_ref is the WHOLE pool
+    [L, 2, n_pages, page, Hkv, D], left where it lives.  k_buf/v_buf
+    [2, block_k, Hkv, D] are two VMEM slots, each filled by one DMA a
+    page; sems [2 (k, v), 2 (slot)]; slot_ref [1] SMEM says which slot
+    holds the current block.  Both grid dimensions run in order, so the
+    block after this one, the next row's first where this is the row's
+    last, is on its way while this one folds.  A row's first block is
+    always brought in (even at length 0, where nothing folds), so every
+    row has a block to wait for and the hand-over stays regular.
+    """
+    b = pl.program_id(0)
+    ki = pl.program_id(1)
+    rows = pl.num_programs(0)
+    nk = pl.num_programs(1)
+    page = pages_ref.shape[3]
+    pages_per_block = block_k // page
+    pages_per_seq = nk * pages_per_block
+    layer = layer_ref[0]
+    length = len_ref[b]
+    live_blocks = jnp.maximum(
+        jax.lax.div(length + (block_k - 1), block_k), 1)
+
+    def block_copies(row, blk, slot):
+        first = row * pages_per_seq + blk * pages_per_block
+        return [
+            pltpu.make_async_copy(
+                pages_ref.at[layer, kv, tbl_ref[first + j]],
+                buf.at[slot, pl.ds(j * page, page)],
+                sems.at[kv, slot])
+            for j in range(pages_per_block)
+            for kv, buf in ((0, k_buf), (1, v_buf))
+        ]
+
+    @pl.when(ki == 0)
+    def _init():
+        _fold_init(m_scr, l_scr, acc_scr)
+
+    @pl.when(jnp.logical_and(b == 0, ki == 0))
+    def _first():
+        slot_ref[0] = 0
+        for copy in block_copies(0, 0, 0):
+            copy.start()
+
+    @pl.when(ki < live_blocks)
+    def _block():
+        slot = slot_ref[0]
+        more = ki + 1 < live_blocks
+        nxt_row = jnp.where(more, b, b + 1)
+        nxt_blk = jnp.where(more, ki + 1, 0)
+
+        @pl.when(nxt_row < rows)
+        def _prefetch():
+            for copy in block_copies(nxt_row, nxt_blk, 1 - slot):
+                copy.start()
+
+        for copy in block_copies(b, ki, slot):
+            copy.wait()
+        slot_ref[0] = 1 - slot
+
+        @pl.when(ki * block_k < length)
+        def _fold():
+            _decode_fold(
+                q_ref, k_buf[slot], v_buf[slot], ki, length, m_scr, l_scr,
+                acc_scr, scale=scale, block_k=block_k, n_rep=n_rep)
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        _fold_finish(o_ref, m_scr, l_scr, acc_scr)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block_k", "interpret"))
+def paged_decode_attention(
+    q, pages, layer, page_tables, lengths, scale=None, block_k=256,
+    interpret=None):
+    """:func:`decode_attention` over a page pool, read in place.
+
+    q: [B, H, D]; pages: the whole pool [L, 2, n_pages, page, Hkv, D]
+    (``models.llama.init_paged_kv_cache``), of which layer ``layer``
+    (int32 scalar, may be traced) is attended; page_tables
+    [B, pages_per_seq] int32 names each row's physical pages, every
+    entry in [0, n_pages) — the kernel copies pages by id, and an id out
+    of range is a wild read, not a dropped one; lengths [B] int32 valid
+    positions.  The kernel brings each row's live blocks into VMEM page
+    by page: no gathered ``[B, S, Hkv, D]`` view and no per-layer slice
+    of the pool ever exists in HBM.  Same blocks, same order, same fold
+    as ``decode_attention`` over the gathered view: bit-equal to it.
+    Returns [B, H, D].
+    """
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    interpret = kernel_interpret(interpret)
+    b, h, d = q.shape
+    page, h_kv = pages.shape[3], pages.shape[4]
+    s = page_tables.shape[1] * page
+    if h % h_kv:
+        raise ValueError(
+            "query heads ({}) must be a multiple of kv heads ({})".format(
+                h, h_kv))
+    block_k = min(block_k, s)
+    if s % block_k or block_k % page:
+        raise ValueError(
+            "block_k {} must divide the row length {} and hold whole "
+            "pages of {}".format(block_k, s, page))
+
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, block_k=block_k,
+        n_rep=h // h_kv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, s // block_k),
+        in_specs=[
+            pl.BlockSpec((None, h, d), lambda b, ki, *refs: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, h, d), lambda b, ki, *refs: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_k, h_kv, d), pages.dtype),
+            pltpu.VMEM((2, block_k, h_kv, d), pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # the slot hand-over needs every program to run in grid order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="paged_decode_attention",
+        interpret=interpret,
+    )(
+        lengths.astype(jnp.int32),
+        page_tables.astype(jnp.int32).reshape(-1),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q, pages)

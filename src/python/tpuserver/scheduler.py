@@ -908,6 +908,7 @@ class DecodeScheduler:
         consumer (the fleet router's prober) can turn the counts into a
         utilization signal without extra configuration plumbing."""
         with self._cond:
+            fns = self._fns or {}
             pager = self._pager
             if pager is not None:
                 alloc, radix = pager
@@ -917,9 +918,7 @@ class DecodeScheduler:
             else:
                 # before the first loop start (or after close): the
                 # pool is whatever the fns bundle will build
-                fns = self._fns
-                pages_total = int(fns.get("n_pages", 0) or 0) \
-                    if fns is not None else 0
+                pages_total = int(fns.get("n_pages", 0) or 0)
                 pages_free = pages_total
                 pages_cached = 0
             return {
@@ -955,6 +954,9 @@ class DecodeScheduler:
                 "pages_free": pages_free,
                 "pages_cached": pages_cached,
                 "loop_seconds": dict(self._loop_seconds),
+                # a fact of the build, not a rate: which decode
+                # attention the step executable holds
+                "decode_attention": fns.get("decode_attention"),
             }
 
     # -- supervisor --------------------------------------------------------

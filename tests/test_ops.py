@@ -7,6 +7,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpuserver.ops import flash_attention
 
@@ -168,3 +169,131 @@ def test_decode_attention_no_gqa_short_length():
     np.testing.assert_allclose(
         np.asarray(out), _dense_decode(q, kc, vc, lengths, 1),
         rtol=2e-4, atol=2e-4)
+
+
+# -- paged decode attention: the pool read in place --------------------------
+
+
+def _paged_case(h, hkv, d, page, ppseq, lengths, dtype, seed, layers=2):
+    """A pool of ``layers`` layers, one shuffled page table row per
+    length (no physical page shared), queries to match."""
+    rng = np.random.RandomState(seed)
+    rows = len(lengths)
+    n_pages = rows * ppseq + 3
+    pool = jnp.asarray(
+        rng.randn(layers, 2, n_pages, page, hkv, d).astype(np.float32),
+        dtype)
+    tables = rng.permutation(n_pages)[:rows * ppseq].reshape(
+        rows, ppseq).astype(np.int32)
+    q = jnp.asarray(rng.randn(rows, h, d).astype(np.float32), dtype)
+    return q, pool, tables, np.asarray(lengths, np.int32)
+
+
+def _gathered(pool, layer, tables):
+    """The contiguous [rows, S, Hkv, D] K and V views of ``layer`` that
+    the fallback path materialises."""
+    rows, ppseq = tables.shape
+    tail = pool.shape[4:]
+    return (pool[layer, 0][tables].reshape(rows, -1, *tail),
+            pool[layer, 1][tables].reshape(rows, -1, *tail))
+
+
+# lengths: 1, inside a page, on a page edge, on a block edge, one past
+# it, the whole row; a 0 (nothing attended: zeros, as decode_attention)
+PAGED_CASES = {
+    "gqa_bf16": dict(h=6, hkv=2, d=16, page=16, ppseq=8, block_k=64,
+                     lengths=[1, 37, 48, 64, 65, 128], dtype="bfloat16"),
+    "no_gqa_f32": dict(h=4, hkv=4, d=8, page=8, ppseq=8, block_k=32,
+                       lengths=[1, 13, 32, 33, 64], dtype="float32"),
+    "one_block_a_row": dict(h=4, hkv=2, d=16, page=16, ppseq=4, block_k=64,
+                            lengths=[64, 1, 17], dtype="bfloat16"),
+    "block_of_one_page": dict(h=2, hkv=1, d=8, page=16, ppseq=4, block_k=16,
+                              lengths=[16, 15, 64, 1], dtype="float32"),
+    "empty_rows": dict(h=4, hkv=2, d=8, page=8, ppseq=4, block_k=16,
+                       lengths=[0, 20, 0, 32, 0], dtype="float32"),
+}
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_decode_attention_equals_the_gathered_view(case, layer):
+    """The paged kernel folds the same blocks in the same order as
+    decode_attention over the gathered view: BIT-equal to it, for
+    shuffled page tables, at every kind of length, on either layer."""
+    from tpuserver.ops import decode_attention, paged_decode_attention
+
+    c = dict(PAGED_CASES[case])
+    block_k, dtype = c.pop("block_k"), jnp.dtype(c.pop("dtype"))
+    q, pool, tables, lengths = _paged_case(dtype=dtype, seed=11, **c)
+    got = paged_decode_attention(
+        q, pool, layer, jnp.array(tables), jnp.array(lengths),
+        block_k=block_k)
+    k_seq, v_seq = _gathered(pool, layer, tables)
+    want = decode_attention(
+        q, k_seq, v_seq, jnp.array(lengths), block_k=block_k)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32))
+    live = lengths > 0
+    q, k_seq, v_seq, got = (
+        np.asarray(x, np.float32)[live] for x in (q, k_seq, v_seq, got))
+    np.testing.assert_allclose(
+        got, _dense_decode(q, k_seq, v_seq, lengths[live],
+                           c["h"] // c["hkv"]),
+        rtol=2e-2, atol=2e-2)
+
+
+def test_paged_decode_attention_never_reads_past_a_rows_live_blocks():
+    """Page-table entries past a row's length (the step clips the
+    sentinel ``n_pages`` onto the last page) change nothing: entries in
+    dead blocks are never copied, and the rest of the last live block
+    is masked."""
+    from tpuserver.ops import paged_decode_attention
+
+    q, pool, tables, lengths = _paged_case(
+        h=6, hkv=2, d=16, page=16, ppseq=8, lengths=[1, 37, 64, 100],
+        dtype=jnp.bfloat16, seed=12)
+    n_pages = pool.shape[2]
+    dead = np.arange(8)[None, :] * 16 >= lengths[:, None]
+    sentinel = np.where(dead, n_pages, tables)
+    outs = [
+        np.asarray(paged_decode_attention(
+            q, pool, 1, jnp.clip(jnp.array(tbl), 0, n_pages - 1),
+            jnp.array(lengths), block_k=64), np.float32)
+        for tbl in (tables, sentinel)]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    # dead BLOCKS are not even copied: poison their pages
+    dead_blocks = np.arange(8)[None, :] // 4 * 64 >= lengths[:, None]
+    poisoned = pool.at[:, :, tables[dead_blocks]].set(jnp.nan)
+    out = np.asarray(paged_decode_attention(
+        q, poisoned, 1, jnp.array(tables), jnp.array(lengths), block_k=64),
+        np.float32)
+    np.testing.assert_array_equal(outs[0], out)
+
+
+def test_paged_decode_attention_takes_a_traced_layer():
+    """One executable serves every layer: ``layer`` may be traced."""
+    from tpuserver.ops import paged_decode_attention
+
+    q, pool, tables, lengths = _paged_case(
+        h=4, hkv=2, d=8, page=8, ppseq=4, lengths=[9, 32],
+        dtype=jnp.float32, seed=13, layers=3)
+    fn = jax.jit(lambda layer: paged_decode_attention(
+        q, pool, layer, jnp.array(tables), jnp.array(lengths), block_k=16))
+    for layer in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(fn(jnp.int32(layer))),
+            np.asarray(paged_decode_attention(
+                q, pool, layer, jnp.array(tables), jnp.array(lengths),
+                block_k=16)))
+    assert not np.array_equal(np.asarray(fn(0)), np.asarray(fn(2)))
+
+
+def test_paged_decode_attention_refuses_a_block_of_broken_pages():
+    from tpuserver.ops import paged_decode_attention
+
+    q, pool, tables, lengths = _paged_case(
+        h=4, hkv=2, d=8, page=24, ppseq=4, lengths=[9],
+        dtype=jnp.float32, seed=14)
+    with pytest.raises(ValueError, match="whole pages"):
+        paged_decode_attention(
+            q, pool, 0, jnp.array(tables), jnp.array(lengths), block_k=32)

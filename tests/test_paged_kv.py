@@ -184,6 +184,98 @@ def test_paged_step_matches_contiguous_kernel(params):
         np.asarray(row), np.asarray(cache[:, :, 0:1]))
 
 
+def test_paged_decode_path_follows_the_shapes():
+    """Which decode attention the paged step is built with is read off
+    the configuration and the geometry, nothing else."""
+    import dataclasses
+
+    kernel = dataclasses.replace(CFG, decode_impl="pallas")
+    dense = dataclasses.replace(CFG, decode_impl="xla")
+    assert llama.paged_decode_path(kernel, 2560, 16) == ("paged_kernel", 256)
+    assert llama.paged_decode_path(kernel, 384, 16) == ("paged_kernel", 128)
+    # a block that would cut a page in two: gather, then the kernel
+    assert llama.paged_decode_path(kernel, 384, 48) == ("gather_kernel", 128)
+    # no 128-multiple block (every tier-1 MAX_SEQ = 64), or no kernel
+    assert llama.paged_decode_path(kernel, MAX_SEQ, PAGE) == (
+        "gather_dense", None)
+    assert llama.paged_decode_path(dense, 2560, 16) == ("gather_dense", None)
+    # "auto": the cost model's choice (dense for short rows, the kernel
+    # where the benchmark and every real preset serve)
+    assert llama.paged_decode_path(CFG, 256, 16)[0] == "gather_dense"
+    assert llama.paged_decode_path(CFG, 2560, 16)[0] == "paged_kernel"
+    for max_seq, path in ((MAX_SEQ, "gather_dense"), (2560, "paged_kernel")):
+        assert llama.make_scheduler_fns(
+            CFG, max_seq, 2)["decode_attention"] == path
+
+
+@pytest.mark.parametrize("max_seq, prompt_len", [
+    (256, 40),    # one 256-token block a row (the benchmark's block)
+    (384, 130),   # 128-token blocks: the prompt ends in the second,
+                  # the third stays dead
+])
+def test_paged_kernel_step_matches_contiguous_kernel(
+        params, max_seq, prompt_len):
+    """The same A/B at a kernel-eligible geometry, where the paged step
+    is built with the kernel that reads the pool in place
+    (``paged_decode_path`` == "paged_kernel") and the contiguous step
+    with ``decode_attention``: bitwise-equal tokens, logprobs, next
+    logits and cache content over 3 steps, through SHUFFLED page
+    tables with the sentinel past each row's reservation."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    # tiny resolves "auto" to dense at these lengths: state the kernel
+    cfg = dataclasses.replace(CFG, decode_impl="pallas")
+    assert llama.paged_decode_path(cfg, max_seq, PAGE)[0] == "paged_kernel"
+    ppseq = max_seq // PAGE
+    slots = 3
+    prompt = (np.arange(1, prompt_len + 1) * 7 % 500).astype(np.int32)
+    slot_cache = llama.init_kv_cache(cfg, 1, max_seq)
+    logits_row, slot_cache = llama.prefill_to_length(
+        params, slot_cache, jnp.asarray(prompt)[None, :], prompt_len, cfg)
+
+    cache = llama.init_kv_cache(cfg, slots, max_seq)
+    logits_c = jnp.zeros((slots, cfg.vocab), jnp.float32)
+    pages = llama.init_paged_kv_cache(cfg, slots * ppseq, PAGE)
+    logits_p = jnp.zeros((slots, cfg.vocab), jnp.float32)
+    # rows 0 and 2 hold the prompt (row 1 is inert); a row reserves the
+    # pages its prompt and 3 more tokens span, the rest is the sentinel
+    physical = np.random.RandomState(3).permutation(
+        slots * ppseq).reshape(slots, ppseq).astype(np.int32)
+    reserved = np.arange(ppseq) * PAGE < prompt_len + 3
+    tables = np.where(reserved[None, :], physical, slots * ppseq)
+    tables[1] = slots * ppseq
+    for slot in (0, 2):
+        cache, logits_c = llama.scheduler_admit(
+            cache, logits_c, slot_cache, logits_row, slot)
+        pages, logits_p = llama.paged_admit(
+            pages, logits_p, slot_cache, logits_row, tables[slot], slot)
+
+    positions = np.array([prompt_len, max_seq, prompt_len], np.int32)
+    active = np.array([True, False, True])
+    forced = np.array([0, 0, 7], np.int32)   # row 2 replays a token
+    fmask = np.array([False, False, True])
+
+    for _ in range(3):
+        t_c, lp_c, logits_c, cache = llama.scheduler_step(
+            params, cache, logits_c, positions, active, forced, fmask,
+            cfg)
+        t_p, lp_p, logits_p, pages = llama.paged_scheduler_step(
+            params, pages, logits_p, tables, positions, active, forced,
+            fmask, cfg)
+        np.testing.assert_array_equal(np.asarray(t_c), np.asarray(t_p))
+        np.testing.assert_array_equal(np.asarray(lp_c), np.asarray(lp_p))
+        np.testing.assert_array_equal(
+            np.asarray(logits_c), np.asarray(logits_p))
+        positions[[0, 2]] += 1
+        fmask[2] = False
+    for slot in (0, 2):   # unreserved pages gather as zeros
+        row = llama.paged_gather(pages, tables[slot])
+        np.testing.assert_array_equal(
+            np.asarray(row), np.asarray(cache[:, :, slot:slot + 1]))
+
+
 def test_chunked_prefill_token_identity(fns, params):
     """A 20-token prompt prefilled in 8-token chunks (interleaved with
     the decode loop) emits byte-identical greedy tokens to the one-shot

@@ -236,3 +236,39 @@ def test_step_and_prefill_carry_the_named_scopes(fns, params):
     assert prefill.as_text().startswith("module @jit_prefill_to_length")
     assert {"embed", "attn.qkv", "attn.kv_write", "attn.kernel",
             "attn.out", "ffn", "head"} <= _scopes(prefill)
+
+
+def test_kernel_eligible_step_has_no_page_gather(params):
+    """Where the geometry lets the decode kernel read the pool in place
+    (every real preset; here ``tiny`` with the kernel stated, 256-token
+    rows), the step holds ``attn.kernel`` and NO ``attn.page_gather``,
+    and the bundle and ``stats()`` name the path that was built."""
+    import dataclasses
+
+    max_seq, slots = 256, 2
+    cfg = dataclasses.replace(CFG, decode_impl="pallas")
+    kernel_fns = llama.make_scheduler_fns(cfg, max_seq, slots)
+    assert kernel_fns["decode_attention"] == "paged_kernel"
+    step = kernel_fns["step"].lower(
+        params, kernel_fns["init_cache"](), kernel_fns["init_logits"](),
+        np.zeros((slots, max_seq // 16), np.int32),
+        np.zeros((slots,), np.int32), np.ones((slots,), bool),
+        np.zeros((slots,), np.int32), np.zeros((slots,), bool))
+    scopes = _scopes(step)
+    assert {"attn.kv_write", "attn.kernel"} <= scopes
+    assert "attn.page_gather" not in scopes
+    sched = DecodeScheduler(kernel_fns, params, slots, max_seq)
+    try:
+        assert sched.stats()["decode_attention"] == "paged_kernel"
+    finally:
+        sched.close()
+
+
+def test_stats_name_the_fallback_decode_attention(fns, params):
+    """At MAX_SEQ = 64 no kernel block fits: the gather, then dense."""
+    assert fns["decode_attention"] == "gather_dense"
+    sched = DecodeScheduler(fns, params, 2, MAX_SEQ)
+    try:
+        assert sched.stats()["decode_attention"] == "gather_dense"
+    finally:
+        sched.close()
