@@ -1069,6 +1069,17 @@ class InferenceServer:
             "tpu_kv_pages_total": "pages_total",
             "tpu_kv_pages_free": "pages_free",
             "tpu_kv_pages_cached": "pages_cached",
+            # the window class of a two-class pool, what the steps'
+            # attention covered and skipped, and the routed layers'
+            # counts (all 0 for a model with neither)
+            "tpu_kv_window_pages_total": "window_pages_total",
+            "tpu_kv_window_pages_free": "window_pages_free",
+            "tpu_scheduler_context_tokens_total": "context_tokens",
+            "tpu_scheduler_window_skipped_tokens_total":
+                "window_skipped_tokens",
+            "tpu_moe_layer_steps_total": "moe_layer_steps",
+            "tpu_moe_local_pairs_total": "moe_local_pairs",
+            "tpu_moe_experts_hit_total": "moe_experts_hit",
             # speculative decoding (ISSUE 19): proposal/acceptance
             # counters perfanalyzer's accept-rate columns window-diff,
             # plus the lifetime accepted-per-step gauge

@@ -178,6 +178,42 @@ CATALOG = {
         "gauge",
         "KV pages held only by the radix prefix cache (unpinned, "
         "evictable), per model."),
+    "tpu_kv_window_pages_total": (
+        "gauge",
+        "Size of the KV page pool's window class (pages of the layers "
+        "that attend a sliding window; 0 for a model without such "
+        "layers), per model."),
+    "tpu_kv_window_pages_free": (
+        "gauge",
+        "Window-class KV pages on the free list, per model: a sequence "
+        "holds at most one window plus a kernel block of them and gives "
+        "pages back as its window moves on."),
+    "tpu_scheduler_context_tokens_total": (
+        "counter",
+        "Key positions the decode steps' attention layers covered: per "
+        "step and active row its context length, times the model's "
+        "attention layers, per model (host side, from positions)."),
+    "tpu_scheduler_window_skipped_tokens_total": (
+        "counter",
+        "Of tpu_scheduler_context_tokens_total, the key positions that "
+        "lay behind a window layer's window and were neither read nor "
+        "kept, per model."),
+    # -- routed (mixture-of-experts) layers --------------------------------
+    "tpu_moe_layer_steps_total": (
+        "counter",
+        "Routed feed-forward layers run by decode steps (steps times "
+        "routed layers), per model."),
+    "tpu_moe_local_pairs_total": (
+        "counter",
+        "(token, expert) pairs of decode steps whose chosen expert is "
+        "held by this process (its share under expert parallelism), "
+        "summed over routed layers, per model."),
+    "tpu_moe_experts_hit_total": (
+        "counter",
+        "Distinct held experts that received at least one pair, summed "
+        "over decode steps and routed layers, per model: over "
+        "tpu_moe_layer_steps_total, the experts whose weights a "
+        "layer-step had to read."),
     # -- speculative decoding ----------------------------------------------
     "tpu_spec_tokens_proposed_total": (
         "counter",

@@ -72,10 +72,93 @@ class LlamaConfig:
     # back to 128-tiles, then to the dense path (_flash_blocks)
     flash_block_q: int = 256
     flash_block_k: int = 512
+    # -- the block as data.  The defaults are the Llama / Mistral block:
+    # rotary positions on every layer, full causal attention, two norms
+    # a layer, a dense SwiGLU.  Everything below is read at trace time.
+    # explicit head size where it is not d_model / n_heads (0 = derived)
+    d_head: int = 0
+    # per-layer attention kind, "full" or "window" (() = all full);
+    # window layers see keys j with 0 <= i - j < window
+    layer_types: tuple = ()
+    window: int = 0
+    # which layers carry rotary positions: "all", or "window" (the full
+    # layers then carry no positions at all)
+    rope_layers: str = "all"
+    # per-head RMSNorm of q and k (gains q_norm / k_norm over head_dim)
+    qk_norm: bool = False
+    # sigmoid output gate: wo((softmax(qk)v) * sigmoid(wg y))
+    attn_gate: bool = False
+    # sandwich norms: x + N2(Attn(N1 x)); x + N4(FFN(N3 x))
+    sandwich_norm: bool = False
+    # the embedding is multiplied by this (sqrt(d_model) under muP)
+    embed_scale: float = 1.0
+    # per-layer feed-forward kind, "dense" or "moe" (() = all dense)
+    ffn_types: tuple = ()
+    # the routed layers' geometry (MoEConfig), None without one
+    moe: object = None
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    def layer_window(self, i):
+        """Layer ``i``'s attention window in tokens, 0 for full."""
+        if self.layer_types and self.layer_types[i] == "window":
+            return self.window
+        return 0
+
+    def layer_rope(self, i):
+        return self.rope_layers == "all" or bool(self.layer_window(i))
+
+    def layer_moe(self, i):
+        return bool(self.ffn_types) and self.ffn_types[i] == "moe"
+
+    @property
+    def window_layers(self):
+        """Indices of the layers that attend a window (their KV lives in
+        the page pool's window class)."""
+        return tuple(i for i in range(self.n_layers) if self.layer_window(i))
+
+    @property
+    def full_layers(self):
+        return tuple(i for i in range(self.n_layers)
+                     if not self.layer_window(i))
+
+    @property
+    def plain(self):
+        """True for the Llama / Mistral block: what the tensor-parallel,
+        int8 and single-shard training paths were written for."""
+        return not (self.layer_types or self.ffn_types or self.qk_norm
+                    or self.attn_gate or self.sandwich_norm
+                    or self.embed_scale != 1.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEConfig:
+    """A routed feed-forward layer: a sigmoid router over ``n_experts``
+    choosing ``top_k`` a token, SwiGLU experts of width ``d_expert``,
+    one shared expert of the same width every token passes through.
+    ``first`` / ``count`` say which experts THIS process holds (expert
+    parallelism: the router keeps its published width, the layer
+    computes the shared expert plus the held experts' part of the sum
+    and hands that partial result on; nothing stands in for the rest)."""
+    n_experts: int = 16
+    top_k: int = 4
+    d_expert: int = 64
+    route_norm: bool = True
+    route_scale: float = 1.0
+    first: int = 0
+    count: int = 0      # 0 = all of them
+
+    @property
+    def held(self):
+        return self.count or self.n_experts
+
+
+class UnsupportedArchitecture(ValueError):
+    """A path of the program that was written for the plain block (or
+    for one page class) was asked to run a configuration it cannot
+    serve: refused where it is asked for, never served wrong."""
 
 
 def llama3_8b():
@@ -109,6 +192,23 @@ def tiny(vocab=256):
     )
 
 
+def tiny_afmoe(vocab=256, window=32, first=0, count=0):
+    """Test-size AFMoE block (the Trinity family): a dense window layer,
+    then routed layers window, full, window; explicit head size,
+    per-head QK norm, output gate, sandwich norms, scaled embedding,
+    sigmoid top-4 routing over 16 experts with a shared expert."""
+    return LlamaConfig(
+        vocab=vocab, d_model=64, n_layers=4, n_heads=8, n_kv_heads=4,
+        d_head=16, d_ff=128, rope_theta=10000.0,
+        layer_types=("window", "window", "full", "window"), window=window,
+        rope_layers="window", qk_norm=True, attn_gate=True,
+        sandwich_norm=True, embed_scale=8.0,
+        ffn_types=("dense", "moe", "moe", "moe"),
+        moe=MoEConfig(n_experts=16, top_k=4, d_expert=32, route_scale=2.448,
+                      first=first, count=count),
+    )
+
+
 # -- parameters --------------------------------------------------------------
 
 
@@ -123,31 +223,85 @@ def init_params(key, cfg):
         ).astype(cfg.dtype)
 
     layers = []
-    for kl in k_layers:
+    for i, kl in enumerate(k_layers):
         ks = jax.random.split(kl, 7)
-        layers.append(
-            {
-                "attn_norm": jnp.ones((cfg.d_model,), cfg.dtype),
-                "wq": dense(ks[0], (cfg.d_model, cfg.n_heads * hd),
-                            cfg.d_model),
-                "wk": dense(ks[1], (cfg.d_model, cfg.n_kv_heads * hd),
-                            cfg.d_model),
-                "wv": dense(ks[2], (cfg.d_model, cfg.n_kv_heads * hd),
-                            cfg.d_model),
-                "wo": dense(ks[3], (cfg.n_heads * hd, cfg.d_model),
-                            cfg.n_heads * hd),
-                "mlp_norm": jnp.ones((cfg.d_model,), cfg.dtype),
+        layer = {
+            "attn_norm": jnp.ones((cfg.d_model,), cfg.dtype),
+            "wq": dense(ks[0], (cfg.d_model, cfg.n_heads * hd),
+                        cfg.d_model),
+            "wk": dense(ks[1], (cfg.d_model, cfg.n_kv_heads * hd),
+                        cfg.d_model),
+            "wv": dense(ks[2], (cfg.d_model, cfg.n_kv_heads * hd),
+                        cfg.d_model),
+            "wo": dense(ks[3], (cfg.n_heads * hd, cfg.d_model),
+                        cfg.n_heads * hd),
+            "mlp_norm": jnp.ones((cfg.d_model,), cfg.dtype),
+        }
+        if not cfg.layer_moe(i):
+            layer.update({
                 "w_gate": dense(ks[4], (cfg.d_model, cfg.d_ff), cfg.d_model),
                 "w_up": dense(ks[5], (cfg.d_model, cfg.d_ff), cfg.d_model),
                 "w_down": dense(ks[6], (cfg.d_ff, cfg.d_model), cfg.d_ff),
-            }
-        )
+            })
+        if not cfg.plain:
+            layer.update(_init_block_extras(kl, cfg, i, dense))
+        layers.append(layer)
     return {
         "embed": dense(k_embed, (cfg.vocab, cfg.d_model), cfg.d_model),
         "layers": layers,
         "norm": jnp.ones((cfg.d_model,), cfg.dtype),
         "lm_head": dense(k_out, (cfg.d_model, cfg.vocab), cfg.d_model),
     }
+
+
+def _init_block_extras(key, cfg, i, dense):
+    """The leaves a layer has beyond the plain block's nine, by what
+    ``cfg`` switches on.  Gains and router biases are random (a test
+    with all-ones gains would not see a norm applied in the wrong
+    place)."""
+    hd, d = cfg.head_dim, cfg.d_model
+    ks = jax.random.split(jax.random.fold_in(key, 7), 12)
+
+    def gain(k, n):
+        return (1.0 + 0.1 * jax.random.normal(k, (n,), jnp.float32)
+                ).astype(cfg.dtype)
+
+    out = {}
+    if cfg.qk_norm:
+        out["q_norm"], out["k_norm"] = gain(ks[0], hd), gain(ks[1], hd)
+    if cfg.attn_gate:
+        out["wg"] = dense(ks[2], (d, cfg.n_heads * hd), d)
+    if cfg.sandwich_norm:
+        out["attn_post_norm"] = gain(ks[3], d)
+        out["mlp_post_norm"] = gain(ks[4], d)
+    if cfg.layer_moe(i):
+        m = cfg.moe
+        f, e = m.d_expert, m.held
+        out["router"] = dense(ks[5], (d, m.n_experts), d)
+        out["router_bias"] = 0.1 * jax.random.normal(
+            ks[6], (m.n_experts,), jnp.float32)
+        out["ws_gate"] = dense(ks[7], (d, f), d)
+        out["ws_up"] = dense(ks[8], (d, f), d)
+        out["ws_down"] = dense(ks[9], (f, d), f)
+        # every expert's values depend on its own id alone, so a share
+        # holds the same experts the uncut layer has
+        def experts(k, shape, fan_in):
+            return jnp.stack([
+                dense(jax.random.fold_in(k, m.first + j), shape, fan_in)
+                for j in range(e)])
+        out["we_gate"] = experts(ks[10], (d, f), d)
+        out["we_up"] = experts(ks[11], (d, f), d)
+        out["we_down"] = experts(jax.random.fold_in(ks[11], 1), (f, d), f)
+    return out
+
+
+def _need_plain(cfg, what):
+    if not cfg.plain:
+        raise UnsupportedArchitecture(
+            "{} serves the plain Llama / Mistral block only; this "
+            "configuration has per-layer attention or feed-forward kinds, "
+            "QK norm, an output gate, sandwich norms or a scaled "
+            "embedding".format(what))
 
 
 def param_specs(cfg, quantized=False, quantized_embed=False):
@@ -161,6 +315,8 @@ def param_specs(cfg, quantized=False, quantized_embed=False):
     ``quantized_embed=True`` iff ``quantize_params`` ran with
     ``quantize_embed=True`` (its per-ROW scales shard with the vocab
     rows)."""
+
+    _need_plain(cfg, "tensor-parallel sharding (param_specs)")
 
     def wspec(spec, out_axis_name):
         if not quantized:
@@ -272,10 +428,13 @@ def _embed_rows(params, tokens, cfg=None):
     from tpuserver.ops import quant
 
     with jax.named_scope("embed"):
-        return quant.gather_rows(
+        x = quant.gather_rows(
             params["embed"], tokens,
             dtype=cfg.dtype if cfg is not None else None,
         )
+        if cfg is not None and cfg.embed_scale != 1.0:
+            x = (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
+        return x
 
 
 def _rms_norm(x, w, eps):
@@ -307,8 +466,81 @@ def _expand_kv(k, n_rep):
     return jnp.repeat(k, n_rep, axis=2)
 
 
+def _swiglu(h, w_gate, w_up, w_down, red=lambda y: y):
+    gated = jax.nn.silu(_mm(h, w_gate)) * _mm(h, w_up)
+    return red(_mm(gated, w_down))
+
+
+def _route(params, x, m):
+    """The router's choice for rows x [n, Dm]: ``(chosen [n, top_k] of all
+    n_experts, w [n, top_k])``.  Scores in float32 at full precision
+    (sigmoid); ``top_k`` of ``score + bias``, weighed by their own
+    scores, normalised and scaled.  A choice between two experts whose
+    scores nearly tie is the one discrete step of the layer, and it
+    should flip as rarely as arithmetic allows."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, chosen = lax.top_k(scores + params["router_bias"], m.top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    if m.route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen, w * m.route_scale
+
+
+def _moe_ffn(params, h, cfg, live=None, stats=None):
+    """The routed feed-forward of one layer, as this process holds it:
+    ``Shared(y) + sum over the chosen experts that are held here of
+    w_e Expert_e(y)`` for h [B, T, Dm].
+
+    The router (:func:`_route`) chooses among all ``n_experts``.  The
+    (token, expert) pairs whose expert is held here (and whose row is
+    ``live``: an inert row or a padding token must not make an expert's
+    weights stream) are sorted by expert and go through ONE kernel,
+    ``ops.moe_grouped_matmul``, three times (gate, up, down); no pair is
+    ever dropped.  ``stats`` (a list) receives ``(pairs held here,
+    distinct held experts hit)`` of this call as two int32 scalars."""
+    from tpuserver.ops.moe import moe_grouped_matmul
+
+    m = cfg.moe
+    b, t, d = h.shape
+    n, k, e = b * t, m.top_k, m.held
+    x = h.reshape(n, d)
+    with jax.named_scope("moe.route"):
+        chosen, w = _route(params, x, m)
+    with jax.named_scope("moe.shared"):
+        out = _swiglu(x, params["ws_gate"], params["ws_up"],
+                      params["ws_down"]).astype(jnp.float32)
+    with jax.named_scope("moe.dispatch"):
+        local = chosen - m.first
+        held = (local >= 0) & (local < e)
+        if live is not None:
+            held = held & live.reshape(n, 1)
+        # pairs of experts held elsewhere sort behind every group
+        group = jnp.where(held, local, e).reshape(n * k)
+        order = jnp.argsort(group)
+        sizes = jnp.sum(
+            group[:, None] == jnp.arange(e, dtype=group.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        xs = x[order // k]
+    with jax.named_scope("moe.experts"):
+        act = (jax.nn.silu(moe_grouped_matmul(xs, params["we_gate"], sizes))
+               * moe_grouped_matmul(xs, params["we_up"], sizes))
+        ys = moe_grouped_matmul(act, params["we_down"], sizes)
+    with jax.named_scope("moe.combine"):
+        # rows behind the last group were never written
+        ys = jnp.where((group[order] < e)[:, None], ys, 0)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * k, dtype=order.dtype))
+        ys = ys[back].reshape(n, k, d).astype(jnp.float32)
+        out = out + jnp.sum(ys * w[:, :, None], axis=1)
+    if stats is not None:
+        stats.append((jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32)))
+    return out.astype(h.dtype).reshape(b, t, d)
+
+
 def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
-           reduce=None):
+           reduce=None, layer=0, live=None, moe_stats=None):
     """One transformer block: x [B, T, Dm] -> [B, T, Dm].
 
     The single source of the block math — dense forward, the tp-sharded
@@ -316,6 +548,10 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
     ``attn_fn`` closures.  ``n_heads``/``n_kv_heads`` are the *local* head
     counts (tp-sharded callers pass per-shard values); ``reduce`` is applied
     to row-parallel matmul outputs (psum over tp in SPMD, identity here).
+    What the block is made of is ``cfg``'s data for ``layer``: rotary
+    positions or none, per-head QK norm, an output gate, sandwich norms,
+    a dense or a routed feed-forward (``live`` [B, T] and ``moe_stats``:
+    :func:`_moe_ffn`); the defaults trace the plain Llama block.
     """
     B, T, _ = x.shape
     hd = cfg.head_dim
@@ -327,17 +563,44 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
         q = _mm(h, params["wq"]).reshape(B, T, nh, hd)
         k = _mm(h, params["wk"]).reshape(B, T, nkv, hd)
         v = _mm(h, params["wv"]).reshape(B, T, nkv, hd)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = _rms_norm(q, params["q_norm"], cfg.norm_eps)
+            k = _rms_norm(k, params["k_norm"], cfg.norm_eps)
+        if cfg.layer_rope(layer):
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        gate = _mm(h, params["wg"]) if cfg.attn_gate else None
     # the caller's closure: attn.kv_write / attn.page_gather / attn.kernel
     attn = attn_fn(q, k, v)
     with jax.named_scope("attn.out"):
-        x = x + red(_mm(attn.reshape(B, T, nh * hd), params["wo"]))
+        attn = attn.reshape(B, T, nh * hd)
+        if gate is not None:
+            attn = (attn.astype(jnp.float32)
+                    * jax.nn.sigmoid(gate.astype(jnp.float32))
+                    ).astype(attn.dtype)
+        out = red(_mm(attn, params["wo"]))
+        if cfg.sandwich_norm:
+            out = _rms_norm(out, params["attn_post_norm"], cfg.norm_eps)
+        x = x + out
     with jax.named_scope("ffn"):
         h = _rms_norm(x, params["mlp_norm"], cfg.norm_eps)
-        gated = (jax.nn.silu(_mm(h, params["w_gate"]))
-                 * _mm(h, params["w_up"]))
-        return x + red(_mm(gated, params["w_down"]))
+        if cfg.layer_moe(layer):
+            out = _moe_ffn(params, h, cfg, live, moe_stats)
+        else:
+            out = _swiglu(h, params["w_gate"], params["w_up"],
+                          params["w_down"], red)
+        if cfg.sandwich_norm:
+            out = _rms_norm(out, params["mlp_post_norm"], cfg.norm_eps)
+        return x + out
+
+
+def _dense_causal(q, k, v, n_rep, window=0):
+    """Plain causal (optionally windowed) self-attention, q [B, T, H, D]
+    against its own k/v [B, T, Hkv, D]: the dense form the windowed
+    layers fall back to where the flash kernel's tiles do not fit."""
+    t = q.shape[1]
+    pos = jnp.tile(jnp.arange(t)[None, :], (q.shape[0], 1))
+    return _attend_cached(q, k, v, pos, t, n_rep, window=window)
 
 
 def forward(params, tokens, cfg):
@@ -347,7 +610,7 @@ def forward(params, tokens, cfg):
     n_rep = cfg.n_heads // cfg.n_kv_heads
     positions = jnp.arange(T)
 
-    def attn_fn(q, k, v):
+    def attn_fn(q, k, v, window=0):
         bq, bk = _flash_blocks(T, cfg)
         if cfg.attn_impl == "pallas" and bq is not None and bk is not None:
             # MXU-tileable lengths only: the TPU lowering needs
@@ -358,14 +621,19 @@ def forward(params, tokens, cfg):
             return flash_attention(
                 q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
                 causal=True, block_q=bq, block_k=bk,
+                window=window or None,
             )
+        if window:
+            return _dense_causal(q, k, v, n_rep, window)
         return ring_attention(
             q, _expand_kv(k, n_rep), _expand_kv(v, n_rep), causal=True
         )
 
     x = _embed_rows(params, tokens, cfg)
-    for layer in params["layers"]:
-        x = _block(layer, x, positions, cfg, attn_fn)
+    for i, layer in enumerate(params["layers"]):
+        x = _block(layer, x, positions, cfg,
+                   functools.partial(attn_fn, window=cfg.layer_window(i)),
+                   layer=i)
     x = _rms_norm(x, params["norm"], cfg.norm_eps)
     return _mm(x, params["lm_head"]).astype(jnp.float32)
 
@@ -388,6 +656,7 @@ def sharded_forward(mesh, cfg):
 def _forward_spmd(params, tokens, cfg):
     # Inside shard_map each device holds a [B/dp, T/sp] token block and
     # tp-sharded weights; tp matmul partial-sums are reduced explicitly.
+    _need_plain(cfg, "the sharded forward")
     B, T = tokens.shape
     tp = lax.psum(1, "tp")
     if cfg.n_kv_heads % tp != 0 or cfg.n_heads % tp != 0:
@@ -562,16 +831,22 @@ def _decode_kernel_block(cfg, max_seq):
     return next((b for b in (256, 128) if max_seq % b == 0), None)
 
 
-def _run_cached(params, cache, x, positions, write_pos, lengths, cfg):
+def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
+                live=None):
     """Shared decode/prefill body: run all blocks, writing new K/V into the
     cache at ``write_pos`` and attending over cache[:lengths].
 
-    x: [B, T, Dm] embedded inputs. Returns (x_out, new_cache)."""
+    x: [B, T, Dm] embedded inputs. Returns (x_out, new_cache).  The
+    contiguous cache keeps every position of every layer; a window
+    layer masks (dense) or skips (flash) what lies behind its window.
+    ``live`` [B, T]: rows that are real tokens (:func:`_moe_ffn`)."""
     n_rep = cfg.n_heads // cfg.n_kv_heads
     new_cache = cache
 
     for i, layer in enumerate(params["layers"]):
-        def attn_fn(q, k, v, i=i):
+        window = cfg.layer_window(i)
+
+        def attn_fn(q, k, v, i=i, window=window):
             nonlocal new_cache
             with jax.named_scope("attn.kv_write"):
                 new_cache = new_cache.at[i, 0].set(
@@ -598,6 +873,7 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg):
                     impl == "pallas"
                     and q.shape[1] == 1
                     and pallas_block is not None
+                    and not window
                 ):
                     # the serving hot op: hand-tiled single-query decode
                     # attention (GQA expansion stays in VMEM, dead cache
@@ -636,23 +912,25 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg):
                     return flash_attention(
                         q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
                         causal=True, block_q=pf_bq, block_k=pf_bk,
+                        window=window or None,
                     )
                 return _attend_cached(
                     q, new_cache[i, 0], new_cache[i, 1], positions, lengths,
-                    n_rep,
+                    n_rep, window=window,
                 )
 
-        x = _block(layer, x, positions, cfg, attn_fn)
+        x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live)
     return x, new_cache
 
 
-def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep):
+def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0):
     """q: [B, Tq, H, D] against cache [B, S, Hkv, D].
 
     Masks cache positions >= ``length`` (a scalar, or a per-row [B]
     vector when the continuous-batching step decodes rows at different
     sequence positions) and (causally) > the query's own global position
-    ``q_pos`` [B, Tq]."""
+    ``q_pos`` [B, Tq]; with ``window``, also those at or beyond
+    ``window`` positions behind the query."""
     k = _expand_kv(cache_k, n_rep)
     v = _expand_kv(cache_v, n_rep)
     s = jnp.einsum(
@@ -663,6 +941,8 @@ def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep):
     if getattr(length, "ndim", 0):
         length = length.reshape(-1, 1, 1, 1)  # per-row valid prefixes
     mask = (k_idx >= length) | (k_idx > q_pos[:, None, :, None])
+    if window:
+        mask = mask | (k_idx <= q_pos[:, None, :, None] - window)
     s = jnp.where(mask, -jnp.inf, s)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -776,7 +1056,10 @@ def prefill_to_length(params, cache, tokens, true_len, cfg):
     B, T = tokens.shape
     positions = jnp.tile(jnp.arange(T)[None, :], (B, 1))
     x = _embed_rows(params, tokens, cfg)
-    x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg)
+    # padding rows route nowhere: only a routed layer reads this
+    live = positions < true_len if cfg.ffn_types else None
+    x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg,
+                               live=live)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
         last = lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)[:, 0]
@@ -819,8 +1102,11 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
     new_cache = cache
     pallas_block = _decode_kernel_block(cfg, max_seq)
 
+    live = (positions < max_seq)[:, None] if cfg.ffn_types else None
     for i, layer in enumerate(params["layers"]):
-        def attn_fn(q, k, v, i=i):
+        window = cfg.layer_window(i)
+
+        def attn_fn(q, k, v, i=i, window=window):
             nonlocal new_cache
             with jax.named_scope("attn.kv_write"):
                 new_cache = new_cache.at[i, 0, rows, positions].set(
@@ -830,7 +1116,7 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
                     v[:, 0].astype(new_cache.dtype), mode="drop"
                 )
             with jax.named_scope("attn.kernel"):
-                if pallas_block is not None:
+                if pallas_block is not None and not window:
                     # the decode-attention kernel already takes per-row
                     # lengths — continuous batching is its natural shape
                     from tpuserver.ops import decode_attention
@@ -845,10 +1131,10 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
                     return out[:, None]
                 return _attend_cached(
                     q, new_cache[i, 0], new_cache[i, 1], q_pos, lengths,
-                    n_rep,
+                    n_rep, window=window,
                 )
 
-        x = _block(layer, x, q_pos, cfg, attn_fn)
+        x = _block(layer, x, q_pos, cfg, attn_fn, layer=i, live=live)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
         logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
@@ -920,6 +1206,41 @@ def init_paged_kv_cache(cfg, n_pages, page_size, dtype=None):
     )
 
 
+def window_ring_pages(cfg, max_seq, page_size):
+    """Pages a sequence holds AT MOST in the window class: one window
+    plus one kernel block (a query near a block's end still sees the
+    tail of the block ``window`` behind it), in whole blocks — or all of
+    ``max_seq`` where that is less.  A window row's page table has this
+    many entries and is a ring: logical page ``p`` is entry ``p %
+    ring``."""
+    _, block = paged_decode_path(cfg, max_seq, page_size)
+    if block is None:
+        raise UnsupportedArchitecture(
+            "window layers are served by the paged decode kernel only: "
+            "max_seq {} needs a 128-multiple block of whole {}-token "
+            "pages".format(max_seq, page_size))
+    ring_blocks = min(-(-cfg.window // block) + 1, max_seq // block)
+    return ring_blocks * (block // page_size)
+
+
+def init_paged_kv_classes(cfg, n_pages, n_window_pages, page_size,
+                          dtype=None):
+    """The page pool of a configuration with window layers, in two
+    classes: ``{"full": [L_full, 2, n_pages, ...], "window": [L_window,
+    2, n_window_pages, ...]}``.  The full class keeps every token of a
+    sequence; the window class keeps one ring of pages a sequence
+    (:func:`window_ring_pages`), whose pages are given back as the
+    window moves past them."""
+    dtype = dtype or cfg.dtype
+    tail = (page_size, cfg.n_kv_heads, cfg.head_dim)
+    return {
+        "full": jnp.zeros(
+            (len(cfg.full_layers), 2, n_pages) + tail, dtype),
+        "window": jnp.zeros(
+            (len(cfg.window_layers), 2, n_window_pages) + tail, dtype),
+    }
+
+
 def paged_decode_path(cfg, max_seq, page_size):
     """Which decode attention :func:`paged_batched_decode_step` traces
     for this geometry, and the kernel's K/V block (None without a
@@ -976,50 +1297,78 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     step's out-of-bounds rows.
     """
     S = tokens.shape[0]
-    n_pages, page = pages.shape[2], pages.shape[3]
-    ppseq = page_tables.shape[1]
+    # one page class (the pool is one array, the plain configurations)
+    # or two ({"full", "window"} pools and tables: window layers)
+    classes = isinstance(pages, dict)
+    pools = dict(pages) if classes else {"full": pages}
+    tables = page_tables if classes else {"full": page_tables}
+    n_pages, page = pools["full"].shape[2], pools["full"].shape[3]
+    ppseq = tables["full"].shape[1]
     max_seq = ppseq * page
     # inert rows clamp to length 1 (see batched_decode_step)
     lengths = jnp.where(positions >= max_seq, 1, positions + 1)
     logical = jnp.clip(positions // page, 0, ppseq - 1)
-    phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)[:, 0]
+    phys = jnp.take_along_axis(
+        tables["full"], logical[:, None], axis=1)[:, 0]
     # sentinel rows scatter out of bounds -> dropped (mode="drop")
     phys = jnp.where(positions >= max_seq, n_pages, phys)
     offs = positions % page
     q_pos = positions[:, None]
     n_rep = cfg.n_heads // cfg.n_kv_heads
     x = _embed_rows(params, tokens, cfg)[:, None, :]  # [S, 1, Dm]
-    new_pages = pages
     # unreserved logical pages clip to a valid (arbitrary) physical
     # page: everything they contribute sits beyond the row's valid
     # length and is masked.  The gather would clamp by itself; the
     # kernel's page copies would not (a DMA does not drop a wild index)
-    tbl = jnp.clip(page_tables, 0, n_pages - 1)
+    tbl = {"full": jnp.clip(tables["full"], 0, n_pages - 1)}
+    write = {"full": phys}
     path, pallas_block = paged_decode_path(cfg, max_seq, page)
+    starts = None
+    if classes:
+        # the window class: a row's table is a ring over logical pages
+        # (ops.paged_decode_attention), the new token lands in entry
+        # (position // page) % ring, and the kernel starts at the first
+        # position still inside the window
+        n_wpages = pools["window"].shape[2]
+        ring = tables["window"].shape[1]
+        phys_w = jnp.take_along_axis(
+            tables["window"], ((positions // page) % ring)[:, None],
+            axis=1)[:, 0]
+        write["window"] = jnp.where(positions >= max_seq, n_wpages, phys_w)
+        tbl["window"] = jnp.clip(tables["window"], 0, n_wpages - 1)
+        starts = jnp.maximum(lengths - cfg.window, 0).astype(jnp.int32)
+    live = (positions < max_seq)[:, None] if cfg.ffn_types else None
+    stats = [] if cfg.ffn_types else None
+    slot_of = {i: ("window", n) for n, i in enumerate(cfg.window_layers)}
+    slot_of.update((i, ("full", n)) for n, i in enumerate(cfg.full_layers))
 
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
-            nonlocal new_pages
+            cls, li = slot_of[i]
+            pool, at = pools[cls], write[cls]
             with jax.named_scope("attn.kv_write"):
-                new_pages = new_pages.at[i, 0, phys, offs].set(
-                    k[:, 0].astype(new_pages.dtype), mode="drop"
+                pool = pool.at[li, 0, at, offs].set(
+                    k[:, 0].astype(pool.dtype), mode="drop"
                 )
-                new_pages = new_pages.at[i, 1, phys, offs].set(
-                    v[:, 0].astype(new_pages.dtype), mode="drop"
+                pool = pool.at[li, 1, at, offs].set(
+                    v[:, 0].astype(pool.dtype), mode="drop"
                 )
+                pools[cls] = pool
             if path == "paged_kernel":
-                with jax.named_scope("attn.kernel"):
+                with jax.named_scope(
+                        "attn.window" if cls == "window" else "attn.kernel"):
                     from tpuserver.ops import paged_decode_attention
 
                     out = paged_decode_attention(
-                        q[:, 0], new_pages, i, tbl,
+                        q[:, 0], pool, li, tbl[cls],
                         lengths.astype(jnp.int32), block_k=pallas_block,
+                        starts=starts if cls == "window" else None,
                     )
                     return out[:, None]
             with jax.named_scope("attn.page_gather"):
-                tail = new_pages.shape[4:]
-                k_seq = new_pages[i, 0][tbl].reshape(S, max_seq, *tail)
-                v_seq = new_pages[i, 1][tbl].reshape(S, max_seq, *tail)
+                tail = pool.shape[4:]
+                k_seq = pool[li, 0][tbl[cls]].reshape(S, max_seq, *tail)
+                v_seq = pool[li, 1][tbl[cls]].reshape(S, max_seq, *tail)
             with jax.named_scope("attn.kernel"):
                 if path == "gather_kernel":
                     # the gathered view is a standard contiguous cache:
@@ -1034,10 +1383,18 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
                 return _attend_cached(
                     q, k_seq, v_seq, q_pos, lengths, n_rep)
 
-        x = _block(layer, x, q_pos, cfg, attn_fn)
+        x = _block(layer, x, q_pos, cfg, attn_fn, layer=i, live=live,
+                   moe_stats=stats)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
         logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+    new_pages = pools if classes else pools["full"]
+    if stats is not None:
+        # what the routed layers did this step, for the host's counters:
+        # [layer-steps, pairs held here, distinct held experts hit]
+        return logits, new_pages, jnp.stack([
+            jnp.int32(len(stats)), sum(s[0] for s in stats),
+            sum(s[1] for s in stats)])
     return logits, new_pages
 
 
@@ -1053,11 +1410,12 @@ def paged_scheduler_step(params, pages, logits_all, page_tables,
         tokens = jnp.where(forced_mask, forced, greedy)
         tok_logp = jnp.take_along_axis(
             logp, tokens[:, None], axis=-1)[:, 0]
-    new_logits, new_pages = paged_batched_decode_step(
+    new_logits, new_pages, *moe = paged_batched_decode_step(
         params, pages, tokens, page_tables, positions, cfg
     )
     new_logits = jnp.where(active[:, None], new_logits, logits_all)
-    return tokens, tok_logp, new_logits, new_pages
+    # a routed configuration's step also returns its routing counts
+    return (tokens, tok_logp, new_logits, new_pages, *moe)
 
 
 def paged_spec_step(params, pages, logits_all, page_tables, positions,
@@ -1168,6 +1526,32 @@ def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
     return pages, logits_all
 
 
+def paged_admit_classes(pages, logits_all, slot_cache, slot_logits,
+                        dest_ids, slot, cfg):
+    """:func:`paged_admit` into a pool of two page classes.  ``pages``
+    and ``dest_ids`` are ``{"full", "window"}``; both ``dest_ids`` are
+    [pages_per_seq] physical ids by LOGICAL page (the class's own
+    sentinel drops a page).  The full layers' rows of the prefilled
+    cache go to the full class whole; of the window layers' rows only
+    the logical pages the host still names — the prompt's last window —
+    are written, so a prefill of two windows admits one."""
+    page = pages["full"].shape[3]
+    ppseq = dest_ids["full"].shape[0]
+    src = slot_cache.reshape(
+        slot_cache.shape[0], 2, ppseq, page, *slot_cache.shape[4:]
+    )
+    out = {}
+    for cls, layers in (("full", cfg.full_layers),
+                        ("window", cfg.window_layers)):
+        out[cls] = pages[cls].at[:, :, dest_ids[cls]].set(
+            src[np.asarray(layers)].astype(pages[cls].dtype), mode="drop"
+        )
+    logits_all = lax.dynamic_update_slice_in_dim(
+        logits_all, slot_logits.astype(logits_all.dtype), slot, axis=0
+    )
+    return out, logits_all
+
+
 def paged_gather(pages, page_ids):
     """One sequence's pages as a fresh single-row contiguous cache
     [L, 2, 1, max_seq, Hkv, hd] — the park/extract shape (so paged
@@ -1224,7 +1608,7 @@ def prefill_span(params, cache, tokens, start, logits_at, cfg):
 
 
 def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
-                       page_size=16, kv_pages=None):
+                       page_size=16, kv_pages=None, kv_window_pages=None):
     """Compiled function bundle for the continuous-batching scheduler,
     over a block-paged KV pool.
 
@@ -1271,6 +1655,19 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     - ``decode_attention`` — which decode attention ``step`` and
       ``spec_step`` were built with (:func:`paged_decode_path`):
       ``"paged_kernel"``, ``"gather_kernel"`` or ``"gather_dense"``
+    - ``window_class`` — None, or for a configuration with window
+      layers ``{"window", "ring", "n_pages"}``: the pool is then two
+      classes of pages (:func:`init_paged_kv_classes`), ``step`` and
+      ``admit`` take ``{"full", "window"}`` tables / destinations, and
+      ``spec_step``, ``gather`` and ``prefill_span`` are ABSENT: what
+      rides on them (speculation, park / resume / KV export, shared
+      prefixes and chunked prefill) the scheduler refuses by name
+      (``UnsupportedArchitecture``).  ``kv_window_pages`` bounds the
+      window class (default: every slot a full ring).
+
+    The ``step`` of a configuration with routed layers returns a fifth
+    result, their ``[layer-steps, held pairs, distinct held experts
+    hit]`` of the step.
 
     With a ``mesh`` the bundle is the GSPMD form: params
     Megatron-split, the page pool and slot cache kv-head-sharded over
@@ -1304,6 +1701,20 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
                 n_pages, pages_per_seq, page_size
             )
         )
+    window_class = None
+    if mesh is not None or quantized:
+        _need_plain(cfg, "tensor-parallel or int8 serving")
+    if cfg.window_layers:
+        ring = window_ring_pages(cfg, max_seq, page_size)
+        n_wpages = (int(kv_window_pages) if kv_window_pages is not None
+                    else max_slots * ring)
+        if n_wpages < ring:
+            raise ValueError(
+                "kv_window_pages={} cannot hold even one sequence's "
+                "window ({} pages of {} tokens)".format(
+                    n_wpages, ring, page_size))
+        window_class = {"window": cfg.window, "ring": ring,
+                        "n_pages": n_wpages}
     if mesh is None:
         step = jax.jit(
             named_partial(paged_scheduler_step, cfg=cfg),
@@ -1322,6 +1733,19 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
 
         def init_cache():
             return init_paged_kv_cache(cfg, n_pages, page_size)
+
+        if window_class is not None:
+            # two page classes: their own pool and admit; what assumes
+            # one table a sequence is left out of the bundle
+            spec_step = gather = prefill_span_fn = None
+            admit = jax.jit(
+                named_partial(paged_admit_classes, cfg=cfg),
+                donate_argnums=(0, 1),
+            )
+
+            def init_cache():  # noqa: F811
+                return init_paged_kv_classes(
+                    cfg, n_pages, n_wpages, page_size)
 
         def init_slot_cache():
             return init_kv_cache(cfg, 1, max_seq)
@@ -1382,7 +1806,7 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
                 jnp.zeros((max_slots, cfg.vocab), jnp.float32), repl
             )
 
-    return {
+    fns = {
         "init_cache": init_cache,
         "init_slot_cache": init_slot_cache,
         "init_logits": init_logits,
@@ -1390,15 +1814,22 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "prefill_span": prefill_span_fn,
         "prefill_bucket": functools.partial(prefill_bucket, cfg, max_seq),
         "step": step,
-        "spec_step": spec_step,
+        # the verify chain unpacks a two-result sub-step: a routed
+        # step returns three
+        "spec_step": None if cfg.ffn_types else spec_step,
         "admit": admit,
         "gather": gather,
         "page_size": page_size,
         "pages_per_seq": pages_per_seq,
         "n_pages": n_pages,
-        "span_safe": cfg.attn_impl != "pallas",
+        "span_safe": cfg.attn_impl != "pallas" and window_class is None,
         "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
+        "window_class": window_class,
     }
+    # what a two-class pool cannot serve is absent, not None: the
+    # scheduler reads ``"spec_step" in fns``
+    return {k: v for k, v in fns.items()
+            if v is not None or k == "window_class"}
 
 
 # -- tensor-parallel serving (decode over a tp mesh) -------------------------

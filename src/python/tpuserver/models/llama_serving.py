@@ -83,8 +83,23 @@ class LlamaGenerateModel(Model):
                  page_size=16, kv_pages=None, prefill_chunk_tokens=256,
                  prefix_cache=True, kv_export=False,
                  target_queue_ms=None, shed_interval_ms=100.0,
-                 spec_tokens=None):
+                 spec_tokens=None, params=None, kv_window_pages=None):
         self._cfg = cfg or llama.tiny(vocab=2048)
+        # the weights, handed in: a pytree in ``llama.init_params``'s
+        # layout for ``cfg`` (already on the device, in the served
+        # type), or a callable that returns one when the model loads.
+        # None: the model makes its own from PRNGKey(0).  A tree handed
+        # as a callable is held under ``_params`` alone, so dropping
+        # that frees the device memory
+        self._params_source = params
+        if params is not None and (quantize or mesh is not None):
+            raise ValueError(
+                "params= hands over weights as they are served: not with "
+                "quantize=True or a mesh, which build their own layout")
+        if not self._cfg.plain and (quantize or mesh is not None):
+            raise llama.UnsupportedArchitecture(
+                "int8 weights and tensor-parallel serving are written "
+                "for the plain Llama / Mistral block")
         # replica identity threaded to the scheduler's fault-injection
         # points (multi-replica chaos harnesses)
         self._fault_scope = fault_scope
@@ -119,6 +134,9 @@ class LlamaGenerateModel(Model):
         # prefill bound, and the radix prefix-cache toggle
         self._page_size = page_size
         self._kv_pages = kv_pages
+        # bound of the window class where the configuration has window
+        # layers (None = every slot a full ring of pages)
+        self._kv_window_pages = kv_window_pages
         self._prefill_chunk_tokens = prefill_chunk_tokens
         self._prefix_cache = prefix_cache
         # default for the per-request ``kv_park`` parameter: park a
@@ -175,6 +193,14 @@ class LlamaGenerateModel(Model):
                     if self._mesh is None:
                         params = jax.device_put(
                             params, jax.devices()[0])
+                elif self._params_source is not None:
+                    source = self._params_source
+                    params = source() if callable(source) else source
+                    if params is None:
+                        raise ValueError(
+                            "model '{}': params= returned no weights "
+                            "(a callable source hands its tree over "
+                            "once)".format(self.name))
                 else:
                     params = llama.init_params(
                         jax.random.PRNGKey(0), self._cfg
@@ -195,6 +221,7 @@ class LlamaGenerateModel(Model):
                         mesh=self._mesh, quantized=self._quantize,
                         page_size=self._page_size,
                         kv_pages=self._kv_pages,
+                        kv_window_pages=self._kv_window_pages,
                     )
                     server = self._server
                     kv_hooks = {}

@@ -87,12 +87,15 @@ def _fold_finish(o_ref, m_scr, l_scr, acc_scr):
 
 def _attn_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, causal,
-    block_q, block_k):
+    block_q, block_k, window=None):
     """One (batch*head, q-block, k-block) program.
 
     q_ref: [block_q, D]; k_ref/v_ref: [block_k, D]; o_ref: [block_q, D];
     scratch m/l: [block_q, 1] fp32, acc: [block_q, D] fp32 — carried
-    across the (sequential) k-block grid dimension.
+    across the (sequential) k-block grid dimension.  With ``window``
+    (causal only) query i sees keys j with 0 <= i - j < window, and K/V
+    blocks wholly behind the window are skipped like those above the
+    diagonal.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -108,6 +111,10 @@ def _attn_kernel(
         if causal
         else True
     )
+    if window is not None:
+        # the block's last key is still inside the first query's window
+        live = jnp.logical_and(
+            live, (ki + 1) * block_k - 1 > qi * block_q - window)
 
     @pl.when(live)
     def _fold():
@@ -124,7 +131,10 @@ def _attn_kernel(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, -jnp.inf)
+            seen = k_pos <= q_pos
+            if window is not None:
+                seen = jnp.logical_and(seen, k_pos > q_pos - window)
+            s = jnp.where(seen, s, -jnp.inf)
         _online_softmax_fold(
             s, m_scr, l_scr, acc_scr,
             lambda p: jnp.dot(
@@ -138,16 +148,21 @@ def _attn_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "scale", "block_q", "block_k", "interpret"),
+    static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
+                     "window"),
 )
 def flash_attention(
     q, k, v, causal=True, scale=None, block_q=128, block_k=128,
-    interpret=None):
+    interpret=None, window=None):
     """Exact attention, q/k/v [B, T, H, D] -> [B, T, H, D].
 
     Drop-in for the XLA attention paths; T must be divisible by
     ``block_q`` and ``block_k`` (pick smaller blocks for short or odd
     sequences).  ``interpret=None`` defers to :func:`kernel_interpret`.
+    ``window`` (causal only): query i attends keys j with
+    0 <= i - j < window; K/V blocks wholly outside a query block's
+    window are neither folded nor fetched (their grid steps point at the
+    nearest live block, which is already resident).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -166,16 +181,26 @@ def flash_attention(
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
 
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k)
+        block_k=block_k, window=window)
+
+    def kv_index(bh, i, j):
+        if window is None:
+            return (bh, j, 0)
+        first = jnp.maximum(i * block_q - window + 1, 0) // block_k
+        last = (i * block_q + block_q - 1) // block_k
+        return (bh, jnp.clip(j, first, last), 0)
+
     out = pl.pallas_call(
         kernel,
         grid=(b * h, t // block_q, t_kv // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((None, block_k, d), kv_index),
+            pl.BlockSpec((None, block_k, d), kv_index),
         ],
         out_specs=pl.BlockSpec(
             (None, block_q, d), lambda bh, i, j: (bh, i, 0)),
@@ -192,12 +217,13 @@ def flash_attention(
 
 def _decode_fold(
     q_ref, k, v, ki, length, m_scr, l_scr, acc_scr, *, scale, block_k,
-    n_rep):
+    n_rep, start=None):
     """Fold K/V block ``ki`` (``k``/``v`` [block_k, Hkv, D], already in
     VMEM) of a row with ``length`` valid positions into the carried
     softmax state.  The one body of both decode kernels: they differ
     only in how the block got into VMEM.  GQA replication happens on
-    the in-VMEM block only."""
+    the in-VMEM block only.  ``start``: the row's first position still
+    inside its window (positions before it are masked)."""
     heads = q_ref.shape[0]
     q = q_ref[:].astype(jnp.float32) * scale          # [H, D]
     k = k.astype(jnp.float32)                         # [bk, Hkv, D]
@@ -210,7 +236,10 @@ def _decode_fold(
     s = jnp.sum(q[None, :, :] * k, axis=-1).T  # [H, bk]
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (heads, k.shape[0]), 1)
-    s = jnp.where(k_pos < length, s, -jnp.inf)
+    seen = k_pos < length
+    if start is not None:
+        seen = jnp.logical_and(seen, k_pos >= start)
+    s = jnp.where(seen, s, -jnp.inf)
     _online_softmax_fold(
         s, m_scr, l_scr, acc_scr,
         lambda p: jnp.sum(p.T[:, :, None] * v, axis=0))
@@ -312,8 +341,7 @@ def decode_attention(
 
 
 def _paged_decode_kernel(
-    len_ref, tbl_ref, layer_ref, q_ref, pages_ref, o_ref, k_buf, v_buf,
-    sems, slot_ref, m_scr, l_scr, acc_scr, *, scale, block_k, n_rep):
+    len_ref, tbl_ref, layer_ref, *refs, scale, block_k, n_rep, windowed):
     """One (row, k-block) program of decode attention over the page pool.
 
     The fold is :func:`_decode_kernel`'s; only the way a K/V block gets
@@ -328,7 +356,19 @@ def _paged_decode_kernel(
     last, is on its way while this one folds.  A row's first block is
     always brought in (even at length 0, where nothing folds), so every
     row has a block to wait for and the hand-over stays regular.
+
+    ``windowed`` (static): a fourth prefetched ref, start_ref [rows],
+    gives each row's first position still inside its window, and a row's
+    table is a RING: logical block ``lb`` of the sequence lives in table
+    block ``lb % nk``, so a row holds one window (plus a block) of pages
+    however long it has grown.  Program ``ki`` of a row then folds
+    logical block ``start // block_k + ki``: blocks wholly behind the
+    window are never brought in.  Without it a row's first block is 0
+    and the table is read straight.
     """
+    start_ref = refs[0] if windowed else None
+    (q_ref, pages_ref, o_ref, k_buf, v_buf, sems, slot_ref, m_scr, l_scr,
+     acc_scr) = refs[1:] if windowed else refs
     b = pl.program_id(0)
     ki = pl.program_id(1)
     rows = pl.num_programs(0)
@@ -338,11 +378,19 @@ def _paged_decode_kernel(
     pages_per_seq = nk * pages_per_block
     layer = layer_ref[0]
     length = len_ref[b]
-    live_blocks = jnp.maximum(
-        jax.lax.div(length + (block_k - 1), block_k), 1)
+    live_blocks = jax.lax.div(length + (block_k - 1), block_k)
+    # first_blk: the row's first live logical block; lb: this program's
+    start, first_blk, lb = None, 0, ki
+    if windowed:
+        start = start_ref[b]
+        first_blk = jax.lax.div(start, block_k)
+        live_blocks = live_blocks - first_blk
+        lb = first_blk + ki
+    live_blocks = jnp.maximum(live_blocks, 1)
 
     def block_copies(row, blk, slot):
-        first = row * pages_per_seq + blk * pages_per_block
+        entry = jax.lax.rem(blk, nk) if windowed else blk
+        first = row * pages_per_seq + entry * pages_per_block
         return [
             pltpu.make_async_copy(
                 pages_ref.at[layer, kv, tbl_ref[first + j]],
@@ -359,7 +407,7 @@ def _paged_decode_kernel(
     @pl.when(jnp.logical_and(b == 0, ki == 0))
     def _first():
         slot_ref[0] = 0
-        for copy in block_copies(0, 0, 0):
+        for copy in block_copies(0, first_blk, 0):
             copy.start()
 
     @pl.when(ki < live_blocks)
@@ -367,22 +415,27 @@ def _paged_decode_kernel(
         slot = slot_ref[0]
         more = ki + 1 < live_blocks
         nxt_row = jnp.where(more, b, b + 1)
-        nxt_blk = jnp.where(more, ki + 1, 0)
+        nxt_first = 0
+        if windowed:
+            nxt_first = jax.lax.div(
+                start_ref[jnp.minimum(b + 1, rows - 1)], block_k)
+        nxt_blk = jnp.where(more, lb + 1, nxt_first)
 
         @pl.when(nxt_row < rows)
         def _prefetch():
             for copy in block_copies(nxt_row, nxt_blk, 1 - slot):
                 copy.start()
 
-        for copy in block_copies(b, ki, slot):
+        for copy in block_copies(b, lb, slot):
             copy.wait()
         slot_ref[0] = 1 - slot
 
-        @pl.when(ki * block_k < length)
+        @pl.when(lb * block_k < length)
         def _fold():
             _decode_fold(
-                q_ref, k_buf[slot], v_buf[slot], ki, length, m_scr, l_scr,
-                acc_scr, scale=scale, block_k=block_k, n_rep=n_rep)
+                q_ref, k_buf[slot], v_buf[slot], lb, length, m_scr, l_scr,
+                acc_scr, scale=scale, block_k=block_k, n_rep=n_rep,
+                start=start)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -393,7 +446,7 @@ def _paged_decode_kernel(
     jax.jit, static_argnames=("scale", "block_k", "interpret"))
 def paged_decode_attention(
     q, pages, layer, page_tables, lengths, scale=None, block_k=256,
-    interpret=None):
+    interpret=None, starts=None):
     """:func:`decode_attention` over a page pool, read in place.
 
     q: [B, H, D]; pages: the whole pool [L, 2, n_pages, page, Hkv, D]
@@ -406,6 +459,14 @@ def paged_decode_attention(
     by page: no gathered ``[B, S, Hkv, D]`` view and no per-layer slice
     of the pool ever exists in HBM.  Same blocks, same order, same fold
     as ``decode_attention`` over the gathered view: bit-equal to it.
+
+    With ``starts`` [B] int32 (the first position of each row still
+    inside its attention window; positions before it are masked and
+    their blocks never read) the page table is a ring of
+    ``pages_per_seq`` pages: logical page ``p`` of a row is table entry
+    ``p % pages_per_seq`` (:func:`_paged_decode_kernel`), so
+    ``lengths`` may pass ``pages_per_seq * page`` while ``lengths -
+    starts`` fits the ring less one block.
     Returns [B, H, D].
     """
     if scale is None:
@@ -424,11 +485,17 @@ def paged_decode_attention(
             "block_k {} must divide the row length {} and hold whole "
             "pages of {}".format(block_k, s, page))
 
+    windowed = starts is not None
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, block_k=block_k,
-        n_rep=h // h_kv)
+        n_rep=h // h_kv, windowed=windowed)
+    prefetch = [lengths.astype(jnp.int32),
+                page_tables.astype(jnp.int32).reshape(-1),
+                jnp.asarray(layer, jnp.int32).reshape(1)]
+    if windowed:
+        prefetch.append(starts.astype(jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, s // block_k),
         in_specs=[
             pl.BlockSpec((None, h, d), lambda b, ki, *refs: (b, 0, 0)),
@@ -455,8 +522,4 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary", "arbitrary")),
         name="paged_decode_attention",
         interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        page_tables.astype(jnp.int32).reshape(-1),
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        q, pages)
+    )(*prefetch, q, pages)

@@ -231,6 +231,10 @@ class _Stream:
         # are tree pages, the rest up to span_pages are owned), and
         # the reserved span in pages
         "table", "radix_nodes", "span_pages",
+        # the window class of a two-class pool: the ring table row
+        # (logical page p -> entry p % ring) and the first logical page
+        # whose fall behind the window has not been handled yet
+        "table_w", "w_done",
         # zero-copy data plane (ISSUE 12): the device-resident prompt
         # view (an XLA-shm segment — cold prefills consume it without
         # host staging), the park-export opt-in, and the attach-resume
@@ -281,6 +285,8 @@ class _Stream:
         self.table = None        # np [pages_per_seq] page-table row
         self.radix_nodes = None  # pinned radix path (prefix pages)
         self.span_pages = 0      # reserved logical pages
+        self.table_w = None      # np [ring] window-class ring row
+        self.w_done = 0          # window pages below this were handled
         self.prompt_dev = prompt_dev  # device prompt view, or None
         self.kv_export = bool(kv_export)
         self.kv_export_on_finish = bool(kv_export_on_finish)
@@ -506,6 +512,15 @@ class DecodeScheduler:
         self._prefix_hits = 0
         self._prefix_misses = 0
         self._prefix_evictions = 0
+        # what the steps attended and routed (loop-written, grow-only):
+        # key positions of all attention layers, those a window layer
+        # did not have to read, and the routed layers' counts fetched
+        # with each step's tokens
+        self._context_tokens = 0
+        self._window_skipped_tokens = 0
+        self._moe_layer_steps = 0
+        self._moe_local_pairs = 0
+        self._moe_experts_hit = 0
         # speculative decoding (ISSUE 19): draft up to ``spec_tokens``
         # candidate continuation tokens per slot per step from the
         # radix prefix cache (tpuserver.speculative.NgramDrafter) and
@@ -521,6 +536,14 @@ class DecodeScheduler:
         if spec_tokens is None:
             spec_tokens = int(os.environ.get("TPUSERVER_SPEC_TOKENS", "0"))
         self._spec_tokens = max(0, int(spec_tokens))
+        # a pool of two page classes (window layers:
+        # llama.make_scheduler_fns "window_class"): what still assumes
+        # one table a sequence is refused by name, here and in submit
+        self._window_class = (fns or {}).get("window_class")
+        if self._window_class and self._spec_tokens:
+            raise self._unsupported(
+                "speculative decoding (spec_tokens={})".format(
+                    self._spec_tokens))
         if self._spec_tokens and "spec_step" not in (fns or {}):
             # bundle has no multi-token verify step (stub fns in
             # tests, older model builds): degrade to the plain path
@@ -552,6 +575,8 @@ class DecodeScheduler:
         # (allocator, radix) of the CURRENT loop, for stats/gauges
         # (a restart rebuilds both with the device pool)
         self._pager = None  # guarded-by: _cond
+        # the CURRENT loop's window-class allocator (two-class pools)
+        self._window_alloc = None  # guarded-by: _cond
         # optional tpuserver.metrics latency histograms: the decode
         # loop is their ONLY writer, so single_writer children observe
         # lock-free (exact, and never a lock acquisition in _loop)
@@ -577,6 +602,14 @@ class DecodeScheduler:
         # seconds of the decode loop thread's life by phase (_LoopClock;
         # stats()["loop_seconds"], tpu_scheduler_loop_seconds_total)
         self._loop_seconds = dict.fromkeys(LOOP_PHASES, 0.0)
+
+    @staticmethod
+    def _unsupported(what):
+        from tpuserver.models.llama import UnsupportedArchitecture
+
+        return UnsupportedArchitecture(
+            "{} is not served over a pool of two page classes (window "
+            "layers): it assumes one page table a sequence".format(what))
 
     # -- frontend side -----------------------------------------------------
 
@@ -614,6 +647,16 @@ class DecodeScheduler:
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("PROMPT_IDS must be non-empty")
+        if self._window_class:
+            for asked, what in (
+                    (resume_cache is not None or on_finish is not None,
+                     "park / resume of a KV cache (kv_cache_region)"),
+                    (kv_export or kv_export_on_finish,
+                     "KV export (kv_park / kv_phase=prefill)"),
+                    (attach_cache is not None,
+                     "KV attach (kv_attach)")):
+                if asked:
+                    raise self._unsupported(what)
         start = resume_pos if resume_cache is not None else 0
         if start + len(prompt) + max_tokens > self._max_seq:
             raise ValueError(
@@ -921,6 +964,12 @@ class DecodeScheduler:
                 pages_total = int(fns.get("n_pages", 0) or 0)
                 pages_free = pages_total
                 pages_cached = 0
+            window_total = window_free = 0
+            if self._window_class:
+                window_total = self._window_class["n_pages"]
+                window_free = (self._window_alloc.free_count
+                               if self._window_alloc is not None
+                               else window_total)
             return {
                 "live_streams": len(self._streams),
                 "pending": len(self._pending),
@@ -953,6 +1002,14 @@ class DecodeScheduler:
                 "pages_total": pages_total,
                 "pages_free": pages_free,
                 "pages_cached": pages_cached,
+                # the window class of a two-class pool (0 / 0 without)
+                "window_pages_total": window_total,
+                "window_pages_free": window_free,
+                "context_tokens": self._context_tokens,
+                "window_skipped_tokens": self._window_skipped_tokens,
+                "moe_layer_steps": self._moe_layer_steps,
+                "moe_local_pairs": self._moe_local_pairs,
+                "moe_experts_hit": self._moe_experts_hit,
                 "loop_seconds": dict(self._loop_seconds),
                 # a fact of the build, not a rate: which decode
                 # attention the step executable holds
@@ -1110,6 +1167,8 @@ class DecodeScheduler:
         stream.table = None
         stream.radix_nodes = None
         stream.span_pages = 0
+        stream.table_w = None
+        stream.w_done = 0
         # a pending attach-resume dies with the loop that would have
         # scattered it: the salvage re-admission falls back to the
         # re-prefill path (greedy decode makes both token-identical)
@@ -1254,11 +1313,25 @@ class DecodeScheduler:
         alloc = PageAllocator(n_pages, page)
         radix = (RadixPrefixCache(page)
                  if self._prefix_cache and span_safe else None)
+        # the window class of a two-class pool: its own allocator and
+        # ring tables (entry p % ring names logical page p); wc is None
+        # for every one-class configuration, whose loop is unchanged
+        wc = self._window_class
+        if wc:
+            window, ring, n_wpages = wc["window"], wc["ring"], wc["n_pages"]
+            alloc_w = PageAllocator(n_wpages, page)
+            tables_w = np.full((self._max_slots, ring), n_wpages, np.int32)
+            n_layers_w = int(pages["window"].shape[0])
+            n_layers_all = n_layers_w + int(pages["full"].shape[0])
+        else:
+            alloc_w = None
+            n_layers_all = int(getattr(pages, "shape", (0,))[0])
         with self._cond:
             # stats/gauges read the live pool through this reference;
             # a supervised restart rebuilds pool, allocator and radix
             # together (the radix cache restarts cold and re-warms)
             self._pager = (alloc, radix)
+            self._window_alloc = alloc_w
         # per-slot page tables, re-scattered to the device each step
         # (sentinel rows are inert); mutated in place as slots turn
         # over — each dispatch converts the then-current content
@@ -1280,6 +1353,31 @@ class DecodeScheduler:
             slots[slot] = None
             ready[slot] = False
             tables[slot] = n_pages
+            if wc:
+                tables_w[slot] = n_wpages
+
+        def free_window_pages(stream):
+            """Everything the stream still holds in the window class."""
+            if stream.table_w is not None:
+                alloc_w.free(
+                    int(p) for p in stream.table_w if p != n_wpages)
+                stream.table_w = None
+
+        def move_window(slot, stream):
+            """Before a step at ``stream.pos``: window pages that now
+            lie wholly behind the window go back to the allocator,
+            unless the ring will need their entry again (logical page
+            p + ring is still inside the reserved span: the entry is
+            reused in place, as it was reserved for)."""
+            dead = max(0, stream.pos + 1 - window) // page
+            for p in range(stream.w_done, dead):
+                if p + ring >= stream.span_pages:
+                    entry = p % ring
+                    if stream.table_w[entry] != n_wpages:
+                        alloc_w.free([int(stream.table_w[entry])])
+                        stream.table_w[entry] = n_wpages
+                        tables_w[slot, entry] = n_wpages
+            stream.w_done = max(stream.w_done, dead)
 
         def superseded():
             """True once a watchdog demotion replaced this loop: a
@@ -1305,6 +1403,8 @@ class DecodeScheduler:
                     # touching stream.table/radix_nodes here would
                     # corrupt it (this loop's own pool dies with it)
                     return
+            if wc:
+                free_window_pages(stream)
             table = stream.table
             nodes = stream.radix_nodes or []
             if table is None:
@@ -1390,6 +1490,8 @@ class DecodeScheduler:
                     alloc.free(dup_ids)
                     stream.radix_nodes.extend(appended)
             tables[slot] = stream.table
+            if wc:
+                tables_w[slot] = stream.table_w
             ready[slot] = True
             self._admitted_total += 1
             if self._queue_hist is not None:
@@ -1515,6 +1617,29 @@ class DecodeScheduler:
                     if radix is not None:
                         self._prefix_hits += shared_len
                     self._prefix_misses += prefill_len - shared_len
+                dest_w = None
+                if wc:
+                    # the window class: the prompt's last window and
+                    # what decode will add, at most one ring; the
+                    # prompt's pages behind the window are never held
+                    first_w = max(0, prefill_len + 1 - window) // page
+                    need_w = min(span_pages, first_w + ring) - first_w
+                    held_w = alloc_w.alloc(need_w)
+                    if held_w is None:
+                        alloc.free(owned)
+                        self._fail(stream, AdmissionQueueFull(
+                            "kv page pool exhausted: admission needs {} "
+                            "window-class pages but only {} are free; "
+                            "retry later".format(
+                                need_w, alloc_w.free_count)), epoch)
+                        clear_slot(slot)
+                        return
+                    stream.table_w = np.full((ring,), n_wpages, np.int32)
+                    dest_w = np.full((ppseq,), n_wpages, np.int32)
+                    for n, pid in enumerate(held_w):
+                        stream.table_w[(first_w + n) % ring] = pid
+                        dest_w[first_w + n] = pid
+                    stream.w_done = first_w
                 table = np.full((ppseq,), n_pages, np.int32)
                 for d, node in enumerate(matched_nodes):
                     table[d] = node.page
@@ -1614,6 +1739,8 @@ class DecodeScheduler:
                     if superseded():
                         return  # demoted mid-dispatch: mutate nothing
                 stream.pos = prefill_len
+                if wc:
+                    dest = {"full": dest, "window": dest_w}
                 pages, logits = fns["admit"](
                     pages, logits, slot_cache, slot_logits, dest, slot)
                 complete_admission(slot, stream, full)
@@ -1913,7 +2040,7 @@ class DecodeScheduler:
                         # nobody drafted (cold caches, all throttled): a
                         # plain sub-step costs spec_k fewer weight passes
                         # and is bitwise-identical for the one token
-                        toks_dev, lps_dev, logits, pages = fns["step"](
+                        toks_dev, lps_dev, logits, pages, *_ = fns["step"](
                             self._params, pages, logits, tables, positions,
                             active, forced_tok, forced_mask,
                         )
@@ -2021,6 +2148,7 @@ class DecodeScheduler:
                     forced_tok = np.zeros((self._max_slots,), np.int32)
                     forced_mask = np.zeros((self._max_slots,), bool)
                     snapshot = []
+                    context = skipped = 0
                     for i in active_ids:
                         st = slots[i]
                         positions[i] = st.pos
@@ -2030,7 +2158,16 @@ class DecodeScheduler:
                             forced_tok[i] = st.forced.popleft()
                             forced_mask[i] = True
                         snapshot.append((i, st, was_forced, st.incarnation))
+                        context += st.pos + 1
+                        if wc:
+                            move_window(i, st)
+                            skipped += max(0, st.pos + 1 - window)
                         st.pos += 1
+                    # key positions this step's attention layers cover,
+                    # and those its window layers need not read
+                    self._context_tokens += context * n_layers_all
+                    if wc:
+                        self._window_skipped_tokens += skipped * n_layers_w
                     # chaos hook: "scheduler.step" raise = loop death (the
                     # supervised-restart path), sleep = slow step, nan =
                     # poison one slot's logits row (the quarantine path),
@@ -2046,9 +2183,16 @@ class DecodeScheduler:
                     self._beat(epoch, step_start)
                     if action is not None and action[0] == "hang":
                         time.sleep(action[1])
-                    tokens_dev, logps_dev, logits, pages = fns["step"](
-                        self._params, pages, logits, tables, positions,
-                        active, forced_tok, forced_mask,
+                    tokens_dev, logps_dev, logits, pages, *moe_dev = fns[
+                        "step"](
+                        self._params, pages, logits,
+                        # the ring rows are rewritten while earlier steps
+                        # are still in flight (move_window): this step gets
+                        # its own copy (a CPU backend may alias numpy
+                        # memory instead of copying it)
+                        {"full": tables, "window": tables_w.copy()} if wc
+                        else tables,
+                        positions, active, forced_tok, forced_mask,
                     )
                     self._beat(epoch, None)
                     if self._step_hist is not None:
@@ -2056,16 +2200,26 @@ class DecodeScheduler:
                         # lock per step just to be observable
                         self._step_hist.observe(
                             time.monotonic() - step_start)
-                    current = (tokens_dev, logps_dev, snapshot)
+                    # a routed configuration's step has a fifth result
+                    current = (tokens_dev, logps_dev, snapshot,
+                               moe_dev[0] if moe_dev else None)
 
             if inflight is not None:
-                tokens_dev, logps_dev, snapshot = inflight
+                tokens_dev, logps_dev, snapshot, moe_dev = inflight
                 with phase("fetch"):
                     # host-transfer chaos; a raise is loop death (restart)
                     fetch_chaos()
                     self._beat(epoch, time.monotonic())
                     toks = np.asarray(tokens_dev)
                     lps = np.asarray(logps_dev)
+                    if moe_dev is not None:
+                        # three integers of the routed layers, fetched
+                        # with the step's tokens
+                        layer_steps, pairs, hit = (
+                            int(n) for n in np.asarray(moe_dev))
+                        self._moe_layer_steps += layer_steps
+                        self._moe_local_pairs += pairs
+                        self._moe_experts_hit += hit
                     self._beat(epoch, None)
                 with phase("deliver"):
                     quarantined = []
