@@ -12,8 +12,9 @@ network beyond loopback, fixed seeds, random weights.  In order:
              first device is a TPU the peaks table knows
   kernels    flash_attention and decode_attention, Mosaic, at the
              llama3_3b head geometry, and paged_decode_attention at the
-             benchmark cell's (16 rows x 2560, pages of 16, 32/8 heads
-             of 128), against float32 host references
+             benchmark cells' (16 rows x 2560, pages of 16, 32/8 heads
+             of 128; 48/8 heads with ``starts=`` over a wrapped ring of
+             a 4,096-token window), against float32 host references
   setup      InferenceServer(default models + ResNet-50 + llama3_3b on
              the continuous-batching scheduler) behind real HTTP and
              gRPC frontends; warm-up requests carry the compiles
@@ -80,18 +81,22 @@ class Size:
     kv_heads: int
     head_dim: int
     seq: int            # kernel phase: flash T and decode cache length
-    paged: tuple        # kernel phase, paged decode: (rows, max_seq,
-                        # page, query heads) over kv_heads of head_dim
+    paged: tuple        # kernel phase, paged decode: (rows, table
+                        # tokens, page, query heads, window or None)
+                        # each, over kv_heads of head_dim; with a window
+                        # the table is a ring and rows grow to twice it
     max_seq: int        # served llama
     prompt_lens: tuple  # (flash, flash, dense) prompt lengths
     max_tokens: int
 
 
 CHIP = Size(heads=24, kv_heads=8, head_dim=128, seq=2048,
-            paged=(16, 2560, 16, 32), max_seq=2048,
+            paged=((16, 2560, 16, 32, None), (16, 4352, 16, 48, 4096)),
+            max_seq=2048,
             prompt_lens=(512, 1536, 200), max_tokens=32)
 DRY = Size(heads=4, kv_heads=2, head_dim=32, seq=512,
-           paged=(10, 512, 16, 4), max_seq=512,
+           paged=((10, 512, 16, 4, None), (12, 512, 16, 6, 200)),
+           max_seq=512,
            prompt_lens=(128, 384, 50), max_tokens=8)
 MAX_SLOTS = 8
 
@@ -201,39 +206,55 @@ def phase_kernels(size, interpret):
     kf, vf = np.asarray(kc, np.float32), np.asarray(vc, np.float32)
     _check_decode_rows(
         "decode_attention over S={}".format(t), got, qd, lengths,
-        lambda b: (kf[b], vf[b]))
+        lambda b: (kf[b, :lengths[b]], vf[b, :lengths[b]]))
 
     # the served decode attention: the same fold over a page pool read
-    # in place, at the benchmark cell's geometry; every row's pages are
-    # scattered over the pool, entries past a row's length are the
-    # clipped sentinel (the last page), layer 1 of 2 is attended
-    rows, seq, page, h = size.paged
-    ppseq = seq // page
-    n_pages = rows * ppseq
-    lengths = np.array(
-        ([1, page, page + 1, 255, 256, 257, seq // 2 - 24, seq - 1, seq]
-         + [int(n) for n in rng.randint(1, seq, rows)])[:rows], np.int32)
-    tables = rng.permutation(n_pages).reshape(rows, ppseq).astype(np.int32)
-    live = np.arange(ppseq)[None, :] * page < lengths[:, None]
-    tables = np.where(live, tables, n_pages - 1)
-    qd = bf16((rows, h, d))
-    pool = bf16((2, 2, n_pages, page, hkv, d))
-    got = np.asarray(
-        paged_decode_attention(qd, pool, 1, jnp.asarray(tables),
-                               jnp.asarray(lengths), interpret=interpret),
-        np.float32)
-    pf = np.asarray(pool[1], np.float32)
-    _check_decode_rows(
-        "paged_decode_attention over {} pages of {}".format(n_pages, page),
-        got, qd, lengths,
-        lambda b: (pf[0][tables[b]].reshape(seq, hkv, d),
-                   pf[1][tables[b]].reshape(seq, hkv, d)))
+    # in place, at the benchmark cells' geometries; every row's pages are
+    # scattered over the pool, layer 1 of 2 is attended.  Straight table:
+    # entries past a row's length are the clipped sentinel (the last
+    # page).  With a window the table is a ring (logical page p in entry
+    # p % entries), rows reach twice the window, and ``starts=`` masks
+    # what lies before it.
+    for rows, seq, page, h, window in size.paged:
+        ppseq = seq // page
+        n_pages = rows * ppseq
+        top = seq if window is None else 2 * window + 512
+        lengths = np.array(
+            ([1, page, page + 1, 255, 256, 257, seq // 2 - 24, seq - 1, seq,
+              top - 255, top]
+             + [int(n) for n in rng.randint(1, top, rows)])[:rows], np.int32)
+        starts = np.maximum(lengths - (window or top), 0)
+        tables = rng.permutation(n_pages).reshape(
+            rows, ppseq).astype(np.int32)
+        if window is None:
+            live = np.arange(ppseq)[None, :] * page < lengths[:, None]
+            tables = np.where(live, tables, n_pages - 1)
+        qd = bf16((rows, h, d))
+        pool = bf16((2, 2, n_pages, page, hkv, d))
+        got = np.asarray(
+            paged_decode_attention(
+                qd, pool, 1, jnp.asarray(tables), jnp.asarray(lengths),
+                interpret=interpret,
+                starts=None if window is None else jnp.asarray(starts)),
+            np.float32)
+        pf = np.asarray(pool[1], np.float32)
+
+        def row_kv(b):
+            pos = np.arange(starts[b], lengths[b])
+            entry = tables[b][pos // page % ppseq]
+            return pf[0][entry, pos % page], pf[1][entry, pos % page]
+
+        _check_decode_rows(
+            "paged_decode_attention over {} pages of {}{}".format(
+                n_pages, page,
+                "" if window is None else ", window {}".format(window)),
+            got, qd, lengths, row_kv)
 
 
 def _check_decode_rows(name, got, q, lengths, row_kv):
     """``got`` [rows, H, D] against single-query softmax attention on
-    the host in float32; ``row_kv(b)`` -> that row's K and V
-    [S, Hkv, D] float32, of which ``lengths[b]`` positions are valid."""
+    the host in float32; ``row_kv(b)`` -> K and V [n, Hkv, D] float32 of
+    the positions row ``b`` attends (``lengths[b]`` is for the log)."""
     import numpy as np
 
     check(np.isfinite(got).all(), "{}: non-finite".format(name))
@@ -241,7 +262,7 @@ def _check_decode_rows(name, got, q, lengths, row_kv):
     h, d = qf.shape[1:]
     worst = 0.0
     for b, n in enumerate(lengths):
-        kb, vb = (np.repeat(x[:n], h // x.shape[1], axis=1)   # [n, H, D]
+        kb, vb = (np.repeat(x, h // x.shape[1], axis=1)       # [n, H, D]
                   for x in row_kv(b))
         s = np.einsum("hd,nhd->hn", qf[b], kb) / np.sqrt(d)
         p = np.exp(s - s.max(-1, keepdims=True))

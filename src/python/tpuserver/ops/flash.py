@@ -10,6 +10,12 @@ steps; accumulation is fp32 (MXU-native via preferred_element_type)
 regardless of input dtype, and causal query blocks skip fully-masked
 K/V blocks via predication.
 
+The decode kernels (``decode_attention`` over a padded cache,
+``paged_decode_attention`` over the page pool read in place) answer one
+query a row with every head at once and share one body,
+:func:`_decode_fold`: two MXU dots a block over the block as it lies in
+the cache, grouped-query attention being a mask on the scores.
+
 Kernel mode (Mosaic or the Pallas interpreter) is decided in one place,
 :func:`kernel_interpret`: a process states it with
 :func:`set_kernel_mode` (chip entry points state Mosaic through
@@ -218,31 +224,40 @@ def flash_attention(
 def _decode_fold(
     q_ref, k, v, ki, length, m_scr, l_scr, acc_scr, *, scale, block_k,
     n_rep, start=None):
-    """Fold K/V block ``ki`` (``k``/``v`` [block_k, Hkv, D], already in
-    VMEM) of a row with ``length`` valid positions into the carried
-    softmax state.  The one body of both decode kernels: they differ
-    only in how the block got into VMEM.  GQA replication happens on
-    the in-VMEM block only.  ``start``: the row's first position still
-    inside its window (positions before it are masked)."""
-    heads = q_ref.shape[0]
-    q = q_ref[:].astype(jnp.float32) * scale          # [H, D]
-    k = k.astype(jnp.float32)                         # [bk, Hkv, D]
-    v = v.astype(jnp.float32)
-    if n_rep > 1:  # GQA: expand kv heads inside VMEM only
-        k = jnp.repeat(k, n_rep, axis=1)              # [bk, H, D]
-        v = jnp.repeat(v, n_rep, axis=1)
-    # Mosaic-friendly batched vec-mat: elementwise multiply +
-    # reduce on the VPU (the head-batched dot_general does not lower)
-    s = jnp.sum(q[None, :, :] * k, axis=-1).T  # [H, bk]
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (heads, k.shape[0]), 1)
-    seen = k_pos < length
+    """Fold K/V block ``ki`` of a row with ``length`` valid positions
+    into the carried softmax state, on the MXU.  The one body of both
+    decode kernels: they differ only in how the block got into VMEM.
+
+    ``k``/``v`` are the block as it lies in the cache, viewed 2-D:
+    [block_k * Hkv, D], column ``c`` holding position ``c // Hkv`` of KV
+    head ``c % Hkv``.  Two dots a block, operands in the cache's dtype,
+    fp32 accumulation: scores ``q . k^T`` [H, block_k * Hkv] of EVERY
+    query head against every KV head, then the columns of another
+    head's group masked to -inf with the positions past ``length`` (and
+    before ``start``, the row's first position still inside its
+    window).  Their probabilities are exact zeros, so ``p . v`` is
+    already the grouped [H, D]: GQA costs the MXU Hkv times the needed
+    products and no repeat, relayout or per-head slice of the block.
+    """
+    heads, cols = q_ref.shape[0], k.shape[0]
+    h_kv = heads // n_rep
+    s = jax.lax.dot_general(
+        q_ref[:].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale   # [H, bk * Hkv]
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    group = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0), n_rep)
+    # position c // Hkv < length  <=>  c < (length - first) * Hkv
+    first = ki * block_k
+    seen = col < (length - first) * h_kv
     if start is not None:
-        seen = jnp.logical_and(seen, k_pos >= start)
+        seen = jnp.logical_and(seen, col >= (start - first) * h_kv)
+    seen = jnp.logical_and(seen, jax.lax.rem(col, h_kv) == group)
     s = jnp.where(seen, s, -jnp.inf)
     _online_softmax_fold(
         s, m_scr, l_scr, acc_scr,
-        lambda p: jnp.sum(p.T[:, :, None] * v, axis=0))
+        lambda p: jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32))
 
 
 def _decode_kernel(
@@ -251,9 +266,9 @@ def _decode_kernel(
     """One (batch, k-block) program of single-query decode attention.
 
     len_ref: scalar-prefetch [batch] int32 valid lengths; q_ref: [H, D]
-    (every query head of this batch row); k_ref/v_ref: [block_k, Hkv, D]
-    cache slices; scratch m/l: [H, 1] fp32, acc: [H, D] fp32 carried
-    across k blocks.
+    (every query head of this batch row); k_ref/v_ref:
+    [block_k * Hkv, D] cache slices (:func:`_decode_fold`'s 2-D view);
+    scratch m/l: [H, 1] fp32, acc: [H, D] fp32 carried across k blocks.
     """
     b = pl.program_id(0)
     ki = pl.program_id(1)
@@ -285,8 +300,8 @@ def decode_attention(
 
     q: [B, H, D] (the current token's queries); k_cache/v_cache:
     [B, S, Hkv, D] with valid prefix ``lengths`` [B] int32; GQA
-    replication (H = Hkv * n_rep) happens on in-VMEM blocks only — the
-    expanded cache never exists in HBM.  Returns [B, H, D].
+    (H = Hkv * n_rep) is a mask on the scores (:func:`_decode_fold`) —
+    no expanded cache exists anywhere.  Returns [B, H, D].
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -311,7 +326,7 @@ def decode_attention(
         live_blocks = jax.lax.div(
             len_ref[b] + (block_k - 1), block_k)
         ki_eff = jnp.minimum(ki, jnp.maximum(live_blocks - 1, 0))
-        return (b, ki_eff, 0, 0)
+        return (b, ki_eff, 0)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=block_k, n_rep=n_rep)
@@ -320,8 +335,8 @@ def decode_attention(
         grid=(b, s // block_k),
         in_specs=[
             pl.BlockSpec((None, h, d), lambda b, ki, *refs: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, h_kv, d), _kv_index),
-            pl.BlockSpec((None, block_k, h_kv, d), _kv_index),
+            pl.BlockSpec((None, block_k * h_kv, d), _kv_index),
+            pl.BlockSpec((None, block_k * h_kv, d), _kv_index),
         ],
         out_specs=pl.BlockSpec(
             (None, h, d), lambda b, ki, *refs: (b, 0, 0)),
@@ -331,13 +346,14 @@ def decode_attention(
             pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    # the fold's 2-D view of a block: merging adjacent dims moves nothing
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
-    return out
+    )(lengths.astype(jnp.int32), q, k_cache.reshape(b, s * h_kv, d),
+      v_cache.reshape(b, s * h_kv, d))
 
 
 def _paged_decode_kernel(
@@ -347,9 +363,9 @@ def _paged_decode_kernel(
     The fold is :func:`_decode_kernel`'s; only the way a K/V block gets
     into VMEM differs.  len_ref [rows], tbl_ref [rows * pages_per_seq]
     (row-major page table, entries in [0, n_pages)) and layer_ref [1]
-    are scalar-prefetched; pages_ref is the WHOLE pool
-    [L, 2, n_pages, page, Hkv, D], left where it lives.  k_buf/v_buf
-    [2, block_k, Hkv, D] are two VMEM slots, each filled by one DMA a
+    are scalar-prefetched; pages_ref is the WHOLE pool, left where it
+    lives, viewed [L, 2, n_pages, page * Hkv, D].  k_buf/v_buf
+    [2, block_k * Hkv, D] are two VMEM slots, each filled by one DMA a
     page; sems [2 (k, v), 2 (slot)]; slot_ref [1] SMEM says which slot
     holds the current block.  Both grid dimensions run in order, so the
     block after this one, the next row's first where this is the row's
@@ -373,8 +389,8 @@ def _paged_decode_kernel(
     ki = pl.program_id(1)
     rows = pl.num_programs(0)
     nk = pl.num_programs(1)
-    page = pages_ref.shape[3]
-    pages_per_block = block_k // page
+    page_rows = pages_ref.shape[3]                    # page * Hkv
+    pages_per_block = k_buf.shape[1] // page_rows
     pages_per_seq = nk * pages_per_block
     layer = layer_ref[0]
     length = len_ref[b]
@@ -394,7 +410,7 @@ def _paged_decode_kernel(
         return [
             pltpu.make_async_copy(
                 pages_ref.at[layer, kv, tbl_ref[first + j]],
-                buf.at[slot, pl.ds(j * page, page)],
+                buf.at[slot, pl.ds(j * page_rows, page_rows)],
                 sems.at[kv, slot])
             for j in range(pages_per_block)
             for kv, buf in ((0, k_buf), (1, v_buf))
@@ -504,8 +520,8 @@ def paged_decode_attention(
         out_specs=pl.BlockSpec(
             (None, h, d), lambda b, ki, *refs: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, block_k, h_kv, d), pages.dtype),
-            pltpu.VMEM((2, block_k, h_kv, d), pages.dtype),
+            pltpu.VMEM((2, block_k * h_kv, d), pages.dtype),
+            pltpu.VMEM((2, block_k * h_kv, d), pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -513,6 +529,9 @@ def paged_decode_attention(
             pltpu.VMEM((h, d), jnp.float32),
         ],
     )
+    # the fold's 2-D view of a page: merging adjacent dims moves nothing
+    # (the compiled step holds a bitcast of the pool, no copy)
+    pages = pages.reshape(*pages.shape[:3], page * h_kv, d)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

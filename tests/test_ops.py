@@ -297,3 +297,97 @@ def test_paged_decode_attention_refuses_a_block_of_broken_pages():
     with pytest.raises(ValueError, match="whole pages"):
         paged_decode_attention(
             q, pool, 0, jnp.array(tables), jnp.array(lengths), block_k=32)
+
+
+# -- the fold on the MXU, at the served head geometries ----------------------
+
+
+def _ring_reference(q, pool, layer, tables, lengths, starts, page):
+    """Float64 single-query attention of every row over positions
+    ``[start, length)``, position ``p`` read through the table as a ring
+    (entry ``p // page % entries``; a straight table never wraps).  A row
+    that attends nothing gives zeros, as the kernel does."""
+    q, kv = np.asarray(q, np.float64), np.asarray(pool[layer], np.float64)
+    rows, h, d = q.shape
+    out = np.zeros((rows, h, d))
+    for b in range(rows):
+        pos = np.arange(starts[b], lengths[b])
+        if not pos.size:
+            continue
+        entry = tables[b][pos // page % tables.shape[1]]
+        k, v = (np.repeat(x[entry, pos % page], h // kv.shape[3], axis=1)
+                for x in kv)                               # [n, H, D]
+        s = np.einsum("hd,nhd->hn", q[b], k) / np.sqrt(d)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b] = np.einsum("hn,nhd->hd", p / p.sum(-1, keepdims=True), v)
+    return out
+
+
+# bf16 in and out: the result is rounded to 8 bits (values reach ~4: a
+# row of length 1 returns V itself), and so are the probabilities
+# before the value product; the products accumulate in float32
+SERVED_TOL = dict(rtol=2e-2, atol=2e-2)
+SERVED_HEADS = {"mistral_32_8": (32, 8), "trinity_48_8": (48, 8),
+                "no_gqa_8_8": (8, 8)}
+
+
+@pytest.mark.parametrize("table", ["straight", "ring_wrapped"])
+@pytest.mark.parametrize("heads", sorted(SERVED_HEADS))
+def test_paged_decode_attention_at_served_geometry(heads, table):
+    """H/Hkv/D as served (32/8/128, 48/8/128; 8/8: no grouping), bf16,
+    the step's block of 256 over pages of 16, against float64 dense
+    attention: lengths 0, 1, on and around block edges; and the same
+    through ``starts=`` over a ring of 3 blocks whose rows have grown
+    past it, so logical blocks land on reused entries."""
+    from tpuserver.ops import paged_decode_attention
+
+    h, hkv = SERVED_HEADS[heads]
+    d, page, block, window = 128, 16, 256, 300
+    if table == "straight":
+        lengths = np.array([0, 1, 255, 256, 257, 512, 700], np.int32)
+        starts = np.zeros_like(lengths)
+    else:
+        lengths = np.array([0, 1, 256, 557, 768, 769, 1025, 1500], np.int32)
+        starts = np.maximum(lengths - window, 0)
+    q, pool, tables, _ = _paged_case(
+        h=h, hkv=hkv, d=d, page=page, ppseq=3 * block // page,
+        lengths=lengths, dtype=jnp.bfloat16, seed=21)
+    got = paged_decode_attention(
+        q, pool, 1, jnp.array(tables), jnp.array(lengths), block_k=block,
+        starts=None if table == "straight" else jnp.array(starts))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float64),
+        _ring_reference(q, pool, 1, tables, lengths, starts, page),
+        **SERVED_TOL)
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+def test_decode_fold_masks_after_the_products(windowed):
+    """The dots run over the whole block before the mask: what the last
+    live block holds past ``length`` (and the first holds before
+    ``start``), under every KV head, must weigh exactly nothing.  Large
+    finite values there change no bit."""
+    from tpuserver.ops import paged_decode_attention
+
+    page, block = 16, 64
+    lengths = np.array([1, 37, 64, 70, 150], np.int32)
+    starts = (np.array([0, 5, 33, 40, 101], np.int32) if windowed
+              else np.zeros_like(lengths))
+    q, pool, tables, _ = _paged_case(
+        h=6, hkv=2, d=16, page=page, ppseq=3 * block // page,
+        lengths=lengths, dtype=jnp.bfloat16, seed=22)
+    pos = np.arange(tables.shape[1] * page)
+    outs = []
+    for fill in (0.0, 1e4, -1e4):
+        filled = pool
+        for b, (lo, hi) in enumerate(zip(starts, lengths)):
+            dead = pos[(pos < lo) | (pos >= hi)]
+            filled = filled.at[:, :, tables[b][dead // page],
+                               dead % page].set(fill)
+        outs.append(np.asarray(paged_decode_attention(
+            q, filled, 1, jnp.array(tables), jnp.array(lengths),
+            block_k=block,
+            starts=jnp.array(starts) if windowed else None), np.float32))
+    assert np.abs(outs[0]).max() > 0.1
+    np.testing.assert_array_equal(outs[0], outs[1])
+    np.testing.assert_array_equal(outs[0], outs[2])
