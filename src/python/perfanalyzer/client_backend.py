@@ -104,16 +104,6 @@ class ClientBackend:
         replicas."""
         return None
 
-    def spec_snapshot(self):
-        """Cumulative speculative-decoding counters ``{"steps": n,
-        "proposed": tokens, "accepted": tokens}`` from the target's
-        telemetry (``tpu_spec_*``), or None when the transport cannot
-        reach them (or the target predates speculation).  Against a
-        fleet router the counters are the churn-safe FLEET aggregate,
-        so the generation profiler's window delta is the fleet-wide
-        acceptance rate."""
-        return None
-
     # -- inference --------------------------------------------------------
 
     def prepare(self, model, input_sets):
@@ -403,19 +393,6 @@ class InProcessBackend(ClientBackend):
                 misses += _coerce_int(stats.get("prefix_misses"))
         return {"hits": hits, "misses": misses} if seen else None
 
-    def spec_snapshot(self):
-        steps = proposed = accepted = 0
-        seen = False
-        for stats in (self.core.health_snapshot().get("models")
-                      or {}).values():
-            if isinstance(stats, dict) and "spec_steps" in stats:
-                seen = True
-                steps += _coerce_int(stats.get("spec_steps"))
-                proposed += _coerce_int(stats.get("spec_proposed"))
-                accepted += _coerce_int(stats.get("spec_accepted"))
-        return ({"steps": steps, "proposed": proposed,
-                 "accepted": accepted} if seen else None)
-
 
 # -- socket-backend shared shm support --------------------------------------
 
@@ -610,28 +587,6 @@ class HttpBackend(_TritonClientShmMixin, ClientBackend):
             fam = families.get(fam_name)
             if fam is None:
                 return None  # pre-paging server: no column
-            out[key] = int(sum(v for _, _, v in fam["samples"]))
-        return out
-
-    def spec_snapshot(self):
-        """The target's ``/metrics`` speculative-decoding counters
-        summed across label sets — against a router this is the fleet
-        aggregate (replica restarts and churn already folded in)."""
-        from tpuserver.metrics import parse_prometheus_text
-
-        got = self._http_get("/metrics")
-        if got is None or got[0] != 200:
-            return None
-        families = parse_prometheus_text(
-            got[1].decode("utf-8", errors="replace"))
-        out = {}
-        for key, fam_name in (
-                ("steps", "tpu_spec_steps_total"),
-                ("proposed", "tpu_spec_tokens_proposed_total"),
-                ("accepted", "tpu_spec_tokens_accepted_total")):
-            fam = families.get(fam_name)
-            if fam is None:
-                return None  # pre-speculation server: no column
             out[key] = int(sum(v for _, _, v in fam["samples"]))
         return out
 

@@ -246,9 +246,6 @@ class GenerationProfiler:
         # report's hit-rate column — post-warmup, so compile-time
         # admissions stay out of the rate
         prefix_before = self.backend.prefix_cache_snapshot()
-        # speculative-decoding counters: the level delta becomes the
-        # accepted-per-step and draft-hit-rate columns
-        spec_before = self.backend.spec_snapshot()
         windows = []
         stable = False
         interrupted = False
@@ -306,20 +303,6 @@ class GenerationProfiler:
             result["prefix_cache_misses"] = dm
             result["prefix_hit_pct"] = (
                 100.0 * dh / (dh + dm) if dh + dm else None)
-        spec_after = self.backend.spec_snapshot()
-        if spec_before is not None and spec_after is not None:
-            ds = max(0, spec_after["steps"] - spec_before["steps"])
-            dp = max(0, spec_after["proposed"] - spec_before["proposed"])
-            da = max(0, spec_after["accepted"] - spec_before["accepted"])
-            result["spec_steps"] = ds
-            result["spec_proposed"] = dp
-            result["spec_accepted"] = da
-            # bonus + accepted drafts per speculative step (> 1 is the
-            # win; None when the window never speculated)
-            result["spec_accept_per_step"] = (
-                (ds + da) / ds if ds else None)
-            result["spec_hit_pct"] = (
-                100.0 * da / dp if dp else None)
         for prefix, sample in (("ttft", ttfts), ("itl", itls)):
             if sample:
                 ms = sorted(v * 1e3 for v in sample)

@@ -45,11 +45,6 @@ _GEN_COLUMNS = [
     ("itl_p90_ms", "{:.2f}"),
     ("itl_p99_ms", "{:.2f}"),
     ("prefix_hit_pct", "{:.1f}"),
-    # speculative decoding (window-delta'd like the prefix-cache
-    # column): mean tokens per speculative step and the draft
-    # acceptance rate; absent on pre-speculation targets
-    ("spec_accept_per_step", "{:.2f}"),
-    ("spec_hit_pct", "{:.1f}"),
     # per-phase columns from the router's disagg counters (set by
     # attach_router_delta only when the target router runs the
     # phase-split plane; absent fields render "-", never 0)
@@ -62,8 +57,7 @@ _GEN_COLUMNS = [
 _GEN_HEADERS = [
     "Streams", "tokens/sec", "gen/sec", "TTFT avg(ms)", "TTFT p50(ms)",
     "TTFT p99(ms)", "ITL p50(ms)", "ITL p90(ms)", "ITL p99(ms)",
-    "prefix-hit%", "accept/step", "spec-hit%",
-    "prefill-q(ms)", "kv-xfer(ms)", "errors", "stable",
+    "prefix-hit%", "prefill-q(ms)", "kv-xfer(ms)", "errors", "stable",
 ]
 
 #: Per-window CSV schema: the reference ReportWriter's columns

@@ -1080,18 +1080,7 @@ class InferenceServer:
             "tpu_moe_layer_steps_total": "moe_layer_steps",
             "tpu_moe_local_pairs_total": "moe_local_pairs",
             "tpu_moe_experts_hit_total": "moe_experts_hit",
-            # speculative decoding (ISSUE 19): proposal/acceptance
-            # counters perfanalyzer's accept-rate columns window-diff,
-            # plus the lifetime accepted-per-step gauge
-            "tpu_spec_tokens_proposed_total": "spec_proposed",
-            "tpu_spec_tokens_accepted_total": "spec_accepted",
-            "tpu_spec_rollbacks_total": "spec_rollbacks",
-            "tpu_spec_steps_total": "spec_steps",
-            "tpu_spec_accept_per_step": "spec_accept_per_step",
         }
-        # the one non-integral family: a mean, exposed as-is (every
-        # other stats value is a count or 0/1 flag)
-        float_families = {"tpu_spec_accept_per_step"}
         samples = {name: [] for name in per_family}
         # the decode loop's seconds by phase: the one family with a
         # second label, float like every *_seconds
@@ -1102,11 +1091,8 @@ class InferenceServer:
             if not isinstance(stats, dict):
                 continue
             for fam_name, key in per_family.items():
-                val = stats.get(key) or 0
                 samples[fam_name].append(
-                    ({"model": model_name},
-                     float(val) if fam_name in float_families
-                     else int(val)))
+                    ({"model": model_name}, int(stats.get(key) or 0)))
             loop_seconds.extend(
                 ({"model": model_name, "phase": phase}, float(seconds))
                 for phase, seconds in (stats.get("loop_seconds")

@@ -214,28 +214,6 @@ CATALOG = {
         "over decode steps and routed layers, per model: over "
         "tpu_moe_layer_steps_total, the experts whose weights a "
         "layer-step had to read."),
-    # -- speculative decoding ----------------------------------------------
-    "tpu_spec_tokens_proposed_total": (
-        "counter",
-        "Draft tokens proposed by the n-gram speculative drafter and "
-        "fed through batched verify steps, per model."),
-    "tpu_spec_tokens_accepted_total": (
-        "counter",
-        "Draft tokens whose greedy argmax matched and were emitted "
-        "(token-identical to single-token decoding), per model."),
-    "tpu_spec_rollbacks_total": (
-        "counter",
-        "Speculative steps that rejected at least one draft token "
-        "and rolled the slot's KV write cursor back, per model."),
-    "tpu_spec_steps_total": (
-        "counter",
-        "Batched decode steps that carried at least one draft token "
-        "into the multi-token verify path, per model."),
-    "tpu_spec_accept_per_step": (
-        "gauge",
-        "Lifetime mean tokens emitted per speculative step (bonus + "
-        "accepted drafts; 1.0 is the non-speculative bound), per "
-        "model."),
     # -- fleet router ------------------------------------------------------
     "tpu_router_failovers_total": (
         "counter", "Requests re-routed to another replica."),

@@ -1418,92 +1418,6 @@ def paged_scheduler_step(params, pages, logits_all, page_tables,
     return (tokens, tok_logp, new_logits, new_pages, *moe)
 
 
-def paged_spec_step(params, pages, logits_all, page_tables, positions,
-                    active, forced, forced_mask, draft, draft_len, cfg):
-    """Multi-token speculative verify: :func:`paged_scheduler_step`
-    followed by up to K drafted continuation tokens, all inside ONE
-    dispatch (``tpuserver.speculative`` is the draft source).
-
-    ``draft`` [S, K] int32 holds each row's proposed continuation and
-    ``draft_len`` [S] int32 how many of those entries are real (0 =
-    no speculation for the row — forced-replay rows and throttled
-    streams).  The step is an unrolled chain of K+1 sub-steps, each
-    the *exact* op sequence of :func:`paged_scheduler_step`'s math
-    (log_softmax → argmax → :func:`paged_batched_decode_step`), so
-    every intermediate logits row is bitwise identical to what k
-    separate single-token steps would compute — the token-identity
-    contract holds by construction, not by tolerance (A/B-pinned in
-    tests/test_speculative.py).
-
-    Sub-step 0 feeds the ordinary greedy-or-forced token at
-    ``positions``; sub-step j >= 1 feeds ``draft[:, j-1]`` at
-    ``positions + j`` (rows past their ``draft_len`` feed at the
-    sentinel ``max_seq`` — writes drop, the row is inert for that
-    sub-step).  Greedy acceptance is computed in-graph: row ``i``
-    accepts the longest prefix of its drafts where the previous
-    sub-step's argmax equals the drafted token, and its returned
-    logits are the sub-step outputs at that acceptance depth —
-    selected by GATHER, never by masked arithmetic, so a poisoned
-    row's NaN logits reach the host quarantine path intact instead
-    of corrupting the select.
-
-    Rejected drafts leave garbage K/V at ``positions + accept + 1``
-    onward; those positions sit beyond the row's advanced write
-    cursor, so the next step (or the retirement donation's
-    ``min(pos, known)`` bound) overwrites or ignores them — the
-    rollback is a host-side cursor move, never a device copy.
-
-    Returns ``(tokens [S, K+1], logprobs [S, K+1], accept [S],
-    new_logits [S, vocab], new_pages)``: ``tokens[:, 0]`` is the
-    base token, ``tokens[:, j]`` the j-th draft, and the host emits
-    ``tokens[i, :1 + accept[i]]``.
-    """
-    S, K = draft.shape
-    page = pages.shape[3]
-    max_seq = page_tables.shape[1] * page
-    with jax.named_scope("sample"):
-        logp = jax.nn.log_softmax(logits_all, axis=-1)
-        greedy = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
-        t0 = jnp.where(forced_mask, forced, greedy)
-        lp0 = jnp.take_along_axis(logp, t0[:, None], axis=-1)[:, 0]
-    cur, new_pages = paged_batched_decode_step(
-        params, pages, t0, page_tables, positions, cfg
-    )
-    toks = [t0]
-    lps = [lp0]
-    stack = [cur]   # stack[j] = logits after feeding sub-step j
-    matches = []
-    for j in range(1, K + 1):
-        cand = draft[:, j - 1]
-        fed = j <= draft_len
-        with jax.named_scope("sample"):
-            logp_j = jax.nn.log_softmax(cur, axis=-1)
-            g = jnp.argmax(cur, axis=-1).astype(jnp.int32)
-            matches.append((g == cand) & fed)
-            lps.append(
-                jnp.take_along_axis(logp_j, cand[:, None], axis=-1)[:, 0]
-            )
-        toks.append(cand)
-        pos_j = jnp.where(fed, positions + j, max_seq)
-        cur, new_pages = paged_batched_decode_step(
-            params, new_pages, cand, page_tables, pos_j, cfg
-        )
-        stack.append(cur)
-    match_stack = jnp.stack(matches, axis=0).astype(jnp.int32)  # [K, S]
-    accept = jnp.sum(jnp.cumprod(match_stack, axis=0), axis=0)
-    accept = accept.astype(jnp.int32)
-    l_stack = jnp.stack(stack, axis=0)  # [K+1, S, vocab]
-    final = l_stack[accept, jnp.arange(S)]
-    final = jnp.where(active[:, None], final, logits_all)
-    return (
-        jnp.stack(toks, axis=1),
-        jnp.stack(lps, axis=1),
-        accept,
-        final,
-        new_pages,
-    )
-
-
 def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
                 slot):
     """Admit one prefilled request into the paged pool: the single-row
@@ -1637,10 +1551,6 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     - ``step(params, pages, logits, page_tables, positions, active,
       forced, forced_mask)`` — :func:`paged_scheduler_step`, pages and
       logits donated
-    - ``spec_step(params, pages, logits, page_tables, positions,
-      active, forced, forced_mask, draft, draft_len)`` —
-      :func:`paged_spec_step`, the multi-token speculative verify
-      (pages and logits donated; one compile per distinct K)
     - ``admit(pages, logits, slot_cache, slot_logits, dest_ids,
       slot)`` — :func:`paged_admit`, pages and logits donated
     - ``gather(pages, page_ids)`` — :func:`paged_gather`: the park
@@ -1652,16 +1562,16 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
       dense chunk vs a one-shot flash pass could flip a near-tie
       greedy argmax, the same hazard :func:`prefill_bucket` guards,
       so the scheduler falls back to whole-prompt prefill there)
-    - ``decode_attention`` — which decode attention ``step`` and
-      ``spec_step`` were built with (:func:`paged_decode_path`):
+    - ``decode_attention`` — which decode attention ``step`` was
+      built with (:func:`paged_decode_path`):
       ``"paged_kernel"``, ``"gather_kernel"`` or ``"gather_dense"``
     - ``window_class`` — None, or for a configuration with window
       layers ``{"window", "ring", "n_pages"}``: the pool is then two
       classes of pages (:func:`init_paged_kv_classes`), ``step`` and
       ``admit`` take ``{"full", "window"}`` tables / destinations, and
-      ``spec_step``, ``gather`` and ``prefill_span`` are ABSENT: what
-      rides on them (speculation, park / resume / KV export, shared
-      prefixes and chunked prefill) the scheduler refuses by name
+      ``gather`` and ``prefill_span`` are ABSENT: what rides on them
+      (park / resume / KV export, shared prefixes and chunked
+      prefill) the scheduler refuses by name
       (``UnsupportedArchitecture``).  ``kv_window_pages`` bounds the
       window class (default: every slot a full ring).
 
@@ -1720,10 +1630,6 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             named_partial(paged_scheduler_step, cfg=cfg),
             donate_argnums=(1, 2),
         )
-        spec_step = jax.jit(
-            named_partial(paged_spec_step, cfg=cfg),
-            donate_argnums=(1, 2),
-        )
         admit = jax.jit(paged_admit, donate_argnums=(0, 1))
         gather = jax.jit(paged_gather)
         prefill_fn = jax.jit(named_partial(prefill_to_length, cfg=cfg))
@@ -1737,7 +1643,7 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         if window_class is not None:
             # two page classes: their own pool and admit; what assumes
             # one table a sequence is left out of the bundle
-            spec_step = gather = prefill_span_fn = None
+            gather = prefill_span_fn = None
             admit = jax.jit(
                 named_partial(paged_admit_classes, cfg=cfg),
                 donate_argnums=(0, 1),
@@ -1762,13 +1668,6 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             in_shardings=(param_sh, cache_sh, repl, repl, repl, repl,
                           repl, repl),
             out_shardings=(repl, repl, repl, cache_sh),
-            donate_argnums=(1, 2),
-        )
-        spec_step = jax.jit(
-            named_partial(paged_spec_step, cfg=cfg),
-            in_shardings=(param_sh, cache_sh, repl, repl, repl, repl,
-                          repl, repl, repl, repl),
-            out_shardings=(repl, repl, repl, repl, cache_sh),
             donate_argnums=(1, 2),
         )
         admit = jax.jit(
@@ -1814,9 +1713,6 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "prefill_span": prefill_span_fn,
         "prefill_bucket": functools.partial(prefill_bucket, cfg, max_seq),
         "step": step,
-        # the verify chain unpacks a two-result sub-step: a routed
-        # step returns three
-        "spec_step": None if cfg.ffn_types else spec_step,
         "admit": admit,
         "gather": gather,
         "page_size": page_size,
@@ -1826,8 +1722,7 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
         "window_class": window_class,
     }
-    # what a two-class pool cannot serve is absent, not None: the
-    # scheduler reads ``"spec_step" in fns``
+    # what a two-class pool cannot serve is absent, not None
     return {k: v for k, v in fns.items()
             if v is not None or k == "window_class"}
 

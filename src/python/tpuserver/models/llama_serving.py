@@ -83,7 +83,7 @@ class LlamaGenerateModel(Model):
                  page_size=16, kv_pages=None, prefill_chunk_tokens=256,
                  prefix_cache=True, kv_export=False,
                  target_queue_ms=None, shed_interval_ms=100.0,
-                 spec_tokens=None, params=None, kv_window_pages=None):
+                 params=None, kv_window_pages=None):
         self._cfg = cfg or llama.tiny(vocab=2048)
         # the weights, handed in: a pytree in ``llama.init_params``'s
         # layout for ``cfg`` (already on the device, in the served
@@ -144,12 +144,6 @@ class LlamaGenerateModel(Model):
         # server-owned XLA-shm region, so a same-host resume attaches
         # and re-scatters instead of re-prefilling prompt + history
         self._kv_export = bool(kv_export)
-        # speculative decoding: candidate tokens drafted (from the
-        # radix prefix cache) and verified per batched step; 0 is
-        # today's single-token path byte-for-byte, None defers to the
-        # TPUSERVER_SPEC_TOKENS environment variable (default 0) so a
-        # whole fleet — or an unmodified test run — can flip it on
-        self._spec_tokens = spec_tokens
         self._scheduler = None  # DecodeScheduler when max_slots > 1
         # continuous-batching models interleave many streams' responses;
         # the frontends must not serialize their stream requests
@@ -248,7 +242,6 @@ class LlamaGenerateModel(Model):
                         prefix_cache=self._prefix_cache,
                         target_queue_ms=self._target_queue_ms,
                         shed_interval_ms=self._shed_interval_ms,
-                        spec_tokens=self._spec_tokens,
                         # queue-wait/step latency histograms land in
                         # the attached server's /metrics registry
                         # (lock-free observes — the decode loop never

@@ -107,12 +107,6 @@ class RadixPrefixCache:
         self._clock = 0
         self.pages = 0          # nodes (= cached+pinned pages) in the tree
         self.unreferenced = 0   # nodes with ref == 0 (pure cache)
-        # bumped on every structural change (insert adds a node, evict
-        # removes one): read-only consumers holding derived indices
-        # over the tree's content — the speculative n-gram drafter —
-        # compare it to decide when to rebuild.  Plain int: safe for
-        # racy reads like the other counters.
-        self.version = 0
 
     # -- lookup / pinning --------------------------------------------------
 
@@ -137,40 +131,6 @@ class RadixPrefixCache:
             node = child
         return path, [n.page for n in path]
 
-    def continuation(self, tokens, limit):
-        """Cached continuation of the EXACT sequence ``tokens``: up to
-        ``limit`` token ids that previously-served sequences decoded
-        after this precise root-anchored context, or ``[]`` when the
-        context isn't cached that deep.
-
-        This is what makes the tree a draft model and not just a KV
-        store: for regenerate/extend traffic the live context is a
-        prefix of a donated sequence, and the exact-prefix walk is
-        unambiguous where any fixed-length n-gram is not (a run of
-        repeated tokens collides every n-gram key, but only one tree
-        path spells the full context).  Where the tree branches, the
-        most recently used child wins — recency is the same signal
-        LRU eviction trusts.
-
-        STRICTLY read-only: no pinning, no ref-count changes, no LRU
-        stamping (same contract as :meth:`iter_sequences`)."""
-        path, _ = self.match(tokens)
-        node = path[-1] if path else self._root
-        rem = [int(t) for t in tokens[len(path) * self.page_size:]]
-        out = []
-        while len(out) < limit:
-            best = None
-            for child in node.children.values():
-                if (list(child.key[:len(rem)]) == rem
-                        and (best is None
-                             or child.last_used > best.last_used)):
-                    best = child
-            if best is None:
-                break
-            out.extend(best.key[len(rem):])
-            node, rem = best, []
-        return out[:limit]
-
     def acquire(self, nodes):
         """Pin ``nodes`` (one ref each) so eviction cannot free pages
         a live stream's page table points at."""
@@ -187,27 +147,6 @@ class RadixPrefixCache:
             if node.ref == 0:
                 self.unreferenced += 1
                 node.last_used = self._tick()
-
-    def iter_sequences(self):
-        """Yield every root-to-leaf token sequence in the tree, as a
-        flat list of ints (page keys concatenated in path order).
-
-        STRICTLY read-only: no pinning, no ref-count changes, no LRU
-        stamping — the speculative drafter walks cached content
-        without affecting what eviction may reclaim.  Caller must not
-        mutate the tree mid-iteration (the decode loop is the only
-        mutator, and it drives both)."""
-        stack = [(self._root, [])]
-        while stack:
-            node, prefix = stack.pop()
-            if node is not self._root:
-                prefix = prefix + list(node.key)
-            if not node.children:
-                if prefix:
-                    yield prefix
-                continue
-            for child in node.children.values():
-                stack.append((child, prefix))
 
     # -- insertion ---------------------------------------------------------
 
@@ -247,7 +186,6 @@ class RadixPrefixCache:
                 node.children[key] = child
                 self.pages += 1
                 self.unreferenced += 1
-                self.version += 1
             else:
                 dups.append((d, child.page))
                 freed.append(page)
@@ -288,7 +226,6 @@ class RadixPrefixCache:
             victim.parent = None
             self.pages -= 1
             self.unreferenced -= 1
-            self.version += 1
             freed.append(victim.page)
             if (parent is not self._root and not parent.children
                     and parent.ref == 0):

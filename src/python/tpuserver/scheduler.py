@@ -90,7 +90,6 @@ Self-healing (tests/test_self_healing.py, docs/resilience.md):
 
 import contextlib
 import math
-import os
 import threading
 import time
 from collections import OrderedDict, deque
@@ -100,7 +99,6 @@ import numpy as np
 from tpuserver import faults
 from tpuserver._trace import span
 from tpuserver.paging import PageAllocator, RadixPrefixCache, pages_for
-from tpuserver.speculative import NgramDrafter
 
 # The wire-mapped stream failures are the CANONICAL tpuserver.errors
 # types (one definition site, tpulint R4-enforced): DeadlineExceeded
@@ -244,11 +242,6 @@ class _Stream:
         # FINISH (not just cancel-reap) and keep the export alive past
         # the completed park — a decode-role replica attaches it
         "kv_export_on_finish",
-        # speculative decoding (ISSUE 19) per-stream throttle state,
-        # owned by the decode loop: consecutive drafted tokens with
-        # zero acceptance, and steps left to skip drafting (probe
-        # cadence once throttled)
-        "spec_miss", "spec_skip",
     )
 
     def __init__(self, prompt, max_tokens, eos_id, resume_cache,
@@ -292,11 +285,6 @@ class _Stream:
         self.kv_export_on_finish = bool(kv_export_on_finish)
         self.attach_cache = None  # imported KV export (device array)
         self.attach_pos = 0       # its valid-prefix end position
-        # speculative-decode throttle (loop-thread only): consecutive
-        # drafted tokens with zero acceptance / steps left to skip
-        # drafting once throttled (probe cadence)
-        self.spec_miss = 0
-        self.spec_skip = 0
 
     def expired(self, now):
         return self.deadline is not None and now >= self.deadline
@@ -407,9 +395,7 @@ class DecodeScheduler:
                  metrics=None, metric_labels=None,
                  prefill_chunk_tokens=256, prefix_cache=True,
                  kv_export=None, kv_import=None, kv_discard=None,
-                 target_queue_ms=None, shed_interval_ms=100.0,
-                 spec_tokens=None, spec_throttle_after=16,
-                 spec_probe_interval=8):
+                 target_queue_ms=None, shed_interval_ms=100.0):
         if max_slots < 1:
             raise ValueError(
                 "max_slots must be >= 1 (got {})".format(max_slots)
@@ -521,44 +507,10 @@ class DecodeScheduler:
         self._moe_layer_steps = 0
         self._moe_local_pairs = 0
         self._moe_experts_hit = 0
-        # speculative decoding (ISSUE 19): draft up to ``spec_tokens``
-        # candidate continuation tokens per slot per step from the
-        # radix prefix cache (tpuserver.speculative.NgramDrafter) and
-        # verify them all in ONE batched device step
-        # (fns["spec_step"]).  0 keeps today's single-token path
-        # byte-identical (the spec branch is never entered); None
-        # defers to the TPUSERVER_SPEC_TOKENS environment variable so
-        # an unmodified test corpus or fleet can be run with
-        # speculation enabled wholesale (default 0).  Throttle knobs:
-        # a stream that drafted ``spec_throttle_after`` consecutive
-        # tokens with ZERO acceptance stops drafting and probes once
-        # every ``spec_probe_interval`` steps until a draft lands.
-        if spec_tokens is None:
-            spec_tokens = int(os.environ.get("TPUSERVER_SPEC_TOKENS", "0"))
-        self._spec_tokens = max(0, int(spec_tokens))
         # a pool of two page classes (window layers:
         # llama.make_scheduler_fns "window_class"): what still assumes
-        # one table a sequence is refused by name, here and in submit
+        # one table a sequence is refused by name in submit
         self._window_class = (fns or {}).get("window_class")
-        if self._window_class and self._spec_tokens:
-            raise self._unsupported(
-                "speculative decoding (spec_tokens={})".format(
-                    self._spec_tokens))
-        if self._spec_tokens and "spec_step" not in (fns or {}):
-            # bundle has no multi-token verify step (stub fns in
-            # tests, older model builds): degrade to the plain path
-            # rather than failing construction — speculation is an
-            # optimization, never a capability requirement
-            self._spec_tokens = 0
-        self._spec_throttle_after = int(spec_throttle_after)
-        self._spec_probe_interval = int(spec_probe_interval)
-        # speculation accounting, same discipline as the counters
-        # above: loop-written under _cond, grow-only, racy stats reads
-        # may lag one step but never decrease.
-        self._spec_steps = 0      # guarded-by: _cond
-        self._spec_proposed = 0   # guarded-by: _cond
-        self._spec_accepted = 0   # guarded-by: _cond
-        self._spec_rollbacks = 0  # guarded-by: _cond
         # park-attach KV export hooks (tentpole 3 of ISSUE 12): a
         # disconnected resumable stream's gathered pages are handed to
         # ``kv_export(generation_id, cache, valid_pos)`` (the server
@@ -991,14 +943,6 @@ class DecodeScheduler:
                 "prefix_hits": self._prefix_hits,
                 "prefix_misses": self._prefix_misses,
                 "prefix_evictions": self._prefix_evictions,
-                "spec_tokens": self._spec_tokens,
-                "spec_steps": self._spec_steps,
-                "spec_proposed": self._spec_proposed,
-                "spec_accepted": self._spec_accepted,
-                "spec_rollbacks": self._spec_rollbacks,
-                "spec_accept_per_step": (
-                    (self._spec_steps + self._spec_accepted)
-                    / self._spec_steps if self._spec_steps else 0.0),
                 "pages_total": pages_total,
                 "pages_free": pages_free,
                 "pages_cached": pages_cached,
@@ -1174,10 +1118,6 @@ class DecodeScheduler:
         # re-prefill path (greedy decode makes both token-identical)
         stream.attach_cache = None
         stream.attach_pos = 0
-        # speculation throttle restarts fresh: the acceptance profile
-        # under the new loop's (cold) radix cache is unknown
-        stream.spec_miss = 0
-        stream.spec_skip = 0
 
     # -- replay buffer -----------------------------------------------------
 
@@ -1339,15 +1279,6 @@ class DecodeScheduler:
         ready = [False] * self._max_slots  # prefill complete
         prefilling = {}                    # slot -> _PrefillTask
         inflight = None  # (tokens_dev, logps_dev, snapshot)
-        # speculative decoding: the drafter reads the radix tree (and
-        # each stream's own context) — strictly read-only, so it can
-        # never change what eviction may reclaim.  max_draft is
-        # spec_k + 1 because the drafter's first proposal predicts the
-        # step's OWN next token (which the verify step computes
-        # exactly); the remaining spec_k feed as candidates.
-        spec_k = self._spec_tokens
-        drafter = (NgramDrafter(radix, max_draft=spec_k + 1)
-                   if spec_k > 0 else None)
 
         def clear_slot(slot):
             slots[slot] = None
@@ -1816,19 +1747,6 @@ class DecodeScheduler:
             st.emitted += 1
             self._tokens_total += 1
 
-        def step_chaos():
-            """The ONE registered fire site (R6) for "scheduler.step":
-            the pipelined and the speculative step paths are mutually
-            exclusive per configuration, and both are the same logical
-            injection point — the batched decode dispatch."""
-            return faults.fire("scheduler.step", self.fault_scope)
-
-        def fetch_chaos():
-            """The ONE registered fire site (R6) for
-            "scheduler.fetch" — the step-result host transfer, on
-            whichever path (pipelined or speculative) is active."""
-            faults.fire("scheduler.fetch", self.fault_scope)
-
         def finish(stream, slot):
             if stream.on_finish is not None:
                 # gather+park is a device dispatch too: under the
@@ -1967,178 +1885,7 @@ class DecodeScheduler:
             current = None
             active_ids = [i for i, s in enumerate(slots)
                           if s is not None and ready[i]]
-            if active_ids and spec_k > 0:
-                with phase("dispatch"):
-                    # speculative multi-token step (ISSUE 19): draft up to
-                    # spec_k candidates per slot from the radix cache, feed
-                    # them all through ONE batched verify dispatch, keep
-                    # the longest argmax-matching prefix plus the bonus
-                    # token.  Variable per-slot advance makes the one-deep
-                    # pipeline impossible (the NEXT step's positions depend
-                    # on THIS step's acceptance), so the spec path
-                    # dispatches and fetches in the same iteration;
-                    # ``inflight`` stays None.
-                    positions = np.full(
-                        (self._max_slots,), self._max_seq, np.int32)
-                    active = np.zeros((self._max_slots,), bool)
-                    forced_tok = np.zeros((self._max_slots,), np.int32)
-                    forced_mask = np.zeros((self._max_slots,), bool)
-                    draft = np.zeros((self._max_slots, spec_k), np.int32)
-                    draft_len = np.zeros((self._max_slots,), np.int32)
-                    snapshot = []
-                    for i in active_ids:
-                        st = slots[i]
-                        positions[i] = st.pos
-                        active[i] = True
-                        was_forced = bool(st.forced)
-                        if was_forced:
-                            forced_tok[i] = st.forced.popleft()
-                            forced_mask[i] = True
-                        k_i = 0
-                        if not was_forced:
-                            if st.spec_skip > 0:
-                                # throttled: this step probes nothing
-                                st.spec_skip -= 1
-                            else:
-                                # never draft past the emission budget:
-                                # 1 bonus + k_i accepted must fit
-                                budget = min(
-                                    spec_k, st.max_tokens - st.emitted - 1)
-                                if budget > 0:
-                                    ctx = [int(t) for t in st.prompt]
-                                    ctx.extend(t for t, _ in st.history)
-                                    # the drafter's FIRST proposal predicts
-                                    # this step's own next token — which the
-                                    # verify step computes exactly — so it
-                                    # drops and the rest feed as candidates
-                                    d = drafter.draft(ctx, budget + 1)[1:]
-                                    k_i = len(d)
-                                    if k_i:
-                                        draft[i, :k_i] = d
-                                        draft_len[i] = k_i
-                        # pos does NOT advance at snapshot (unlike the
-                        # pipelined path): the fetch below advances it by
-                        # the tokens actually kept
-                        snapshot.append(
-                            (i, st, was_forced, st.incarnation, k_i))
-                    action = step_chaos()
-                    if action is not None and action[0] == "nan":
-                        row = min(max(0, action[1]), self._max_slots - 1)
-                        logits = logits.at[row].set(float("nan"))
-                    step_start = time.monotonic()
-                    self._beat(epoch, step_start)
-                    if action is not None and action[0] == "hang":
-                        time.sleep(action[1])
-                    if draft_len.any():
-                        toks_dev, lps_dev, acc_dev, logits, pages = fns[
-                            "spec_step"](
-                            self._params, pages, logits, tables, positions,
-                            active, forced_tok, forced_mask, draft,
-                            draft_len,
-                        )
-                    else:
-                        # nobody drafted (cold caches, all throttled): a
-                        # plain sub-step costs spec_k fewer weight passes
-                        # and is bitwise-identical for the one token
-                        toks_dev, lps_dev, logits, pages, *_ = fns["step"](
-                            self._params, pages, logits, tables, positions,
-                            active, forced_tok, forced_mask,
-                        )
-                        acc_dev = None
-                    self._beat(epoch, None)
-                    if self._step_hist is not None:
-                        self._step_hist.observe(
-                            time.monotonic() - step_start)
-                with phase("fetch"):
-                    # host-transfer chaos; a raise is loop death (restart)
-                    fetch_chaos()
-                    self._beat(epoch, time.monotonic())
-                    toks = np.asarray(toks_dev)
-                    lps = np.asarray(lps_dev)
-                    accs = (np.asarray(acc_dev) if acc_dev is not None else
-                            np.zeros((self._max_slots,), np.int32))
-                    self._beat(epoch, None)
-                with phase("deliver"):
-                    if toks.ndim == 1:
-                        # plain-step fallback: same emission code below,
-                        # one column, zero accepted drafts
-                        toks = toks[:, None]
-                        lps = lps[:, None]
-                    quarantined = []
-                    finished = []
-                    with self._cond:
-                        if self._epoch != epoch:
-                            return  # superseded mid-fetch: deliver nothing
-                        for i, st, was_forced, inc, k_i in snapshot:
-                            if slots[i] is not st or st.incarnation != inc:
-                                continue  # slot retired mid-step
-                            if st.cancelled:
-                                export_kv(st)
-                                release_pages(st)
-                                self._detach_locked(st)
-                                clear_slot(i)
-                                continue
-                            a = min(int(accs[i]), k_i)
-                            if k_i:
-                                self._spec_steps += 1
-                                self._spec_proposed += k_i
-                                self._spec_accepted += a
-                                if a < k_i:
-                                    self._spec_rollbacks += 1
-                                if a > 0:
-                                    st.spec_miss = 0
-                                else:
-                                    st.spec_miss += k_i
-                                    if (st.spec_miss
-                                            >= self._spec_throttle_after):
-                                        st.spec_skip = (
-                                            self._spec_probe_interval)
-                            if was_forced:
-                                st.pos += 1
-                                continue  # resumed-prompt feed, no emission
-                            fed = 0
-                            poisoned = False
-                            hit_eos = False
-                            for j in range(1 + a):
-                                tok = int(toks[i, j])
-                                lp = float(lps[i, j])
-                                if not np.isfinite(lp):
-                                    poisoned = True
-                                    break
-                                emit(st, tok, lp)
-                                fed += 1
-                                if (st.eos_id is not None
-                                        and tok == st.eos_id):
-                                    hit_eos = True
-                                    break
-                            if poisoned:
-                                # poisoned output: row-independent math, so
-                                # co-batched slots are untouched — retire
-                                # only the offender, never donating its KV
-                                quarantined.append((i, st))
-                                release_pages(st, insert=False)
-                                clear_slot(i)
-                                continue
-                            # rejected-position rollback is exactly this
-                            # cursor move: the next step re-feeds from
-                            # here, overwriting any speculative garbage
-                            # beyond it (still inside the reserved span,
-                            # and release_pages donates only up to pos —
-                            # nothing leaks or double-donates)
-                            st.pos += fed
-                            if st.emitted >= st.max_tokens or hit_eos:
-                                finished.append((st, i))
-                    for i, st in quarantined:
-                        with self._cond:
-                            self._quarantined += 1
-                        self._fail(st, SlotQuarantined(
-                            "generation produced non-finite logits after {} "
-                            "emitted tokens; its slot was quarantined (co-"
-                            "batched generations are unaffected)".format(
-                                st.emitted)), epoch)
-                    for st, i in finished:
-                        finish(st, i)
-            elif active_ids:
+            if active_ids:
                 with phase("dispatch"):
                     # sentinel position max_seq on inert rows: their cache
                     # writes drop instead of corrupting a parked slot
@@ -2175,7 +1922,7 @@ class DecodeScheduler:
                     # watchdog provably observes it.  A raise here may have
                     # left the donated cache consumed — exactly what the
                     # restart rebuilds.
-                    action = step_chaos()
+                    action = faults.fire("scheduler.step", self.fault_scope)
                     if action is not None and action[0] == "nan":
                         row = min(max(0, action[1]), self._max_slots - 1)
                         logits = logits.at[row].set(float("nan"))
@@ -2208,7 +1955,7 @@ class DecodeScheduler:
                 tokens_dev, logps_dev, snapshot, moe_dev = inflight
                 with phase("fetch"):
                     # host-transfer chaos; a raise is loop death (restart)
-                    fetch_chaos()
+                    faults.fire("scheduler.fetch", self.fault_scope)
                     self._beat(epoch, time.monotonic())
                     toks = np.asarray(tokens_dev)
                     lps = np.asarray(logps_dev)

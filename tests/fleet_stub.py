@@ -136,16 +136,6 @@ def main():
                          "'jitter' mode, so gray-failure tier-1 tests "
                          "get realistic latency spread without jax "
                          "replicas")
-    ap.add_argument("--spec-tokens", type=int, default=0,
-                    help="stub twin of DecodeScheduler(spec_tokens=K): "
-                         "each generation step drafts up to K "
-                         "continuation tokens by prior-occurrence "
-                         "lookup over the fed sequence and emits the "
-                         "verified prefix in one burst — token-"
-                         "identical to single-token stub decode, and "
-                         "the tpu_spec_* /metrics counters move so "
-                         "fleet chaos/perf runs have a real "
-                         "acceptance-rate column without jax replicas")
     args = ap.parse_args()
 
     lock = threading.Lock()
@@ -188,10 +178,6 @@ def main():
     # has real numbers to aggregate.  "seen" holds every prefix tuple
     # of every admitted prompt
     prefix = {"seen": set(), "hits": 0, "misses": 0}
-    # stub twin of the scheduler's speculative-decoding counters
-    # (--spec-tokens): moved by the draft/verify burst in
-    # _generate_stream, exported as tpu_spec_* in /metrics
-    spec = {"steps": 0, "proposed": 0, "accepted": 0, "rollbacks": 0}
     # replica-local generation replay state: gid -> {"fed": [ids the
     # virtual model consumed], "emitted": [tokens], "target": int,
     # "delay_ms": float, "done": bool} — what makes Last-Event-ID
@@ -285,7 +271,6 @@ def main():
             count = served["count"]
             gens = served["gen"]
             hits, misses = prefix["hits"], prefix["misses"]
-            spec_now = dict(spec)
         return (
             "# HELP stub_requests_total Inferences served by this "
             "stub replica.\n"
@@ -302,26 +287,8 @@ def main():
             "# HELP tpu_prefix_cache_misses_total Prompt tokens "
             "prefilled cold by the (stub) prefix cache.\n"
             "# TYPE tpu_prefix_cache_misses_total counter\n"
-            "tpu_prefix_cache_misses_total {}\n"
-            "# HELP tpu_spec_steps_total Stub decode steps that "
-            "carried draft tokens.\n"
-            "# TYPE tpu_spec_steps_total counter\n"
-            "tpu_spec_steps_total {}\n"
-            "# HELP tpu_spec_tokens_proposed_total Draft tokens "
-            "proposed by the stub drafter.\n"
-            "# TYPE tpu_spec_tokens_proposed_total counter\n"
-            "tpu_spec_tokens_proposed_total {}\n"
-            "# HELP tpu_spec_tokens_accepted_total Draft tokens "
-            "verified and emitted by the stub.\n"
-            "# TYPE tpu_spec_tokens_accepted_total counter\n"
-            "tpu_spec_tokens_accepted_total {}\n"
-            "# HELP tpu_spec_rollbacks_total Stub speculative steps "
-            "that rejected at least one draft token.\n"
-            "# TYPE tpu_spec_rollbacks_total counter\n"
-            "tpu_spec_rollbacks_total {}\n".format(
-                count, gens, hits, misses, spec_now["steps"],
-                spec_now["proposed"], spec_now["accepted"],
-                spec_now["rollbacks"]))
+            "tpu_prefix_cache_misses_total {}\n".format(
+                count, gens, hits, misses))
 
     class Handler(BaseHTTPRequestHandler):
         # the stub answers with several small writes (status, headers,
@@ -539,46 +506,6 @@ def main():
                         token = next_token(entry["fed"])
                         entry["fed"].append(token)
                         entry["emitted"].append(token)
-                        if (args.spec_tokens > 0
-                                and len(entry["emitted"])
-                                < entry["target"]):
-                            # stub twin of the scheduler's speculative
-                            # step: the drafter is clairvoyant (the
-                            # virtual model is cheap to run ahead), with
-                            # a deterministic miss every 4th step so the
-                            # rollback accounting is exercised too —
-                            # the fleet property under test is the burst
-                            # emission and tpu_spec_* counter plumbing,
-                            # not draft quality.  Every candidate is
-                            # still verified against the exact
-                            # next_token chain, so the stream stays
-                            # token-identical to the plain path by
-                            # construction.
-                            fed = entry["fed"]
-                            budget = min(
-                                args.spec_tokens,
-                                entry["target"] - len(entry["emitted"]))
-                            draft = []
-                            ahead = list(fed)
-                            for _ in range(budget):
-                                t = next_token(ahead)
-                                ahead.append(t)
-                                draft.append(t)
-                            if draft and spec["steps"] % 4 == 3:
-                                draft[-1] = (draft[-1] + 1) % 101
-                            if draft:
-                                accepted = 0
-                                for cand in draft:
-                                    if cand != next_token(fed):
-                                        break
-                                    fed.append(cand)
-                                    entry["emitted"].append(cand)
-                                    accepted += 1
-                                spec["steps"] += 1
-                                spec["proposed"] += len(draft)
-                                spec["accepted"] += accepted
-                                if accepted < len(draft):
-                                    spec["rollbacks"] += 1
                     if delay > 0:
                         time.sleep(delay / 1000.0)
                 if kv_prefill:

@@ -309,8 +309,6 @@ def test_window_class_exhaustion_is_a_typed_shed():
 
 
 REFUSED = {
-    "speculation": lambda fns: DecodeScheduler(
-        fns, None, 2, MAX_SEQ, spec_tokens=2),
     "park": lambda fns: DecodeScheduler(fns, None, 2, MAX_SEQ).submit(
         [1, 2, 3], 4, on_finish=lambda cache: None),
     "resume": lambda fns: DecodeScheduler(fns, None, 2, MAX_SEQ).submit(
@@ -328,14 +326,14 @@ REFUSED = {
 
 @pytest.mark.parametrize("what", sorted(REFUSED))
 def test_two_page_classes_refuse_by_name(what):
-    """What still assumes one page table a sequence (speculation, park /
-    resume, KV export / attach) and what was written for the plain block
+    """What still assumes one page table a sequence (park / resume,
+    KV export / attach) and what was written for the plain block
     (int8, tensor parallelism) is refused with a typed error where it is
     asked for, never served wrong."""
     fns = llama.make_scheduler_fns(CFG, MAX_SEQ, 2, page_size=PAGE)
     assert fns["window_class"]["ring"] == 16
     assert not fns["span_safe"]          # no radix sharing, no chunks
-    assert "spec_step" not in fns and "gather" not in fns
+    assert "gather" not in fns
     with pytest.raises(llama.UnsupportedArchitecture):
         REFUSED[what](fns)
 

@@ -144,6 +144,43 @@ def test_replica_metrics_move_under_traffic():
         core.close()
 
 
+def test_scheduler_families_are_all_exported():
+    """Every scheduler, page-pool and routed-layer family the catalog
+    declares is in ``/metrics`` of a server whose one model runs on the
+    scheduler, once it has served a generation; no family of a removed
+    feature is."""
+    from tpuserver.core import InferenceServer, InferRequest
+    from tpuserver.http_frontend import HttpFrontend
+    from tpuserver.metrics import CATALOG
+    from tpuserver.models import llama
+    from tpuserver.models.llama_serving import LlamaGenerateModel
+
+    core = InferenceServer([LlamaGenerateModel(
+        cfg=llama.tiny(vocab=512), max_seq=64, max_slots=2)])
+    frontend = HttpFrontend(core, port=0).start()
+    try:
+        tokens = list(core.infer_stream(InferRequest(
+            "llama_generate",
+            inputs={"PROMPT_IDS": np.array([5, 6, 7], np.int32),
+                    "MAX_TOKENS": np.array([4], np.int32)})))
+        assert len(tokens) == 4
+        types, _, samples = parse_exposition(scrape(frontend.port))
+    finally:
+        frontend.stop()
+        core.close()
+    wanted = {name for name in CATALOG
+              if name.startswith(("tpu_scheduler_", "tpu_kv_", "tpu_moe_"))}
+    assert len(wanted) >= 24
+    assert wanted <= set(types), sorted(wanted - set(types))
+    sampled = {name for name, _, _ in samples}
+    for family in wanted:
+        assert (family in sampled
+                or family + "_count" in sampled), family
+    assert not [n for n in set(types) | sampled if n.startswith("tpu_spec")]
+    assert sample_value(samples, "tpu_scheduler_tokens_total",
+                        model="llama_generate") == 4
+
+
 # -- replica + router: token counters, fleet aggregation, single source -----
 
 

@@ -22,12 +22,23 @@ Everything device-backed runs the tiny config on CPU-sim with small
 pinned geometry per the tier-1 runtime budget.
 """
 
+import dataclasses
+import json
+import os
+import time
+
 import numpy as np
 import pytest
 
+from tpuserver import faults
 from tpuserver.models import llama
 from tpuserver.paging import PageAllocator, RadixPrefixCache, pages_for
-from tpuserver.scheduler import AdmissionQueueFull, DecodeScheduler
+from tpuserver.scheduler import (
+    AdmissionQueueFull,
+    DeadlineExceeded,
+    DecodeScheduler,
+    SlotQuarantined,
+)
 
 CFG = llama.tiny(vocab=512)
 MAX_SEQ = 64
@@ -184,28 +195,55 @@ def test_paged_step_matches_contiguous_kernel(params):
         np.asarray(row), np.asarray(cache[:, :, 0:1]))
 
 
-def test_paged_decode_path_follows_the_shapes():
+def _cell_geometry(config, dry_run=False):
+    """``(max_seq, page_size)`` of a benchmark configuration's served
+    model, read from the file the benchmark reads."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs", config)
+    with open(path) as f:
+        group = json.load(f)
+    (entry,) = (group["dry_run"] if dry_run else group)["repository"]
+    return entry["max_seq"], entry["page_size"]
+
+
+# decode_impl (None: the default, "auto"), (max_seq, page), the path
+DECODE_PATHS = {
+    "kernel_256": ("pallas", (2560, 16), ("paged_kernel", 256)),
+    "kernel_128": ("pallas", (384, 16), ("paged_kernel", 128)),
+    # a block that would cut a page in two: gather, then the kernel
+    "split_page": ("pallas", (384, 48), ("gather_kernel", 128)),
+    # no 128-multiple block (every tier-1 MAX_SEQ = 64), or no kernel
+    "no_block": ("pallas", (MAX_SEQ, PAGE), ("gather_dense", None)),
+    "xla": ("xla", (2560, 16), ("gather_dense", None)),
+    # "auto": the cost model's choice (dense for short rows)
+    "auto_short": (None, (MAX_SEQ, PAGE), ("gather_dense", None)),
+    # the accepted cells, with decode_impl as their model files leave
+    # it: benchmark/models/llama_generate.py sets none, so the cost
+    # model (decode_crossover_length) decides Mistral's two cells;
+    # afmoe_generate.py passes "pallas".  A refit that sent either to
+    # the dense path would fail here, not on the chip.
+    "mistral_cells": (None, _cell_geometry("mistral-7b-v0.3-l20.json"),
+                      ("paged_kernel", 256)),
+    "trinity_cell": ("pallas",
+                     _cell_geometry("trinity-large-preview-ep8-l5.json"),
+                     ("paged_kernel", 256)),
+    # the CPU dry run of Mistral's cells does NOT trace the kernel
+    "mistral_dry_run": (None,
+                        _cell_geometry("mistral-7b-v0.3-l20.json", True),
+                        ("gather_dense", None)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_PATHS))
+def test_paged_decode_path_follows_the_shapes(case):
     """Which decode attention the paged step is built with is read off
     the configuration and the geometry, nothing else."""
-    import dataclasses
-
-    kernel = dataclasses.replace(CFG, decode_impl="pallas")
-    dense = dataclasses.replace(CFG, decode_impl="xla")
-    assert llama.paged_decode_path(kernel, 2560, 16) == ("paged_kernel", 256)
-    assert llama.paged_decode_path(kernel, 384, 16) == ("paged_kernel", 128)
-    # a block that would cut a page in two: gather, then the kernel
-    assert llama.paged_decode_path(kernel, 384, 48) == ("gather_kernel", 128)
-    # no 128-multiple block (every tier-1 MAX_SEQ = 64), or no kernel
-    assert llama.paged_decode_path(kernel, MAX_SEQ, PAGE) == (
-        "gather_dense", None)
-    assert llama.paged_decode_path(dense, 2560, 16) == ("gather_dense", None)
-    # "auto": the cost model's choice (dense for short rows, the kernel
-    # where the benchmark and every real preset serve)
-    assert llama.paged_decode_path(CFG, 256, 16)[0] == "gather_dense"
-    assert llama.paged_decode_path(CFG, 2560, 16)[0] == "paged_kernel"
-    for max_seq, path in ((MAX_SEQ, "gather_dense"), (2560, "paged_kernel")):
-        assert llama.make_scheduler_fns(
-            CFG, max_seq, 2)["decode_attention"] == path
+    impl, (max_seq, page), path = DECODE_PATHS[case]
+    cfg = CFG if impl is None else dataclasses.replace(CFG, decode_impl=impl)
+    assert cfg.decode_impl == (impl or "auto")
+    assert llama.paged_decode_path(cfg, max_seq, page) == path
+    assert llama.make_scheduler_fns(
+        cfg, max_seq, 2, page_size=page)["decode_attention"] == path[0]
 
 
 @pytest.mark.parametrize("max_seq, prompt_len", [
@@ -221,8 +259,6 @@ def test_paged_kernel_step_matches_contiguous_kernel(
     with ``decode_attention``: bitwise-equal tokens, logprobs, next
     logits and cache content over 3 steps, through SHUFFLED page
     tables with the sentinel past each row's reservation."""
-    import dataclasses
-
     import jax.numpy as jnp
 
     # tiny resolves "auto" to dense at these lengths: state the kernel
@@ -372,5 +408,100 @@ def test_admission_bounded_by_pages_not_slots(params):
         for s in streams:
             rest = list(s)
             assert len(rest) == 7  # 8 total, first already taken
+    finally:
+        sched.close()
+
+
+# -- page accounting after retirement, by every route out of a slot ----------
+
+
+@pytest.fixture(scope="module", params=["tiny", "tiny_afmoe"])
+def pool(request):
+    """``(fns, params, max_seq, prompt, n)`` of a one-class pool (with
+    its radix cache) and of a two-class pool, whose window is 32 tokens:
+    40 prompt tokens and 30 answers move it over two pages."""
+    import jax
+
+    if request.param == "tiny":
+        return (request.getfixturevalue("fns"),
+                request.getfixturevalue("params"), MAX_SEQ,
+                (np.arange(1, 25) * 7 % 500).astype(np.int32), 20)
+    cfg = dataclasses.replace(llama.tiny_afmoe(vocab=512),
+                              attn_impl="pallas", decode_impl="pallas")
+    return (llama.make_scheduler_fns(cfg, 384, 2, page_size=PAGE),
+            llama.init_params(jax.random.PRNGKey(0), cfg), 384,
+            (np.arange(1, 41) * 7 % 500).astype(np.int32), 30)
+
+
+def _out_by_max_tokens(sched, prompt, n, ref):
+    assert _collect(sched, prompt, n) == ref
+
+
+def _out_by_eos(sched, prompt, n, ref):
+    eos = ref[2]
+    got = [t for t, _ in sched.submit(prompt, n, eos_id=eos)]
+    assert got == ref[:ref.index(eos) + 1]
+
+
+def _out_by_cancel(sched, prompt, n, ref):
+    stream = sched.submit(prompt, n)
+    assert next(stream)[0] == ref[0]
+    stream.close()  # the consumer walks away mid-generation
+
+
+def _out_by_deadline(sched, prompt, n, ref):
+    stream = sched.submit(prompt, n)
+    assert next(stream)[0] == ref[0]
+    with sched._cond:
+        (st,) = sched._streams
+        assert st.emitted < n  # in a slot, mid-generation
+        st.deadline = time.monotonic()
+    with pytest.raises(DeadlineExceeded):
+        list(stream)
+
+
+def _out_by_quarantine(sched, prompt, n, ref):
+    # the fourth step's logits row of slot 0 goes non-finite
+    faults.install("scheduler.step", mode="nan", times=1, delay=0, skip=3)
+    try:
+        with pytest.raises(SlotQuarantined):
+            list(sched.submit(prompt, n))
+    finally:
+        faults.clear("scheduler.step")
+    assert sched.stats()["quarantined"] == 1
+
+
+ROUTES_OUT = {
+    "max_tokens": _out_by_max_tokens,
+    "eos": _out_by_eos,
+    "cancel": _out_by_cancel,
+    "deadline": _out_by_deadline,
+    "quarantine": _out_by_quarantine,
+}
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES_OUT))
+def test_pages_reconcile_after_retirement(pool, route):
+    """Whichever way a stream leaves its slot, once the scheduler is
+    idle every page of every class is free or held by the radix cache:
+    nothing leaks and nothing is freed twice."""
+    fns, params, max_seq, prompt, n = pool
+    sched = DecodeScheduler(fns, params, 2, max_seq)
+    try:
+        ref = _collect(sched, prompt, n)
+        assert len(ref) == n
+        ROUTES_OUT[route](sched, prompt, n, ref)
+        until = time.monotonic() + 60
+        stats = sched.stats()
+        while stats["live_streams"] or stats["pending"]:
+            assert time.monotonic() < until, stats
+            time.sleep(0.01)
+            stats = sched.stats()
+        assert stats["restarts"] == 0
+        assert (stats["pages_free"] + stats["pages_cached"]
+                == stats["pages_total"] > 0)
+        assert stats["window_pages_free"] == stats["window_pages_total"]
+        assert bool(stats["window_pages_total"]) == bool(fns["window_class"])
+        assert stats["pages_cached"] > 0 or not fns["span_safe"]
     finally:
         sched.close()

@@ -520,7 +520,9 @@ def test_router_inflight_cap_sheds_typed_429(fleet):
             assert "in-flight request cap" in str(exc.value)
             t.join(timeout=10)
             assert done == [True]  # the in-flight request was untouched
-            # capacity freed: the next request goes through
+            # capacity freed (the router gives the slot back after it
+            # wrote the response): the next request goes through
+            assert _wait_until(lambda: capped.stats()["inflight"] == 0)
             client.infer("simple", [in0, in1])
         finally:
             client.close()
@@ -791,6 +793,10 @@ def test_remove_home_replica_hands_off_capable_stream(fleet,
     router = FleetRouter(fleet["backends"], probe_interval_s=0.1,
                          gen_ttl_s=30.0).start()
     try:
+        # the home's decode loop stalls before its seventh step (five
+        # tokens delivered) until the replica has left the fleet: the
+        # router cannot have the whole answer buffered by then
+        faults.install("scheduler.step", mode="partition", skip=6)
         body = _stream_body("t-member-remove")
         conn, resp = _open_stream(router.url, body)
         try:
@@ -802,6 +808,7 @@ def test_remove_home_replica_hands_off_capable_stream(fleet,
         assert home in fleet["backends"]
         handoffs_before = router.stats()["handoffs"]
         router.remove_replica(home)
+        faults.clear("scheduler.step")
         snap = router.generation_snapshot("t-member-remove")
         assert snap["home"] is None and snap["home_lost"] is True
         conn, resp = _open_stream(

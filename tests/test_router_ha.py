@@ -475,20 +475,38 @@ def test_router_sigterm_drain_finishes_streams_and_flushes_journal(
         reference = _tokens(ref_events)
 
         result = {}
+        signalled = threading.Event()
+
+        def mid_generation(n_events):
+            # the reader holds its first event and has not read the
+            # final one: the stream is admitted and still open
+            if n_events == 1:
+                router_proc.send_signal(signal.SIGTERM)
+                signalled.set()
 
         def worker():
             events, final = _stream(router_port,
-                                    _gen_body("dgen", 10, 50))
+                                    _gen_body("dgen", 10, 50),
+                                    on_event=mid_generation)
             result["tokens"] = _tokens(events)
             result["final"] = final
 
         thread = threading.Thread(target=worker, daemon=True)
         thread.start()
-        time.sleep(0.2)  # the stream is mid-generation
-        router_proc.send_signal(signal.SIGTERM)
-        # draining = stop admitting: a fresh request sheds typed 503
-        # (or the process already exited and refuses the connection)
+        assert signalled.wait(timeout=30), "the stream never started"
+        # draining = stop admitting: once the router says it drains, a
+        # fresh request sheds typed 503 (or the process already exited
+        # and refuses the connection)
         try:
+            draining, until = False, time.monotonic() + 30
+            while not draining:
+                assert time.monotonic() < until, "router never drained"
+                conn = http.client.HTTPConnection(
+                    "127.0.0.1", router_port, timeout=5)
+                conn.request("GET", "/router/stats")
+                draining = json.loads(
+                    conn.getresponse().read())["draining"]
+                conn.close()
             conn = http.client.HTTPConnection(
                 "127.0.0.1", router_port, timeout=5)
             conn.request("POST", STREAM_PATH, _gen_body("late", 4),
@@ -496,7 +514,7 @@ def test_router_sigterm_drain_finishes_streams_and_flushes_journal(
             resp = conn.getresponse()
             assert resp.status == 503, resp.status
             conn.close()
-        except (ConnectionError, OSError):
+        except (OSError, http.client.HTTPException):
             pass
         thread.join(timeout=30)
         assert not thread.is_alive(), "in-flight stream never finished"

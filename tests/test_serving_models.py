@@ -240,3 +240,31 @@ def test_llama_generate_pipelined_emission_boundaries():
     longest = seqs[11]
     for n, toks in seqs.items():
         assert toks == longest[:n], (n, toks, longest)
+
+
+def test_generate_model_options_are_scheduler_options():
+    """Every keyword ``LlamaGenerateModel`` hands ``DecodeScheduler``
+    (directly, or through the ``kv_hooks`` it splats) is one of the
+    scheduler's own, and the two option counts are the ones ROADMAP.md
+    D5 records: whoever adds an option moves the record with it."""
+    import ast
+    import inspect
+
+    from tpuserver.models import llama_serving
+    from tpuserver.scheduler import DecodeScheduler
+
+    tree = ast.parse(inspect.getsource(llama_serving))
+    forwarded = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and getattr(
+                node.func, "id", None) == "DecodeScheduler":
+            forwarded |= {k.arg for k in node.keywords if k.arg}
+        elif (isinstance(node, ast.Assign)
+              and getattr(node.targets[0], "id", None) == "kv_hooks"
+              and isinstance(node.value, ast.Call)):
+            forwarded |= {k.arg for k in node.value.keywords}
+    accepted = set(inspect.signature(DecodeScheduler.__init__).parameters)
+    assert {"kv_export", "metrics", "prefix_cache"} <= forwarded <= accepted
+    offered = inspect.signature(
+        llama_serving.LlamaGenerateModel.__init__).parameters
+    assert (len(offered) - 1, len(accepted) - 1) == (24, 21)

@@ -690,6 +690,33 @@ def test_scheduler_stats_keys_are_documented():
         "docs/resilience.md: {}".format(sorted(missing)))
 
 
+def test_decode_loop_has_one_step_dispatch():
+    """``DecodeScheduler._loop`` has ONE step body: it subscripts
+    ``fns["step"]`` at exactly one call site and names no second step
+    executable (whoever brings multi-token verification back writes it
+    against this body, not beside it)."""
+    import ast
+    import inspect
+    import textwrap
+
+    from tpuserver.scheduler import DecodeScheduler
+
+    fn = ast.parse(textwrap.dedent(
+        inspect.getsource(DecodeScheduler._loop))).body[0]
+    keys = [node.slice.value for node in ast.walk(fn)
+            if isinstance(node, ast.Subscript)
+            and isinstance(node.value, ast.Name) and node.value.id == "fns"
+            and isinstance(node.slice, ast.Constant)]
+    calls = [node.func.slice.value for node in ast.walk(fn)
+             if isinstance(node, ast.Call)
+             and isinstance(node.func, ast.Subscript)
+             and isinstance(node.func.value, ast.Name)
+             and node.func.value.id == "fns"]
+    assert keys.count("step") == 1 and calls.count("step") == 1
+    # the other executables of the bundle admit, prefill and park
+    assert {k for k in keys if "step" in k} == {"step"}, keys
+
+
 OBSERVABILITY_MD = os.path.join(REPO_ROOT, "docs", "observability.md")
 
 

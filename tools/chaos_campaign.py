@@ -109,10 +109,6 @@ def build_parser():
     ap.add_argument("--concurrency", type=int, default=32,
                     help="--proof: generation streams per worker "
                          "(default 32 => 64 total)")
-    ap.add_argument("--spec-tokens", type=int, default=0,
-                    help="run the stub replicas' speculative-decoding "
-                         "twin at this draft budget (0 = off); token "
-                         "identity must hold under every fault")
     ap.add_argument("--json", default=None,
                     help="write the campaign report (violations, "
                          "schedule, stats) here")
@@ -122,27 +118,22 @@ def build_parser():
 # -- fleet ------------------------------------------------------------------
 
 
-def start_fleet(cycles, manifest_dir=None, spec_tokens=0,
-                active_routers=1):
+def start_fleet(cycles, manifest_dir=None, active_routers=1):
     """The campaign target: a role-split stub fleet (1 prefill + 1
     decode) supervised together with an active+standby router pair
     sharing one crash journal — every tier a scheduled fault can hit
     is a real, supervised OS process.  ``manifest_dir`` makes the
     supervisor itself a target: ``supervisor_sigkill`` crashes it and
     a successor built from the SAME manifest adopts the fleet.
-    ``spec_tokens`` turns on the replicas' stub speculative-decoding
-    twin — burst emission must survive every scheduled fault with the
-    identical token streams.  ``active_routers=2`` (scheduled
-    automatically when ``active_router_sigkill`` is in the mix) runs
-    the PARTITIONED front tier — two actives with per-partition
-    journal subdirectories plus the standby."""
+    ``active_routers=2`` (scheduled automatically when
+    ``active_router_sigkill`` is in the mix) runs the PARTITIONED
+    front tier — two actives with per-partition journal
+    subdirectories plus the standby."""
     from tpuserver.fleet import FleetSupervisor
 
     stub = os.path.join(REPO, "tests", "fleet_stub.py")
     command = [sys.executable, stub, "--port", "{port}",
                "--scope", "{scope}"]
-    if spec_tokens > 0:
-        command += ["--spec-tokens", str(spec_tokens)]
     router_command = [
         sys.executable, os.path.join(REPO, "tools", "router.py"),
         "--backends", "{backends}", "--port", "{port}",
@@ -506,7 +497,6 @@ def run_campaign(args, schedule):
         manifest_dir = tempfile.mkdtemp(prefix="campaign-manifest-")
     supervisor = start_fleet(
         args.cycles, manifest_dir=manifest_dir,
-        spec_tokens=args.spec_tokens,
         active_routers=(2 if "active_router_sigkill" in schedule.kinds
                         else 1))
     injectors = FleetInjectors(supervisor, manifest_dir=manifest_dir)
@@ -591,8 +581,7 @@ def run_campaign(args, schedule):
                     and fleetmanifest.process_start_token(
                         row["pid"]) is not None}
                 supervisor = start_fleet(
-                    args.cycles, manifest_dir=manifest_dir,
-                    spec_tokens=args.spec_tokens)
+                    args.cycles, manifest_dir=manifest_dir)
                 injectors.supervisor = supervisor
                 summary["supervisor_restarts"] += 1
                 wait_converged(supervisor, recorder, context)
@@ -665,7 +654,7 @@ def run_proof(args, schedule):
 
     recorder = chaoslib.InvariantRecorder(sink)
     supervisor = start_fleet(
-        args.cycles, spec_tokens=args.spec_tokens,
+        args.cycles,
         active_routers=(2 if "active_router_sigkill" in schedule.kinds
                         else 1))
     injectors = FleetInjectors(supervisor)

@@ -499,13 +499,10 @@ def pool_phase(cycles, soak):
             f.stop()
 
 
-def router_phase(cycles, soak, budget, spec_tokens=0):
+def router_phase(cycles, soak, budget):
     """Fleet-router soak: plain clients stream through a FleetRouter
     over two replicas while one replica SIGTERM-drains/revives and live
-    upstream streams are severed mid-generation every cycle.  With
-    ``spec_tokens > 0`` both replicas run the speculative decoding
-    engine — the reference capture, severs, drains and handoffs must
-    all land on the identical token streams."""
+    upstream streams are severed mid-generation every cycle."""
     import signal
 
     import tritonclient.http as httpclient
@@ -520,7 +517,7 @@ def router_phase(cycles, soak, budget, spec_tokens=0):
         LlamaGenerateModel(
             cfg=llama.tiny(vocab=512), max_seq=64, max_slots=4,
             max_restarts=64, restart_window_s=3600.0,
-            restart_backoff_s=0.01, spec_tokens=spec_tokens)
+            restart_backoff_s=0.01)
         for _ in scopes
     ]
     cores = [
@@ -2390,11 +2387,6 @@ def main():
                         help="requests per worker per cycle (default: "
                              "40 in pool mode, 6 full generations in "
                              "router mode)")
-    parser.add_argument("--spec-tokens", type=int, default=0,
-                        help="router mode: run both replicas with the "
-                             "speculative decoding engine at this draft "
-                             "budget (0 = off); every identity check "
-                             "must still hold")
     args = parser.parse_args()
 
     if args.router_kill:
@@ -2532,8 +2524,7 @@ def main():
         # router soak default: fewer, heavier cycles (each cycle runs
         # 4 workers x soak full generations through the router)
         soak = args.soak if args.soak is not None else 6
-        router_phase(args.cycles, soak, args.budget,
-                     spec_tokens=args.spec_tokens)
+        router_phase(args.cycles, soak, args.budget)
         elapsed = time.monotonic() - t0
         if _failures:
             print("\nrouter chaos smoke FAILED: {} violation(s) in "

@@ -46,7 +46,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "src", "python"))
 
 
-def build_models(names, slots, spec_tokens=0):
+def build_models(names, slots):
     from tpuserver.models.simple import SimpleModel
 
     models = []
@@ -59,7 +59,7 @@ def build_models(names, slots, spec_tokens=0):
         tpuserver.enable_compile_cache()
         models.append(LlamaGenerateModel(
             cfg=llama.tiny(vocab=512), max_seq=64, max_slots=slots,
-            restart_backoff_s=0.01, spec_tokens=spec_tokens))
+            restart_backoff_s=0.01))
     if "simple" in names:
         models.append(SimpleModel())
     if not models:
@@ -75,8 +75,7 @@ def serve_replica(args):
     from tpuserver.http_frontend import HttpFrontend
 
     core = InferenceServer(
-        build_models(args.models.split(","), args.slots,
-                     spec_tokens=args.spec_tokens),
+        build_models(args.models.split(","), args.slots),
         fault_scope=args.scope or None,
         role=args.role or None,
         spawn_nonce=args.spawn_nonce or None)
@@ -131,11 +130,6 @@ def main(argv=None):
                     help="comma list of replica models (llama, simple)")
     ap.add_argument("--slots", type=int, default=4,
                     help="llama scheduler slots per replica (default 4)")
-    ap.add_argument("--spec-tokens", type=int, default=0,
-                    help="speculative decoding draft budget per replica "
-                         "scheduler step (0 = off; token streams are "
-                         "identical either way, docs/resilience.md "
-                         "'Speculative decoding')")
     ap.add_argument("--drain-timeout", type=float, default=10.0,
                     help="replica SIGTERM drain budget in seconds")
     ap.add_argument("--replicas", type=int, default=2,
@@ -222,15 +216,12 @@ def main(argv=None):
             sys.executable, os.path.join(REPO, "tests", "fleet_stub.py"),
             "--port", "{port}", "--scope", "{scope}",
         ]
-        if args.spec_tokens > 0:
-            command += ["--spec-tokens", str(args.spec_tokens)]
     else:
         command = [
             sys.executable, os.path.abspath(__file__), "--serve-replica",
             "--port", "{port}", "--scope", "{scope}",
             "--models", args.models, "--slots", str(args.slots),
             "--drain-timeout", str(args.drain_timeout),
-            "--spec-tokens", str(args.spec_tokens),
         ]
     router_command = None
     if (args.router_processes or args.router_standby
@@ -287,7 +278,10 @@ def main(argv=None):
         print("  replica {url} [{scope}] pid={pid} state={state}".format(
             **rep), flush=True)
     try:
-        stop.wait()
+        # a wait that times out, so a signal the kernel handed to
+        # another thread still reaches its handler (tools/router.py)
+        while not stop.wait(0.2):
+            pass
     finally:
         if disposition["action"] == "handover":
             supervisor.handover()

@@ -200,7 +200,12 @@ def main(argv=None):
         if args.partition_count > 1 else "",
     ), flush=True)
     try:
-        stop.wait()
+        # a wait that times out: Python runs a signal's handler only
+        # when the main thread wakes, and a SIGTERM the kernel handed
+        # to another thread does not wake a lock acquire without a
+        # timeout (seen under load: the handler never ran)
+        while not stop.wait(0.2):
+            pass
         if drain_first.is_set():
             print("router draining...", flush=True)
             router.drain(timeout_s=args.drain_timeout)
