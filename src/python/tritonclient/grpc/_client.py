@@ -22,7 +22,7 @@ from tritonclient.utils import InferenceServerException, raise_error
 from . import grpc_service_pb2 as pb
 from ._infer_input import InferInput, InferRequestedOutput  # noqa: F401
 from ._infer_result import InferResult
-from ._infer_stream import _InferStream
+from ._infer_stream import _InferStream, _PulledStream
 from ._service import ServiceStub
 from ._utils import (
     _get_inference_request,
@@ -836,61 +836,47 @@ class InferenceServerClient:
             request_id, base_params, headers, resume, max_reconnects,
             reconnect_backoff_s, read_timeout, on_reconnect, gen_id,
             _StreamDropped):
-        import queue as _queue
-
         last_seq = -1
         yielded_any = False
         attempt = 0
         while True:
             if len(targets) > 1:
                 self._rebind(targets[attempt % len(targets)])
-            responses = _queue.Queue()
+            send_params = dict(base_params)
+            sent_resume = gen_id is not None and last_seq >= 0
+            if sent_resume:
+                # mid-generation reconnect: ask the server to replay
+                # from the first seq we have not seen
+                send_params.pop("generation_id", None)
+                send_params["resume_generation_id"] = gen_id
+                send_params["resume_from_seq"] = last_seq + 1
+            request = _get_inference_request(
+                model_name=model_name,
+                inputs=inputs,
+                model_version=model_version,
+                request_id=request_id,
+                outputs=outputs,
+                parameters=send_params,
+            )
+            request.parameters[
+                "triton_enable_empty_final_response"
+            ].bool_param = True
+            if self._verbose:
+                print("generate_stream\n{}".format(request))
+            # one request, then the half-close: grpc sends both and the
+            # server's request side ends there; the caller's own thread
+            # reads the call, so a response crosses no thread and no
+            # queue of this client on its way here
+            self._stream = _PulledStream(
+                self._stub.ModelStreamInfer(
+                    iter((request,)), metadata=self._metadata(headers)),
+                read_timeout, self._verbose)
             try:
                 try:
-                    self.start_stream(
-                        lambda result, error: responses.put(
-                            (result, error)),
-                        headers=headers,
-                    )
-                    send_params = dict(base_params)
-                    sent_resume = gen_id is not None and last_seq >= 0
-                    if sent_resume:
-                        # mid-generation reconnect: ask the server to
-                        # replay from the first seq we have not seen
-                        send_params.pop("generation_id", None)
-                        send_params["resume_generation_id"] = gen_id
-                        send_params["resume_from_seq"] = last_seq + 1
-                    self.async_stream_infer(
-                        model_name,
-                        inputs,
-                        model_version=model_version,
-                        outputs=outputs,
-                        request_id=request_id,
-                        enable_empty_final_response=True,
-                        parameters=send_params,
-                    )
-                except InferenceServerException as e:
-                    # the just-opened stream died before (or while) the
-                    # request was enqueued — a transport-level failure
-                    # (in-band server errors never deactivate the
-                    # stream), so it rides the same reconnect path;
-                    # prefer the stream's own delivered error (e.g.
-                    # "connection refused") over the generic
-                    # stream-invalid message
-                    try:
-                        _, delivered = responses.get_nowait()
-                    except _queue.Empty:
-                        delivered = None
-                    raise _StreamDropped(delivered or e)
-                while True:
-                    try:
-                        result, error = responses.get(timeout=read_timeout)
-                    except _queue.Empty:
-                        raise InferenceServerException(
-                            "generate_stream: no response within "
-                            "{}s".format(read_timeout))
-                    if error is not None:
-                        if getattr(error, "status", lambda: None)() is None:
+                    for response in self._stream:
+                        if response.error_message:
+                            error = InferenceServerException(
+                                response.error_message)
                             if (sent_resume and "unknown or expired "
                                     "generation id" in str(error)):
                                 # OUR resume named a generation this
@@ -904,21 +890,25 @@ class InferenceServerClient:
                                 raise _StreamDropped(error)
                             # in-band server error: terminal
                             raise error
-                        raise _StreamDropped(error)
-                    resp = result.get_response()
-                    final = resp.parameters.get("triton_final_response")
-                    if final is not None and final.bool_param:
-                        return
-                    if "generation_id" in resp.parameters:
-                        gen_id = resp.parameters[
-                            "generation_id"].string_param
-                    if "seq" in resp.parameters:
-                        seq = resp.parameters["seq"].int64_param
-                        if seq <= last_seq:
-                            continue  # replayed duplicate
-                        last_seq = seq
-                    yielded_any = True
-                    yield result
+                        resp = response.infer_response
+                        final = resp.parameters.get("triton_final_response")
+                        if final is not None and final.bool_param:
+                            return
+                        if "generation_id" in resp.parameters:
+                            gen_id = resp.parameters[
+                                "generation_id"].string_param
+                        if "seq" in resp.parameters:
+                            seq = resp.parameters["seq"].int64_param
+                            if seq <= last_seq:
+                                continue  # replayed duplicate
+                            last_seq = seq
+                        yielded_any = True
+                        yield InferResult(resp)
+                    return  # the server ended the call
+                except grpc.RpcError as rpc_error:
+                    # the transport died (refused, reset, aborted by the
+                    # server): in-band errors never end the call this way
+                    raise _StreamDropped(get_error_grpc(rpc_error))
             except _StreamDropped as drop:
                 # resume is only safe with a resume token (the server
                 # marked the generation resumable) OR before anything
