@@ -1080,6 +1080,14 @@ class InferenceServer:
             "tpu_moe_layer_steps_total": "moe_layer_steps",
             "tpu_moe_local_pairs_total": "moe_local_pairs",
             "tpu_moe_experts_hit_total": "moe_experts_hit",
+            # the block steps' counts (0 for a one-token model)
+            "tpu_diffusion_row_passes_total": "diffusion_row_passes",
+            "tpu_diffusion_commit_passes_total":
+                "diffusion_commit_passes",
+            "tpu_diffusion_tokens_unmasked_total":
+                "diffusion_tokens_unmasked",
+            "tpu_diffusion_blocks_committed_total":
+                "diffusion_blocks_committed",
         }
         samples = {name: [] for name in per_family}
         # the decode loop's seconds by phase: the one family with a

@@ -214,6 +214,23 @@ CATALOG = {
         "over decode steps and routed layers, per model: over "
         "tpu_moe_layer_steps_total, the experts whose weights a "
         "layer-step had to read."),
+    # -- generation by diffusion over blocks --------------------------------
+    "tpu_diffusion_row_passes_total": (
+        "counter",
+        "Passes of a row's block fetched from block steps (denoise and "
+        "commit passes alike), per model: over "
+        "tpu_scheduler_step_seconds_count, the rows a step carries."),
+    "tpu_diffusion_commit_passes_total": (
+        "counter",
+        "Of tpu_diffusion_row_passes_total, the commit passes (no "
+        "position left masked: the pass whose K/V the cache keeps), per "
+        "model."),
+    "tpu_diffusion_tokens_unmasked_total": (
+        "counter",
+        "Positions that denoise passes unmasked, per model: over "
+        "tpu_diffusion_row_passes_total, the tokens a row pass yields."),
+    "tpu_diffusion_blocks_committed_total": (
+        "counter", "Blocks whose commit pass came back, per model."),
     # -- fleet router ------------------------------------------------------
     "tpu_router_failovers_total": (
         "counter", "Requests re-routed to another replica."),

@@ -96,6 +96,13 @@ class LlamaConfig:
     ffn_types: tuple = ()
     # the routed layers' geometry (MoEConfig), None without one
     moe: object = None
+    # generation by diffusion over blocks of this many positions (0 =
+    # one token at a time under the causal mask): query i sees key j iff
+    # j // block_len <= i // block_len, a step carries a block a row
+    # (paged_block_step), and ``mask_id`` is the token a position holds
+    # until a pass unmasks it
+    block_len: int = 0
+    mask_id: int = 0
 
     @property
     def head_dim(self):
@@ -130,14 +137,18 @@ class LlamaConfig:
         int8 and single-shard training paths were written for."""
         return not (self.layer_types or self.ffn_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norm
-                    or self.embed_scale != 1.0)
+                    or self.embed_scale != 1.0 or self.block_len)
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """A routed feed-forward layer: a sigmoid router over ``n_experts``
-    choosing ``top_k`` a token, SwiGLU experts of width ``d_expert``,
-    one shared expert of the same width every token passes through.
+    """A routed feed-forward layer: a router over ``n_experts`` (scores
+    ``score_func``: ``"sigmoid"`` of each logit, or ``"softmax"`` over
+    all experts before the choice; ``router_bias``: a per-expert bias
+    added for the choice alone) choosing ``top_k`` a token, SwiGLU
+    experts of width ``d_expert``, and ``n_shared`` shared experts
+    (one SwiGLU of ``n_shared`` times that width, none at 0) every token
+    passes through.
     ``first`` / ``count`` say which experts THIS process holds (expert
     parallelism: the router keeps its published width, the layer
     computes the shared expert plus the held experts' part of the sum
@@ -149,6 +160,9 @@ class MoEConfig:
     route_scale: float = 1.0
     first: int = 0
     count: int = 0      # 0 = all of them
+    score_func: str = "sigmoid"
+    router_bias: bool = True
+    n_shared: int = 1
 
     @property
     def held(self):
@@ -278,11 +292,14 @@ def _init_block_extras(key, cfg, i, dense):
         m = cfg.moe
         f, e = m.d_expert, m.held
         out["router"] = dense(ks[5], (d, m.n_experts), d)
-        out["router_bias"] = 0.1 * jax.random.normal(
-            ks[6], (m.n_experts,), jnp.float32)
-        out["ws_gate"] = dense(ks[7], (d, f), d)
-        out["ws_up"] = dense(ks[8], (d, f), d)
-        out["ws_down"] = dense(ks[9], (f, d), f)
+        if m.router_bias:
+            out["router_bias"] = 0.1 * jax.random.normal(
+                ks[6], (m.n_experts,), jnp.float32)
+        if m.n_shared:
+            fs = f * m.n_shared
+            out["ws_gate"] = dense(ks[7], (d, fs), d)
+            out["ws_up"] = dense(ks[8], (d, fs), d)
+            out["ws_down"] = dense(ks[9], (fs, d), fs)
         # every expert's values depend on its own id alone, so a share
         # holds the same experts the uncut layer has
         def experts(k, shape, fan_in):
@@ -295,13 +312,28 @@ def _init_block_extras(key, cfg, i, dense):
     return out
 
 
+def tiny_sdar(vocab=256, block_len=4):
+    """Test-size block-diffusion MoE block (the SDAR family): rotary GQA
+    with per-head QK norm, every layer routed by a softmax top-2 of 8
+    experts with no shared expert and no router bias, generation over
+    blocks of ``block_len`` with the vocabulary's last id as the mask."""
+    return LlamaConfig(
+        vocab=vocab, d_model=64, n_layers=2, n_heads=8, n_kv_heads=4,
+        d_head=16, d_ff=128, rope_theta=10000.0, qk_norm=True,
+        ffn_types=("moe", "moe"),
+        moe=MoEConfig(n_experts=8, top_k=2, d_expert=32,
+                      score_func="softmax", router_bias=False, n_shared=0),
+        block_len=block_len, mask_id=vocab - 1,
+    )
+
+
 def _need_plain(cfg, what):
     if not cfg.plain:
         raise UnsupportedArchitecture(
             "{} serves the plain Llama / Mistral block only; this "
             "configuration has per-layer attention or feed-forward kinds, "
-            "QK norm, an output gate, sandwich norms or a scaled "
-            "embedding".format(what))
+            "QK norm, an output gate, sandwich norms, a scaled embedding "
+            "or generation over blocks".format(what))
 
 
 def param_specs(cfg, quantized=False, quantized_embed=False):
@@ -474,14 +506,20 @@ def _swiglu(h, w_gate, w_up, w_down, red=lambda y: y):
 def _route(params, x, m):
     """The router's choice for rows x [n, Dm]: ``(chosen [n, top_k] of all
     n_experts, w [n, top_k])``.  Scores in float32 at full precision
-    (sigmoid); ``top_k`` of ``score + bias``, weighed by their own
-    scores, normalised and scaled.  A choice between two experts whose
-    scores nearly tie is the one discrete step of the layer, and it
-    should flip as rarely as arithmetic allows."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    (``m.score_func``: sigmoid, or softmax over all experts);
+    ``top_k`` of ``score + bias`` (of the score alone without a
+    ``router_bias``), weighed by their own scores, normalised and
+    scaled.  A choice between two experts whose scores nearly tie is the
+    one discrete step of the layer, and it should flip as rarely as
+    arithmetic allows."""
+    scores = jnp.dot(
         x.astype(jnp.float32), params["router"].astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
-    _, chosen = lax.top_k(scores + params["router_bias"], m.top_k)
+        precision=lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(scores, axis=-1) if m.score_func == "softmax"
+              else jax.nn.sigmoid(scores))
+    _, chosen = lax.top_k(
+        scores + params["router_bias"] if m.router_bias else scores,
+        m.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=1)
     if m.route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -491,7 +529,8 @@ def _route(params, x, m):
 def _moe_ffn(params, h, cfg, live=None, stats=None):
     """The routed feed-forward of one layer, as this process holds it:
     ``Shared(y) + sum over the chosen experts that are held here of
-    w_e Expert_e(y)`` for h [B, T, Dm].
+    w_e Expert_e(y)`` for h [B, T, Dm] (no ``Shared`` where the
+    configuration has no shared expert).
 
     The router (:func:`_route`) chooses among all ``n_experts``.  The
     (token, expert) pairs whose expert is held here (and whose row is
@@ -508,9 +547,12 @@ def _moe_ffn(params, h, cfg, live=None, stats=None):
     x = h.reshape(n, d)
     with jax.named_scope("moe.route"):
         chosen, w = _route(params, x, m)
-    with jax.named_scope("moe.shared"):
-        out = _swiglu(x, params["ws_gate"], params["ws_up"],
-                      params["ws_down"]).astype(jnp.float32)
+    if m.n_shared:
+        with jax.named_scope("moe.shared"):
+            out = _swiglu(x, params["ws_gate"], params["ws_up"],
+                          params["ws_down"]).astype(jnp.float32)
+    else:
+        out = jnp.zeros((n, d), jnp.float32)
     with jax.named_scope("moe.dispatch"):
         local = chosen - m.first
         held = (local >= 0) & (local < e)
@@ -594,13 +636,15 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
         return x + out
 
 
-def _dense_causal(q, k, v, n_rep, window=0):
-    """Plain causal (optionally windowed) self-attention, q [B, T, H, D]
-    against its own k/v [B, T, Hkv, D]: the dense form the windowed
-    layers fall back to where the flash kernel's tiles do not fit."""
+def _dense_causal(q, k, v, n_rep, window=0, block=0):
+    """Plain causal (optionally windowed, or block-causal) self-attention,
+    q [B, T, H, D] against its own k/v [B, T, Hkv, D]: the dense form
+    the windowed and the block layers fall back to where the flash
+    kernel's tiles do not fit."""
     t = q.shape[1]
     pos = jnp.tile(jnp.arange(t)[None, :], (q.shape[0], 1))
-    return _attend_cached(q, k, v, pos, t, n_rep, window=window)
+    return _attend_cached(q, k, v, pos, t, n_rep, window=window,
+                          block=block)
 
 
 def forward(params, tokens, cfg):
@@ -622,9 +666,10 @@ def forward(params, tokens, cfg):
                 q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
                 causal=True, block_q=bq, block_k=bk,
                 window=window or None,
+                block_causal=cfg.block_len or None,
             )
-        if window:
-            return _dense_causal(q, k, v, n_rep, window)
+        if window or cfg.block_len:
+            return _dense_causal(q, k, v, n_rep, window, cfg.block_len)
         return ring_attention(
             q, _expand_kv(k, n_rep), _expand_kv(v, n_rep), causal=True
         )
@@ -913,24 +958,27 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
                         q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
                         causal=True, block_q=pf_bq, block_k=pf_bk,
                         window=window or None,
+                        block_causal=cfg.block_len or None,
                     )
                 return _attend_cached(
                     q, new_cache[i, 0], new_cache[i, 1], positions, lengths,
-                    n_rep, window=window,
+                    n_rep, window=window, block=cfg.block_len,
                 )
 
         x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live)
     return x, new_cache
 
 
-def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0):
+def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0,
+                   block=0):
     """q: [B, Tq, H, D] against cache [B, S, Hkv, D].
 
     Masks cache positions >= ``length`` (a scalar, or a per-row [B]
     vector when the continuous-batching step decodes rows at different
     sequence positions) and (causally) > the query's own global position
     ``q_pos`` [B, Tq]; with ``window``, also those at or beyond
-    ``window`` positions behind the query."""
+    ``window`` positions behind the query.  With ``block`` the causal
+    bound is the end of the query's own block of that many positions."""
     k = _expand_kv(cache_k, n_rep)
     v = _expand_kv(cache_v, n_rep)
     s = jnp.einsum(
@@ -940,7 +988,10 @@ def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0):
     k_idx = jnp.arange(k.shape[1])[None, None, None, :]
     if getattr(length, "ndim", 0):
         length = length.reshape(-1, 1, 1, 1)  # per-row valid prefixes
-    mask = (k_idx >= length) | (k_idx > q_pos[:, None, :, None])
+    last = q_pos[:, None, :, None]
+    if block:
+        last = (last // block + 1) * block - 1
+    mask = (k_idx >= length) | (k_idx > last)
     if window:
         mask = mask | (k_idx <= q_pos[:, None, :, None] - window)
     s = jnp.where(mask, -jnp.inf, s)
@@ -1418,6 +1469,191 @@ def paged_scheduler_step(params, pages, logits_all, page_tables,
     return (tokens, tok_logp, new_logits, new_pages, *moe)
 
 
+# -- generation by diffusion over blocks --------------------------------------
+
+
+def init_block_state(cfg, rows):
+    """What a block step carries to the next, a row a slot: the block's
+    ``tokens`` [rows, B] (``mask_id`` where still masked), its ``masked``
+    bits, the block's first position ``start`` and the number ``pass``
+    of denoise passes the block has had."""
+    b = cfg.block_len
+    return {
+        "tokens": jnp.full((rows, b), cfg.mask_id, jnp.int32),
+        "masked": jnp.ones((rows, b), bool),
+        "start": jnp.zeros((rows,), jnp.int32),
+        "pass": jnp.zeros((rows,), jnp.int32),
+    }
+
+
+def prefill_blocks(params, cache, tokens, true_len, cfg):
+    """Prefill a PADDED prompt of a block configuration: the prompt's
+    whole blocks (its first ``true_len // B * B`` tokens) write their
+    K/V under the block-causal mask and nothing is read of them (no
+    head); the ``true_len % B`` tokens left over open the first block as
+    given tokens.  Returns ``(state, cache)``, the state
+    (:func:`init_block_state`) of one row.
+
+    A query sees only its own and earlier blocks, so the rows past the
+    whole blocks (the rest and the padding: K/V the first pass of the
+    first block overwrites or ``lengths`` masks) cannot reach them."""
+    b = cfg.block_len
+    rows, t = tokens.shape
+    positions = jnp.tile(jnp.arange(t)[None, :], (rows, 1))
+    aligned = true_len // b * b
+    x = _embed_rows(params, tokens, cfg)
+    live = positions < aligned if cfg.ffn_types else None
+    _, new_cache = _run_cached(params, cache, x, positions, 0, t, cfg,
+                               live=live)
+    tail = lax.dynamic_slice_in_dim(
+        jnp.pad(tokens, ((0, 0), (0, b))), aligned, b, axis=1)
+    given = (aligned + jnp.arange(b) < true_len)[None, :]
+    state = init_block_state(cfg, rows)
+    state.update(
+        tokens=jnp.where(given, tail, state["tokens"]),
+        masked=state["masked"] & ~given,
+        start=state["start"] + aligned)
+    return state, new_cache
+
+
+def unmask_block(logits, masked, n_pass, steps, taus, mask_id):
+    """The unmask rule of a denoise pass, a row at a time: logits
+    [S, B, V] float32 of the block's positions, ``masked`` [S, B],
+    ``n_pass`` [S] the passes the block has had, ``steps`` [S] the
+    row's ``denoising_steps`` T (1..B), ``taus`` [S] its confidence
+    threshold.  The mask token is never a prediction: its logit is
+    taken out first.  ``x0 = argmax``, ``c = softmax(logits)[x0]``; the static
+    schedule unmasks ``n = B // T`` (+1 in the first ``B % T`` passes)
+    masked positions of highest ``c`` (ties: lowest position); where at
+    least ``n`` masked positions have ``c > tau``, all of those instead.
+    ``n`` never passes the positions still masked: an unmasked token is
+    final.  Returns ``(x0, log c, newly unmasked)``, each [S, B]."""
+    b = masked.shape[1]
+    logits = logits.at[:, :, mask_id].set(-jnp.inf)
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    logc = jnp.max(logits, axis=-1) - jax.nn.logsumexp(logits, axis=-1)
+    conf = jnp.where(masked, jnp.exp(logc), -jnp.inf)
+    t = jnp.clip(steps, 1, b)
+    n = b // t + (n_pass < b % t).astype(jnp.int32)
+    n = jnp.minimum(n, jnp.sum(masked, axis=1, dtype=jnp.int32))
+    at = jnp.arange(b)
+    # ahead[s, j, k]: position k is unmasked before position j
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (at[None, None, :] < at[None, :, None]))
+    top = masked & (jnp.sum(ahead, axis=-1, dtype=jnp.int32) < n[:, None])
+    high = masked & (conf > taus[:, None])
+    enough = jnp.sum(high, axis=1, dtype=jnp.int32) >= n
+    return x0, logc, jnp.where(enough[:, None], high, top)
+
+
+def paged_block_step(params, pages, state, page_tables, steps, taus,
+                     active, cfg):
+    """One pass of every active row's current block over the paged
+    pool, in ONE device dispatch: the step of a configuration with
+    ``block_len`` B > 0, as :func:`paged_scheduler_step` is of the rest.
+
+    ``state`` (:func:`init_block_state`) holds each row's block.  The
+    pass embeds the block's B positions (the mask token where masked),
+    writes their K/V to the block's page slots (a later pass overwrites
+    them, the commit pass last) and attends the ``start + B`` positions
+    of all earlier blocks and the block itself with no mask inside it.
+    What is done with the logits [S, B, V] tells the two kinds of pass
+    apart, row by row:
+
+    - a **denoise** pass (some position masked) unmasks positions by
+      :func:`unmask_block`; an unmasked token is final;
+    - a **commit** pass (none masked) ran the block's final tokens, so
+      its K/V is what the cache keeps; the row's next block starts, all
+      masked, at ``start + B``.
+
+    ``steps`` / ``taus`` [S]: the rows' ``denoising_steps`` and
+    confidence thresholds; ``active`` [S]: rows that hold a request (the
+    rest write nothing, attend one position and keep their state).
+
+    Returns ``(out [S, 2B+3] int32, logc [S, B], new state, new pages,
+    routing counts)``; ``out`` is the block after the pass, the
+    positions the pass unmasked (0 / 1), the block's ``start``, whether
+    the pass was a commit, and the block's pass number; ``logc`` the
+    log-confidence of each position's ``x0`` in this pass."""
+    b = cfg.block_len
+    n_pages, page = pages.shape[2], pages.shape[3]
+    ppseq = page_tables.shape[1]
+    max_seq = ppseq * page
+    masked, start, n_pass = state["masked"], state["start"], state["pass"]
+    tokens = jnp.where(masked, cfg.mask_id, state["tokens"])
+    live_row = active & (start + b <= max_seq)
+    # inert rows: the sentinel position (writes drop), one key attended
+    first = jnp.where(live_row, start, max_seq)
+    lengths = jnp.where(live_row, start + b, 1).astype(jnp.int32)
+    positions = first[:, None] + jnp.arange(b)[None, :]      # [S, B]
+    logical = jnp.clip(first // page, 0, ppseq - 1)
+    phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)
+    phys = jnp.where(live_row[:, None], phys, n_pages)       # [S, 1]
+    # a block lies in one page: page_size is a multiple of B
+    offs = (first % page)[:, None] + jnp.arange(b)[None, :]
+    tbl = jnp.clip(page_tables, 0, n_pages - 1)
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+    path, pallas_block = paged_decode_path(cfg, max_seq, page)
+    with jax.named_scope("diffusion.embed_block"):
+        x = _embed_rows(params, tokens, cfg)                 # [S, B, Dm]
+    live = jnp.broadcast_to(live_row[:, None], tokens.shape)
+    stats = []
+    pool = pages
+
+    for i, layer in enumerate(params["layers"]):
+        def attn_fn(q, k, v, i=i):
+            nonlocal pool
+            with jax.named_scope("attn.kv_write"):
+                pool = pool.at[i, 0, phys, offs].set(
+                    k.astype(pool.dtype), mode="drop")
+                pool = pool.at[i, 1, phys, offs].set(
+                    v.astype(pool.dtype), mode="drop")
+            if path == "paged_kernel":
+                with jax.named_scope("attn.kernel"):
+                    from tpuserver.ops import paged_decode_attention
+
+                    return paged_decode_attention(
+                        q, pool, i, tbl, lengths, block_k=pallas_block)
+            with jax.named_scope("attn.page_gather"):
+                tail = pool.shape[4:]
+                k_seq = pool[i, 0][tbl].reshape(-1, max_seq, *tail)
+                v_seq = pool[i, 1][tbl].reshape(-1, max_seq, *tail)
+            with jax.named_scope("attn.kernel"):
+                # every query of the block sees all ``lengths`` keys
+                return _attend_cached(
+                    q, k_seq, v_seq,
+                    jnp.broadcast_to(lengths[:, None] - 1, tokens.shape),
+                    lengths, n_rep)
+
+        x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live,
+                   moe_stats=stats)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        logits = _mm(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("diffusion.unmask"):
+        x0, logc, newly = unmask_block(
+            logits, masked, n_pass, steps, taus, cfg.mask_id)
+        commit = ~jnp.any(masked, axis=1)
+        tokens = jnp.where(newly, x0, tokens)
+        out = jnp.concatenate([
+            tokens, newly.astype(jnp.int32), start[:, None],
+            commit.astype(jnp.int32)[:, None], n_pass[:, None]], axis=1)
+        nxt = {
+            "tokens": jnp.where(commit[:, None], cfg.mask_id, tokens),
+            "masked": jnp.where(commit[:, None], True, masked & ~newly),
+            "start": jnp.where(commit, start + b, start),
+            "pass": jnp.where(commit, 0, n_pass + 1),
+        }
+        # rows without a request keep their state
+        nxt = {k: jnp.where(active.reshape((-1,) + (1,) * (v.ndim - 1)),
+                            v, state[k]) for k, v in nxt.items()}
+    return out, logc, nxt, pool, jnp.stack([
+        jnp.int32(len(stats)), sum(s[0] for s in stats),
+        sum(s[1] for s in stats)])
+
+
+
 def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
                 slot):
     """Admit one prefilled request into the paged pool: the single-row
@@ -1425,18 +1661,29 @@ def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
     ``pages_per_seq`` logical pages and scatters to the physical ids
     ``dest_ids`` names (the sentinel ``n_pages`` drops a page — shared
     prefix pages already live in the pool and must not be rewritten).
-    The row's next-token logits land in ``logits_all`` row ``slot``."""
+    The row's next-token logits land in ``logits_all`` row ``slot`` (of
+    a block configuration: every leaf of the row's block state in that
+    of ``logits_all``, :func:`init_block_state`)."""
     page = pages.shape[3]
     ppseq = dest_ids.shape[0]
+    shape = pages.shape
+    if shape[4] % 8:
+        # fewer KV heads than the 8 rows of a tile: scattered as it
+        # lies, the pool is copied into a layout that tiles pages with
+        # heads and back (two copies of the whole pool and as much
+        # temporary memory an admission, seen on the v5e at 4 KV heads).
+        # Over the [.., page * Hkv, D] view, a bitcast, it is in place.
+        pages = pages.reshape(*shape[:3], page * shape[4], shape[5])
     src = slot_cache.reshape(
-        slot_cache.shape[0], 2, ppseq, page, *slot_cache.shape[4:]
+        slot_cache.shape[0], 2, ppseq, *pages.shape[3:]
     )
     pages = pages.at[:, :, dest_ids].set(
         src.astype(pages.dtype), mode="drop"
-    )
-    logits_all = lax.dynamic_update_slice_in_dim(
-        logits_all, slot_logits.astype(logits_all.dtype), slot, axis=0
-    )
+    ).reshape(shape)
+    logits_all = jax.tree_util.tree_map(
+        lambda rows, row: lax.dynamic_update_slice_in_dim(
+            rows, row.astype(rows.dtype), slot, axis=0),
+        logits_all, slot_logits)
     return pages, logits_all
 
 
@@ -1579,6 +1826,17 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     result, their ``[layer-steps, held pairs, distinct held experts
     hit]`` of the step.
 
+    A configuration that generates by diffusion over blocks
+    (``cfg.block_len`` B > 0) gets the block forms under the same keys:
+    ``step`` is :func:`paged_block_step` ``(params, pages, state,
+    page_tables, steps, taus, active)``, ``prefill`` is
+    :func:`prefill_blocks` (a row's block state in the logits' place),
+    ``init_logits`` the state of every slot (:func:`init_block_state`),
+    ``block_len`` / ``mask_id`` say so to the scheduler (0 otherwise),
+    and ``gather`` / ``prefill_span`` are absent: park, export, shared
+    prefixes and chunked prefill do not know blocks yet and the
+    scheduler refuses them by name.
+
     With a ``mesh`` the bundle is the GSPMD form: params
     Megatron-split, the page pool and slot cache kv-head-sharded over
     tp (``cache_spec`` — the page axes are unsharded, so the
@@ -1614,6 +1872,12 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     window_class = None
     if mesh is not None or quantized:
         _need_plain(cfg, "tensor-parallel or int8 serving")
+    if cfg.block_len and (cfg.window_layers or page_size % cfg.block_len
+                          or max_seq % cfg.block_len):
+        raise UnsupportedArchitecture(
+            "generation over blocks of {} needs full attention layers, "
+            "and a page_size ({}) and max_seq ({}) of whole blocks".format(
+                cfg.block_len, page_size, max_seq))
     if cfg.window_layers:
         ring = window_ring_pages(cfg, max_seq, page_size)
         n_wpages = (int(kv_window_pages) if kv_window_pages is not None
@@ -1658,6 +1922,19 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
 
         def init_logits():
             return jnp.zeros((max_slots, cfg.vocab), jnp.float32)
+
+        if cfg.block_len:
+            # a block a row a step: the block forms of step and prefill,
+            # the block state in the logits' place
+            gather = prefill_span_fn = None
+            step = jax.jit(
+                named_partial(paged_block_step, cfg=cfg),
+                donate_argnums=(1, 2),
+            )
+            prefill_fn = jax.jit(named_partial(prefill_blocks, cfg=cfg))
+
+            def init_logits():  # noqa: F811
+                return init_block_state(cfg, max_slots)
 
     else:
         param_sh, cache_sh, repl = serving_shardings(
@@ -1718,7 +1995,10 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "page_size": page_size,
         "pages_per_seq": pages_per_seq,
         "n_pages": n_pages,
-        "span_safe": cfg.attn_impl != "pallas" and window_class is None,
+        "span_safe": (cfg.attn_impl != "pallas" and window_class is None
+                      and not cfg.block_len),
+        "block_len": cfg.block_len,
+        "mask_id": cfg.mask_id,
         "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
         "window_class": window_class,
     }

@@ -40,6 +40,15 @@ reproducible.  An optional ``eos_id`` request parameter ends a
 generation early on that token (emitted, then the slot retires and is
 reused), on both paths.  See docs/resilience.md "Paged KV cache &
 radix prefix cache".
+
+Generation by diffusion over blocks (a configuration with
+``block_len`` > 0; continuous batching only): one streamed response a
+FINISHED BLOCK, carrying its tokens in position order (``TOKEN``,
+``LOGPROB``, ``POSITION``, ``UNMASK_PASS``, each of the block's length),
+sent by the pass that unmasks the block's last position.  Request
+parameters ``denoising_steps`` (1..block length; default the block
+length, one token a pass) and ``confidence_threshold`` (0..1; default 1,
+the static schedule alone) are the caller's trade of quality for speed.
 """
 
 import threading
@@ -85,6 +94,16 @@ class LlamaGenerateModel(Model):
                  target_queue_ms=None, shed_interval_ms=100.0,
                  params=None, kv_window_pages=None):
         self._cfg = cfg or llama.tiny(vocab=2048)
+        if self._cfg.block_len:
+            if max_slots < 2:
+                raise llama.UnsupportedArchitecture(
+                    "generation by diffusion over blocks is the "
+                    "scheduler's block step: max_slots must be > 1")
+            # one response a finished block, its tokens in position order
+            self.outputs = tuple(
+                TensorSpec(name, dtype, [-1]) for name, dtype in (
+                    ("TOKEN", "INT32"), ("LOGPROB", "FP32"),
+                    ("POSITION", "INT32"), ("UNMASK_PASS", "INT32")))
         # the weights, handed in: a pytree in ``llama.init_params``'s
         # layout for ``cfg`` (already on the device, in the served
         # type), or a callable that returns one when the model loads.
@@ -429,6 +448,10 @@ class LlamaGenerateModel(Model):
         eos_id = int(eos_id) if eos_id is not None else None
 
         ring = self._ring_writer(request)
+        if ring is not None and self._cfg.block_len:
+            raise llama.UnsupportedArchitecture(
+                "the shm token ring holds one token a slot; a block "
+                "configuration sends a block a response")
         ring_write = ring[1] if ring is not None else None
         seq_guarded = ring[2] if ring is not None else False
         # pin every referenced region for the stream's lifetime: a
@@ -676,8 +699,11 @@ class LlamaGenerateModel(Model):
             # "Disaggregated prefill/decode")
             kv_prefill = request.parameters.get("kv_phase") == "prefill"
             attach_cache, attach_pos = self._attach_from_params(request)
+            blocks = {k: request.parameters[k] for k in (
+                "denoising_steps", "confidence_threshold")
+                if request.parameters.get(k) is not None}
             stream = scheduler.submit(
-                prompt, max_tokens, eos_id=eos_id,
+                prompt, max_tokens, eos_id=eos_id, **blocks,
                 resume_cache=(jnp.asarray(parked)
                               if parked is not None else None),
                 resume_pos=pos, on_finish=on_finish,
@@ -699,7 +725,19 @@ class LlamaGenerateModel(Model):
             )
             seq = 0
         for token, logprob in stream:
-            if ring_write is not None:
+            if self._cfg.block_len:
+                # a finished block; seq counts responses
+                toks, lps, at, passes = token
+                yield {
+                    "TOKEN": np.array(toks, dtype=np.int32),
+                    "LOGPROB": np.array(lps, dtype=np.float32),
+                    "POSITION": np.array(at, dtype=np.int32),
+                    "UNMASK_PASS": np.array(passes, dtype=np.int32),
+                    RESPONSE_PARAMS_KEY: {
+                        "generation_id": gen_id, "seq": seq,
+                    },
+                }
+            elif ring_write is not None:
                 # the shm token ring: tensors land in the client's
                 # region slot; the event shrinks to its descriptor.
                 # Replayed tokens on resume REWRITE their slots (seq

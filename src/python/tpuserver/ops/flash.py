@@ -93,7 +93,7 @@ def _fold_finish(o_ref, m_scr, l_scr, acc_scr):
 
 def _attn_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, causal,
-    block_q, block_k, window=None):
+    block_q, block_k, window=None, block_causal=None):
     """One (batch*head, q-block, k-block) program.
 
     q_ref: [block_q, D]; k_ref/v_ref: [block_k, D]; o_ref: [block_q, D];
@@ -101,7 +101,9 @@ def _attn_kernel(
     across the (sequential) k-block grid dimension.  With ``window``
     (causal only) query i sees keys j with 0 <= i - j < window, and K/V
     blocks wholly behind the window are skipped like those above the
-    diagonal.
+    diagonal.  With ``block_causal`` = B (causal only) the diagonal is
+    one of blocks of B positions: query i sees key j iff
+    ``j < (i // B + 1) * B``, its own block in both directions.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -111,9 +113,15 @@ def _attn_kernel(
     def _init():
         _fold_init(m_scr, l_scr, acc_scr)
 
+    def last_seen(q_pos):
+        """The last key position a query at ``q_pos`` sees."""
+        if block_causal is None:
+            return q_pos
+        return (q_pos // block_causal + 1) * block_causal - 1
+
     # causal: K/V blocks wholly above the diagonal contribute nothing
     live = (
-        ki * block_k <= qi * block_q + (block_q - 1)
+        ki * block_k <= last_seen(qi * block_q + (block_q - 1))
         if causal
         else True
     )
@@ -137,7 +145,7 @@ def _attn_kernel(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            seen = k_pos <= q_pos
+            seen = k_pos <= last_seen(q_pos)
             if window is not None:
                 seen = jnp.logical_and(seen, k_pos > q_pos - window)
             s = jnp.where(seen, s, -jnp.inf)
@@ -155,11 +163,11 @@ def _attn_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "window"),
+                     "window", "block_causal"),
 )
 def flash_attention(
     q, k, v, causal=True, scale=None, block_q=128, block_k=128,
-    interpret=None, window=None):
+    interpret=None, window=None, block_causal=None):
     """Exact attention, q/k/v [B, T, H, D] -> [B, T, H, D].
 
     Drop-in for the XLA attention paths; T must be divisible by
@@ -169,6 +177,9 @@ def flash_attention(
     0 <= i - j < window; K/V blocks wholly outside a query block's
     window are neither folded nor fetched (their grid steps point at the
     nearest live block, which is already resident).
+    ``block_causal`` = B (causal only; generation by diffusion over
+    blocks): query i attends keys j with ``j < (i // B + 1) * B``, every
+    earlier block and its own block whole.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -187,11 +198,14 @@ def flash_attention(
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
 
-    if window is not None and not causal:
-        raise ValueError("a window needs causal attention")
+    if (window is not None or block_causal is not None) and not causal:
+        raise ValueError("a window or a block diagonal needs causal "
+                         "attention")
+    if window is not None and block_causal is not None:
+        raise ValueError("no window under a block diagonal")
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, window=window)
+        block_k=block_k, window=window, block_causal=block_causal)
 
     def kv_index(bh, i, j):
         if window is None:
@@ -223,7 +237,7 @@ def flash_attention(
 
 def _decode_fold(
     q_ref, k, v, ki, length, m_scr, l_scr, acc_scr, *, scale, block_k,
-    n_rep, start=None):
+    n_rep, start=None, n_q=1):
     """Fold K/V block ``ki`` of a row with ``length`` valid positions
     into the carried softmax state, on the MXU.  The one body of both
     decode kernels: they differ only in how the block got into VMEM.
@@ -238,15 +252,21 @@ def _decode_fold(
     window).  Their probabilities are exact zeros, so ``p . v`` is
     already the grouped [H, D]: GQA costs the MXU Hkv times the needed
     products and no repeat, relayout or per-head slice of the block.
+
+    ``n_q`` queries a row (a block of a diffusion step; all see the same
+    ``length`` positions) are ``n_q * H`` query rows of the same two
+    dots, row ``r`` being head ``r % H`` of query ``r // H``.
     """
     heads, cols = q_ref.shape[0], k.shape[0]
-    h_kv = heads // n_rep
+    h_kv = heads // n_q // n_rep
     s = jax.lax.dot_general(
         q_ref[:].astype(k.dtype), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale   # [H, bk * Hkv]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-    group = jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0), n_rep)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+    if n_q > 1:
+        head = jax.lax.rem(head, heads // n_q)
+    group = jax.lax.div(head, n_rep)
     # position c // Hkv < length  <=>  c < (length - first) * Hkv
     first = ki * block_k
     seen = col < (length - first) * h_kv
@@ -357,7 +377,8 @@ def decode_attention(
 
 
 def _paged_decode_kernel(
-    len_ref, tbl_ref, layer_ref, *refs, scale, block_k, n_rep, windowed):
+    len_ref, tbl_ref, layer_ref, *refs, scale, block_k, n_rep, windowed,
+    n_q=1):
     """One (row, k-block) program of decode attention over the page pool.
 
     The fold is :func:`_decode_kernel`'s; only the way a K/V block gets
@@ -451,7 +472,7 @@ def _paged_decode_kernel(
             _decode_fold(
                 q_ref, k_buf[slot], v_buf[slot], lb, length, m_scr, l_scr,
                 acc_scr, scale=scale, block_k=block_k, n_rep=n_rep,
-                start=start)
+                start=start, n_q=n_q)
 
     @pl.when(ki == nk - 1)
     def _finish():
@@ -459,13 +480,16 @@ def _paged_decode_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_k", "interpret"))
+    jax.jit, static_argnames=("scale", "block_k", "interpret", "n_q"))
 def paged_decode_attention(
     q, pages, layer, page_tables, lengths, scale=None, block_k=256,
-    interpret=None, starts=None):
+    interpret=None, starts=None, n_q=1):
     """:func:`decode_attention` over a page pool, read in place.
 
-    q: [B, H, D]; pages: the whole pool [L, 2, n_pages, page, Hkv, D]
+    q: [B, H, D], or [B, Q, H, D] for Q queries a row that all attend
+    the row's ``lengths`` positions (a block of a diffusion step: no
+    mask among them; the fold's dots then carry Q * H query rows);
+    pages: the whole pool [L, 2, n_pages, page, Hkv, D]
     (``models.llama.init_paged_kv_cache``), of which layer ``layer``
     (int32 scalar, may be traced) is attended; page_tables
     [B, pages_per_seq] int32 names each row's physical pages, every
@@ -483,12 +507,19 @@ def paged_decode_attention(
     ``p % pages_per_seq`` (:func:`_paged_decode_kernel`), so
     ``lengths`` may pass ``pages_per_seq * page`` while ``lengths -
     starts`` fits the ring less one block.
-    Returns [B, H, D].
+    Returns q's shape.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     interpret = kernel_interpret(interpret)
-    b, h, d = q.shape
+    if q.ndim == 4:
+        b, n_q, h, d = q.shape
+        return paged_decode_attention(
+            q.reshape(b, n_q * h, d), pages, layer, page_tables, lengths,
+            scale=scale, block_k=block_k, interpret=interpret,
+            starts=starts, n_q=n_q).reshape(q.shape)
+    b, rows, d = q.shape
+    h = rows // n_q
     page, h_kv = pages.shape[3], pages.shape[4]
     s = page_tables.shape[1] * page
     if h % h_kv:
@@ -504,7 +535,7 @@ def paged_decode_attention(
     windowed = starts is not None
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, block_k=block_k,
-        n_rep=h // h_kv, windowed=windowed)
+        n_rep=h // h_kv, windowed=windowed, n_q=n_q)
     prefetch = [lengths.astype(jnp.int32),
                 page_tables.astype(jnp.int32).reshape(-1),
                 jnp.asarray(layer, jnp.int32).reshape(1)]
@@ -514,19 +545,19 @@ def paged_decode_attention(
         num_scalar_prefetch=len(prefetch),
         grid=(b, s // block_k),
         in_specs=[
-            pl.BlockSpec((None, h, d), lambda b, ki, *refs: (b, 0, 0)),
+            pl.BlockSpec((None, rows, d), lambda b, ki, *refs: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (None, h, d), lambda b, ki, *refs: (b, 0, 0)),
+            (None, rows, d), lambda b, ki, *refs: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, block_k * h_kv, d), pages.dtype),
             pltpu.VMEM((2, block_k * h_kv, d), pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
     # the fold's 2-D view of a page: merging adjacent dims moves nothing
@@ -535,7 +566,7 @@ def paged_decode_attention(
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         # the slot hand-over needs every program to run in grid order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),

@@ -242,6 +242,10 @@ class _Stream:
         # FINISH (not just cancel-reap) and keep the export alive past
         # the completed park — a decode-role replica attaches it
         "kv_export_on_finish",
+        # generation by diffusion over blocks: the request's
+        # ``denoising_steps`` and confidence threshold, and the block in
+        # progress, position -> (token, logprob, pass that unmasked it)
+        "steps", "tau", "block",
     )
 
     def __init__(self, prompt, max_tokens, eos_id, resume_cache,
@@ -285,6 +289,9 @@ class _Stream:
         self.kv_export_on_finish = bool(kv_export_on_finish)
         self.attach_cache = None  # imported KV export (device array)
         self.attach_pos = 0       # its valid-prefix end position
+        self.steps = 0            # denoising passes a block (block step)
+        self.tau = 1.0            # confidence threshold (1 = schedule)
+        self.block = {}           # the block in progress
 
     def expired(self, now):
         return self.deadline is not None and now >= self.deadline
@@ -507,6 +514,18 @@ class DecodeScheduler:
         self._moe_layer_steps = 0
         self._moe_local_pairs = 0
         self._moe_experts_hit = 0
+        # what the block steps did (generation by diffusion over blocks;
+        # loop-written, grow-only, 0 for every other configuration): row
+        # passes fetched, those that were commit passes (each commits a
+        # block) and positions unmasked
+        self._diffusion_row_passes = 0
+        self._diffusion_commit_passes = 0
+        self._diffusion_tokens_unmasked = 0
+        # block length of a configuration that generates by diffusion
+        # over blocks (llama.make_scheduler_fns "block_len"), else 0:
+        # the step then carries a block a row, and what does not know
+        # blocks yet is refused by name in submit
+        self._block_len = int((fns or {}).get("block_len") or 0)
         # a pool of two page classes (window layers:
         # llama.make_scheduler_fns "window_class"): what still assumes
         # one table a sequence is refused by name in submit
@@ -555,10 +574,14 @@ class DecodeScheduler:
         # stats()["loop_seconds"], tpu_scheduler_loop_seconds_total)
         self._loop_seconds = dict.fromkeys(LOOP_PHASES, 0.0)
 
-    @staticmethod
-    def _unsupported(what):
+    def _unsupported(self, what):
         from tpuserver.models.llama import UnsupportedArchitecture
 
+        if self._block_len:
+            return UnsupportedArchitecture(
+                "{} is not served for a configuration that generates by "
+                "diffusion over blocks: it assumes one token a row a "
+                "step".format(what))
         return UnsupportedArchitecture(
             "{} is not served over a pool of two page classes (window "
             "layers): it assumes one page table a sequence".format(what))
@@ -569,10 +592,21 @@ class DecodeScheduler:
                resume_pos=0, on_finish=None, deadline=None,
                generation_id=None, prompt_dev=None, kv_export=False,
                kv_export_on_finish=False, attach_cache=None,
-               attach_pos=0):
+               attach_pos=0, denoising_steps=None,
+               confidence_threshold=None):
         """Enqueue one generation; returns an iterator of
         ``(token, logprob)`` pairs that blocks as the decode loop
         produces them.
+
+        A configuration that generates by diffusion over blocks yields
+        ``(block, None)`` pairs instead, one a finished block: ``block``
+        is ``(tokens, logprobs, positions, unmask passes)``, four lists
+        in position order, sent by the pass that unmasks the block's
+        last position.  ``denoising_steps`` (1..block length, default
+        the block length: one token a pass) and ``confidence_threshold``
+        (0..1, default 1: the static schedule alone) are its per-request
+        settings; generation runs in whole blocks and delivery stops at
+        ``max_tokens``.
 
         ``resume_cache``/``resume_pos`` continue from a parked KV cache
         (the prompt replays through the batched step without emission);
@@ -599,7 +633,22 @@ class DecodeScheduler:
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("PROMPT_IDS must be non-empty")
-        if self._window_class:
+        blk = self._block_len
+        if not blk and (denoising_steps is not None
+                        or confidence_threshold is not None):
+            raise ValueError(
+                "denoising_steps / confidence_threshold are settings of "
+                "generation by diffusion over blocks; this model "
+                "generates one token a step")
+        steps = blk if denoising_steps is None else int(denoising_steps)
+        tau = (1.0 if confidence_threshold is None
+               else float(confidence_threshold))
+        if blk and not (1 <= steps <= blk and 0.0 <= tau <= 1.0):
+            raise ValueError(
+                "denoising_steps must lie in 1..{} (got {}) and "
+                "confidence_threshold in 0..1 (got {})".format(
+                    blk, steps, tau))
+        if self._window_class or blk:
             for asked, what in (
                     (resume_cache is not None or on_finish is not None,
                      "park / resume of a KV cache (kv_cache_region)"),
@@ -610,6 +659,12 @@ class DecodeScheduler:
                 if asked:
                     raise self._unsupported(what)
         start = resume_pos if resume_cache is not None else 0
+        if blk and -(-(len(prompt) + max_tokens) // blk) * blk \
+                > self._max_seq:
+            raise ValueError(
+                "prompt ({}) + max_tokens ({}) in whole blocks of {} "
+                "exceeds max sequence {}".format(
+                    len(prompt), max_tokens, blk, self._max_seq))
         if start + len(prompt) + max_tokens > self._max_seq:
             raise ValueError(
                 "position ({}) + prompt ({}) + max_tokens ({}) exceeds max "
@@ -633,6 +688,7 @@ class DecodeScheduler:
             # back to the prefill path (token-identical, just slower)
             stream.attach_cache = attach_cache
             stream.attach_pos = int(attach_pos)
+        stream.steps, stream.tau = steps, tau
         with span("sched.submit"), self._cond:
             if self._closed:
                 raise SchedulerClosed("scheduler is shut down")
@@ -695,6 +751,9 @@ class DecodeScheduler:
         original request's deadline died with its connection — a
         reconnect carrying a fresh timeout must not be killed by the
         stale one."""
+        if self._block_len:
+            raise self._unsupported(
+                "resume of a generation (resume_generation_id)")
         from_seq = int(from_seq)
         # the park-race wait has its own bound; it must not clobber the
         # ``deadline`` parameter, which is the RECONNECT's own request
@@ -954,6 +1013,13 @@ class DecodeScheduler:
                 "moe_layer_steps": self._moe_layer_steps,
                 "moe_local_pairs": self._moe_local_pairs,
                 "moe_experts_hit": self._moe_experts_hit,
+                "diffusion_row_passes": self._diffusion_row_passes,
+                "diffusion_commit_passes": self._diffusion_commit_passes,
+                "diffusion_tokens_unmasked":
+                    self._diffusion_tokens_unmasked,
+                # a commit pass that came back is a block committed
+                "diffusion_blocks_committed":
+                    self._diffusion_commit_passes,
                 "loop_seconds": dict(self._loop_seconds),
                 # a fact of the build, not a rate: which decode
                 # attention the step executable holds
@@ -1118,6 +1184,9 @@ class DecodeScheduler:
         # re-prefill path (greedy decode makes both token-identical)
         stream.attach_cache = None
         stream.attach_pos = 0
+        # a block in progress dies with the loop: the re-admission
+        # starts it again from the delivered blocks
+        stream.block = {}
 
     # -- replay buffer -----------------------------------------------------
 
@@ -1238,6 +1307,9 @@ class DecodeScheduler:
             self._loop_seconds = seconds = dict(self._loop_seconds)
         phase = _LoopClock(seconds)
         fns = self._fns
+        # block length of a configuration that generates by diffusion
+        # over blocks (a step then carries a block a row), else 0
+        blk = self._block_len
         page = fns["page_size"]
         ppseq = fns["pages_per_seq"]
         n_pages = fns["n_pages"]
@@ -1506,6 +1578,10 @@ class DecodeScheduler:
                 # can never run out of pages mid-generation: exhaustion
                 # is a typed admission-time shed, not an OOM
                 span_end = start + len(stream.prompt) + stream.max_tokens
+                if blk:
+                    # reservation by block: generation runs in whole
+                    # blocks, whatever is delivered of the last
+                    span_end = -(-span_end // blk) * blk
                 span_pages = pages_for(span_end, page)
                 matched_nodes = []
                 shared_pages = 0
@@ -1733,7 +1809,8 @@ class DecodeScheduler:
                 self._admit_hist.observe(time.monotonic() - began)
 
         def emit(st, tok, lp):
-            """One token onto its stream's queue (``_cond`` held).  A
+            """One token (of a block configuration: one finished
+            block) onto its stream's queue (``_cond`` held).  A
             stream's first token on its first admission is the
             server-side time to first token; resumes and re-admissions
             after a restart (``incarnation`` > 1) restarted the stamp
@@ -1742,10 +1819,50 @@ class DecodeScheduler:
                     and self._first_token_hist is not None):
                 self._first_token_hist.observe(
                     time.monotonic() - st.enqueued_at)
-            st.history.append((tok, lp))
+            if blk:
+                # a finished block: ``tok`` is its (tokens, logprobs,
+                # positions, unmask passes), already cut to what is
+                # still to be delivered
+                st.history.extend(zip(tok[0], tok[1]))
+                st.emitted += len(tok[0])
+                self._tokens_total += len(tok[0])
+            else:
+                st.history.append((tok, lp))
+                st.emitted += 1
+                self._tokens_total += 1
             st.queue.put(("tok", tok, lp))
-            st.emitted += 1
-            self._tokens_total += 1
+
+        def block_pass(st, row, logcs):
+            """One fetched pass of a live row's block (``_cond`` held):
+            ``row`` / ``logcs`` are the step's results for the row
+            (``llama.paged_block_step``).  Counts the pass, records what
+            it unmasked, and when no position of the block is left
+            masked sends the block.  Returns ``(poisoned, finished)``."""
+            start, commit, n_pass = (int(n) for n in row[2 * blk:])
+            self._diffusion_row_passes += 1
+            # the keys the pass attended, every attention layer
+            self._context_tokens += (start + blk) * n_layers_all
+            if commit:
+                self._diffusion_commit_passes += 1
+                return False, False
+            for j in np.flatnonzero(row[blk:2 * blk]):
+                lp = float(logcs[j])
+                if not np.isfinite(lp):
+                    return True, False
+                st.block[start + int(j)] = (int(row[j]), lp, n_pass)
+                self._diffusion_tokens_unmasked += 1
+            # positions below st.pos were given (the prompt's rest)
+            if len(st.block) < start + blk - max(start, st.pos):
+                return False, False
+            at = sorted(st.block)[:st.max_tokens - st.emitted]
+            done = [st.block[p] for p in at]
+            st.block = {}
+            st.pos = start + blk
+            emit(st, ([t for t, _, _ in done], [lp for _, lp, _ in done],
+                      at, [n for _, _, n in done]), None)
+            return False, (st.emitted >= st.max_tokens or (
+                st.eos_id is not None
+                and any(t == st.eos_id for t, _, _ in done)))
 
         def finish(stream, slot):
             if stream.on_finish is not None:
@@ -1889,27 +2006,45 @@ class DecodeScheduler:
                 with phase("dispatch"):
                     # sentinel position max_seq on inert rows: their cache
                     # writes drop instead of corrupting a parked slot
-                    positions = np.full(
-                        (self._max_slots,), self._max_seq, np.int32)
                     active = np.zeros((self._max_slots,), bool)
-                    forced_tok = np.zeros((self._max_slots,), np.int32)
-                    forced_mask = np.zeros((self._max_slots,), bool)
                     snapshot = []
                     context = skipped = 0
-                    for i in active_ids:
-                        st = slots[i]
-                        positions[i] = st.pos
-                        active[i] = True
-                        was_forced = bool(st.forced)
-                        if was_forced:
-                            forced_tok[i] = st.forced.popleft()
-                            forced_mask[i] = True
-                        snapshot.append((i, st, was_forced, st.incarnation))
-                        context += st.pos + 1
-                        if wc:
-                            move_window(i, st)
-                            skipped += max(0, st.pos + 1 - window)
-                        st.pos += 1
+                    if blk:
+                        # a block a row: the device carries each row's
+                        # block, its start and its pass number; the host
+                        # says who is live and each row's settings (the
+                        # keys attended are counted when the pass comes
+                        # back and says where its block starts)
+                        steps = np.ones((self._max_slots,), np.int32)
+                        taus = np.ones((self._max_slots,), np.float32)
+                        for i in active_ids:
+                            st = slots[i]
+                            active[i] = True
+                            steps[i], taus[i] = st.steps, st.tau
+                            snapshot.append((i, st, False, st.incarnation))
+                        step_args = (steps, taus, active)
+                    else:
+                        positions = np.full(
+                            (self._max_slots,), self._max_seq, np.int32)
+                        forced_tok = np.zeros((self._max_slots,), np.int32)
+                        forced_mask = np.zeros((self._max_slots,), bool)
+                        for i in active_ids:
+                            st = slots[i]
+                            positions[i] = st.pos
+                            active[i] = True
+                            was_forced = bool(st.forced)
+                            if was_forced:
+                                forced_tok[i] = st.forced.popleft()
+                                forced_mask[i] = True
+                            snapshot.append(
+                                (i, st, was_forced, st.incarnation))
+                            context += st.pos + 1
+                            if wc:
+                                move_window(i, st)
+                                skipped += max(0, st.pos + 1 - window)
+                            st.pos += 1
+                        step_args = (positions, active, forced_tok,
+                                     forced_mask)
                     # key positions this step's attention layers cover,
                     # and those its window layers need not read
                     self._context_tokens += context * n_layers_all
@@ -1923,7 +2058,7 @@ class DecodeScheduler:
                     # left the donated cache consumed — exactly what the
                     # restart rebuilds.
                     action = faults.fire("scheduler.step", self.fault_scope)
-                    if action is not None and action[0] == "nan":
+                    if action is not None and action[0] == "nan" and not blk:
                         row = min(max(0, action[1]), self._max_slots - 1)
                         logits = logits.at[row].set(float("nan"))
                     step_start = time.monotonic()
@@ -1939,7 +2074,7 @@ class DecodeScheduler:
                         # memory instead of copying it)
                         {"full": tables, "window": tables_w.copy()} if wc
                         else tables,
-                        positions, active, forced_tok, forced_mask,
+                        *step_args,
                     )
                     self._beat(epoch, None)
                     if self._step_hist is not None:
@@ -1995,6 +2130,19 @@ class DecodeScheduler:
                                 continue
                             if was_forced:
                                 continue  # resumed-prompt feed, no emission
+                            if blk:
+                                # a pass of the row's block: 0..B
+                                # positions unmasked, a block sent when
+                                # its last position is
+                                poisoned, done = block_pass(
+                                    st, toks[i], lps[i])
+                                if poisoned:
+                                    quarantined.append((i, st))
+                                    release_pages(st, insert=False)
+                                    clear_slot(i)
+                                elif done:
+                                    finished.append((st, i))
+                                continue
                             tok = int(toks[i])
                             lp = float(lps[i])
                             if not np.isfinite(lp):

@@ -791,7 +791,15 @@ class InferenceServerClient:
         In-band ``error_message`` responses raise immediately — those
         are typed server failures (quarantined slot, expired resume
         id), not transport faults.  ``on_reconnect(attempt, exc)``
-        fires before each reattempt."""
+        fires before each reattempt.
+
+        A model that generates by diffusion over blocks sends one
+        response a FINISHED BLOCK: ``TOKEN``, ``LOGPROB``, ``POSITION``
+        and ``UNMASK_PASS``, each of the block's length, in position
+        order; ``parameters={"denoising_steps": n,
+        "confidence_threshold": tau}`` are its per-request settings, and
+        ``seq`` counts blocks.  It does not replay a dropped stream
+        yet: call with ``resume=False``."""
         if self._stream is not None:
             raise_error(
                 "cannot generate_stream with a stream already active"

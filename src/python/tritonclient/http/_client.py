@@ -985,6 +985,12 @@ class InferenceServerClient:
         ``inputs`` is a dict name -> numpy array (serialized as JSON
         data — generation prompts are small); ``parameters`` are the
         request parameters (``eos_id``, ``generation_id``, ...).
+        
+        A model that generates by diffusion over blocks sends one event
+        a finished block (outputs ``TOKEN``, ``LOGPROB``, ``POSITION``,
+        ``UNMASK_PASS`` of the block's length; request parameters
+        ``denoising_steps`` / ``confidence_threshold``); call it with
+        ``resume=False``: it does not replay a dropped stream yet.
         """
         import http.client as _http_client
 

@@ -141,3 +141,41 @@ def test_llama_streaming_example():
             result.stdout
     finally:
         frontend.stop()
+
+
+def test_block_diffusion_streaming_example():
+    """A block-diffusion answer streamed a block a response, at both
+    ``denoising_steps`` (own tiny-SDAR server on the scheduler)."""
+    from tpuserver.core import InferenceServer
+    from tpuserver.grpc_frontend import GrpcFrontend
+    from tpuserver.models import llama
+    from tpuserver.models.llama_serving import LlamaGenerateModel
+
+    core = InferenceServer([LlamaGenerateModel(
+        cfg=llama.tiny_sdar(), max_seq=64, max_slots=4)])
+    frontend = GrpcFrontend(core, port=0).start()
+    try:
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.path.join(REPO, "src", "python")
+        env["JAX_PLATFORMS"] = "cpu"
+        result = subprocess.run(
+            [sys.executable,
+             os.path.join(EXAMPLES_DIR, "block_diffusion_streaming_client.py"),
+             "-u", "127.0.0.1:{}".format(frontend.port), "-n", "12"],
+            capture_output=True, text=True, timeout=600, env=env,
+        )
+        assert result.returncode == 0, result.stdout + result.stderr
+        assert "PASS: block diffusion streaming" in result.stdout
+        # 12 tokens twice: delivered tokens and the block step's counters
+        text = core.metrics_text()
+        assert 'tpu_scheduler_tokens_total{model="llama_generate"} 24' in text
+        passes = {
+            line.split("{")[0]: float(line.rsplit(" ", 1)[1])
+            for line in text.splitlines()
+            if line.startswith("tpu_diffusion_")}
+        assert passes["tpu_diffusion_tokens_unmasked_total"] >= 24
+        assert (passes["tpu_diffusion_row_passes_total"]
+                > passes["tpu_diffusion_commit_passes_total"] > 0)
+    finally:
+        frontend.stop()
+        core.close()
