@@ -1084,6 +1084,8 @@ class InferenceServer:
             "tpu_diffusion_row_passes_total": "diffusion_row_passes",
             "tpu_diffusion_commit_passes_total":
                 "diffusion_commit_passes",
+            "tpu_diffusion_fused_commits_total":
+                "diffusion_fused_commits",
             "tpu_diffusion_tokens_unmasked_total":
                 "diffusion_tokens_unmasked",
             "tpu_diffusion_blocks_committed_total":

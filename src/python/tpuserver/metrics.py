@@ -217,20 +217,30 @@ CATALOG = {
     # -- generation by diffusion over blocks --------------------------------
     "tpu_diffusion_row_passes_total": (
         "counter",
-        "Passes of a row's block fetched from block steps (denoise and "
-        "commit passes alike), per model: over "
-        "tpu_scheduler_step_seconds_count, the rows a step carries."),
+        "Passes of a row's block fetched from block steps, one a slot a "
+        "step whatever the pass did (denoise, commit or both), per "
+        "model: over tpu_scheduler_step_seconds_count, the rows a step "
+        "carries."),
     "tpu_diffusion_commit_passes_total": (
         "counter",
-        "Of tpu_diffusion_row_passes_total, the commit passes (no "
-        "position left masked: the pass whose K/V the cache keeps), per "
+        "Of tpu_diffusion_row_passes_total, the passes that did NOTHING "
+        "but commit a block (no next block fits under max_seq), per "
         "model."),
+    "tpu_diffusion_fused_commits_total": (
+        "counter",
+        "Commits that rode on the next block's first denoise pass (one "
+        "pass, two blocks of the row), per model: over "
+        "tpu_diffusion_blocks_committed_total, how often a block costs "
+        "T passes and not T + 1."),
     "tpu_diffusion_tokens_unmasked_total": (
         "counter",
         "Positions that denoise passes unmasked, per model: over "
         "tpu_diffusion_row_passes_total, the tokens a row pass yields."),
     "tpu_diffusion_blocks_committed_total": (
-        "counter", "Blocks whose commit pass came back, per model."),
+        "counter",
+        "Blocks whose commit came back, per model: "
+        "tpu_diffusion_commit_passes_total + "
+        "tpu_diffusion_fused_commits_total."),
     # -- fleet router ------------------------------------------------------
     "tpu_router_failovers_total": (
         "counter", "Requests re-routed to another replica."),

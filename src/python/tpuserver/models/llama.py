@@ -1553,51 +1553,78 @@ def paged_block_step(params, pages, state, page_tables, steps, taus,
     pool, in ONE device dispatch: the step of a configuration with
     ``block_len`` B > 0, as :func:`paged_scheduler_step` is of the rest.
 
-    ``state`` (:func:`init_block_state`) holds each row's block.  The
-    pass embeds the block's B positions (the mask token where masked),
-    writes their K/V to the block's page slots (a later pass overwrites
-    them, the commit pass last) and attends the ``start + B`` positions
-    of all earlier blocks and the block itself with no mask inside it.
-    What is done with the logits [S, B, V] tells the two kinds of pass
-    apart, row by row:
+    ``state`` (:func:`init_block_state`) holds each row's block.  A pass
+    embeds a block's B positions (the mask token where masked), writes
+    their K/V to the block's page slots (a later pass overwrites them,
+    the commit last) and attends the ``start + B`` positions of all
+    earlier blocks and the block itself with no mask inside it.  What a
+    row does in a pass, read on the device from its state:
 
     - a **denoise** pass (some position masked) unmasks positions by
       :func:`unmask_block`; an unmasked token is final;
-    - a **commit** pass (none masked) ran the block's final tokens, so
-      its K/V is what the cache keeps; the row's next block starts, all
-      masked, at ``start + B``.
+    - a row with no position left masked **commits**: the block's final
+      tokens run once more, so their K/V is what the cache keeps.  The
+      SAME pass is the first denoise pass of the row's next block, all
+      masked, at ``start + B``: its queries attend ``start + 2B`` keys,
+      the committed K/V among them (written, layer by layer, before the
+      kernel reads the pool), while the committing block's queries see
+      their own ``start + B`` and never the opening block.  A block so
+      costs T passes, not T + 1;
+    - where no next block fits (``start + 2B > max_seq``) the pass only
+      commits, and the row's state moves on to ``start + B``.
+
+    The two blocks of a committing row are two VIRTUAL rows of one
+    forward: rows [0, S) carry each slot's denoise pass (or its lone
+    commit), rows [S, 2S) the commit that rides along, under the slot's
+    page table both.  The second half of a slot that is not committing
+    is inert as an inactive row is (sentinel position, one key attended,
+    writes dropped, no expert pair).  Head, logits and the unmask rule
+    run over the first S rows only: a commit needs no logits.
 
     ``steps`` / ``taus`` [S]: the rows' ``denoising_steps`` and
     confidence thresholds; ``active`` [S]: rows that hold a request (the
     rest write nothing, attend one position and keep their state).
 
-    Returns ``(out [S, 2B+3] int32, logc [S, B], new state, new pages,
-    routing counts)``; ``out`` is the block after the pass, the
-    positions the pass unmasked (0 / 1), the block's ``start``, whether
-    the pass was a commit, and the block's pass number; ``logc`` the
-    log-confidence of each position's ``x0`` in this pass."""
+    Returns ``(out [S, 2B+4] int32, logc [S, B], new state, new pages,
+    routing counts)``; ``out`` is the block the pass denoised after the
+    pass, the positions the pass unmasked (0 / 1), that block's
+    ``start``, whether the pass committed a block (alone: the block at
+    ``start``; riding along: the block before it), the block's pass
+    number, and whether the commit rode on the next block's first pass;
+    ``logc`` the log-confidence of each position's ``x0`` in this
+    pass."""
     b = cfg.block_len
     n_pages, page = pages.shape[2], pages.shape[3]
     ppseq = page_tables.shape[1]
     max_seq = ppseq * page
-    masked, start, n_pass = state["masked"], state["start"], state["pass"]
+    held = state["start"]
+    commit = ~jnp.any(state["masked"], axis=1)
+    # a committing row opens its next block in the same pass
+    fused = commit & (held + 2 * b <= max_seq)
+    start = jnp.where(fused, held + b, held)
+    masked = fused[:, None] | state["masked"]
+    n_pass = jnp.where(fused, 0, state["pass"])
     tokens = jnp.where(masked, cfg.mask_id, state["tokens"])
-    live_row = active & (start + b <= max_seq)
+    # two virtual rows a slot: its pass, and the commit riding along
+    tokens2 = jnp.concatenate([tokens, state["tokens"]])
+    live_row = jnp.concatenate(
+        [active & (start + b <= max_seq), active & fused])
     # inert rows: the sentinel position (writes drop), one key attended
-    first = jnp.where(live_row, start, max_seq)
-    lengths = jnp.where(live_row, start + b, 1).astype(jnp.int32)
-    positions = first[:, None] + jnp.arange(b)[None, :]      # [S, B]
+    first = jnp.where(live_row, jnp.concatenate([start, held]), max_seq)
+    lengths = jnp.where(live_row, first + b, 1).astype(jnp.int32)
+    positions = first[:, None] + jnp.arange(b)[None, :]      # [2S, B]
     logical = jnp.clip(first // page, 0, ppseq - 1)
-    phys = jnp.take_along_axis(page_tables, logical[:, None], axis=1)
-    phys = jnp.where(live_row[:, None], phys, n_pages)       # [S, 1]
+    tables2 = jnp.concatenate([page_tables, page_tables])
+    phys = jnp.take_along_axis(tables2, logical[:, None], axis=1)
+    phys = jnp.where(live_row[:, None], phys, n_pages)       # [2S, 1]
     # a block lies in one page: page_size is a multiple of B
     offs = (first % page)[:, None] + jnp.arange(b)[None, :]
-    tbl = jnp.clip(page_tables, 0, n_pages - 1)
+    tbl = jnp.clip(tables2, 0, n_pages - 1)
     n_rep = cfg.n_heads // cfg.n_kv_heads
     path, pallas_block = paged_decode_path(cfg, max_seq, page)
     with jax.named_scope("diffusion.embed_block"):
-        x = _embed_rows(params, tokens, cfg)                 # [S, B, Dm]
-    live = jnp.broadcast_to(live_row[:, None], tokens.shape)
+        x = _embed_rows(params, tokens2, cfg)                # [2S, B, Dm]
+    live = jnp.broadcast_to(live_row[:, None], tokens2.shape)
     stats = []
     pool = pages
 
@@ -1623,27 +1650,30 @@ def paged_block_step(params, pages, state, page_tables, steps, taus,
                 # every query of the block sees all ``lengths`` keys
                 return _attend_cached(
                     q, k_seq, v_seq,
-                    jnp.broadcast_to(lengths[:, None] - 1, tokens.shape),
+                    jnp.broadcast_to(lengths[:, None] - 1, tokens2.shape),
                     lengths, n_rep)
 
         x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live,
                    moe_stats=stats)
     with jax.named_scope("head"):
-        x = _rms_norm(x, params["norm"], cfg.norm_eps)
+        x = _rms_norm(x[:tokens.shape[0]], params["norm"], cfg.norm_eps)
         logits = _mm(x, params["lm_head"]).astype(jnp.float32)
     with jax.named_scope("diffusion.unmask"):
         x0, logc, newly = unmask_block(
             logits, masked, n_pass, steps, taus, cfg.mask_id)
-        commit = ~jnp.any(masked, axis=1)
         tokens = jnp.where(newly, x0, tokens)
         out = jnp.concatenate([
             tokens, newly.astype(jnp.int32), start[:, None],
-            commit.astype(jnp.int32)[:, None], n_pass[:, None]], axis=1)
+            commit.astype(jnp.int32)[:, None], n_pass[:, None],
+            fused.astype(jnp.int32)[:, None]], axis=1)
+        # a lone commit moves the row on; every other pass stays with
+        # the block it denoised
+        alone = commit & ~fused
         nxt = {
-            "tokens": jnp.where(commit[:, None], cfg.mask_id, tokens),
-            "masked": jnp.where(commit[:, None], True, masked & ~newly),
-            "start": jnp.where(commit, start + b, start),
-            "pass": jnp.where(commit, 0, n_pass + 1),
+            "tokens": jnp.where(alone[:, None], cfg.mask_id, tokens),
+            "masked": alone[:, None] | (masked & ~newly),
+            "start": jnp.where(alone, start + b, start),
+            "pass": jnp.where(alone, 0, n_pass + 1),
         }
         # rows without a request keep their state
         nxt = {k: jnp.where(active.reshape((-1,) + (1,) * (v.ndim - 1)),
@@ -1651,7 +1681,6 @@ def paged_block_step(params, pages, state, page_tables, steps, taus,
     return out, logc, nxt, pool, jnp.stack([
         jnp.int32(len(stats)), sum(s[0] for s in stats),
         sum(s[1] for s in stats)])
-
 
 
 def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,

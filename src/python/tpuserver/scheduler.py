@@ -516,10 +516,12 @@ class DecodeScheduler:
         self._moe_experts_hit = 0
         # what the block steps did (generation by diffusion over blocks;
         # loop-written, grow-only, 0 for every other configuration): row
-        # passes fetched, those that were commit passes (each commits a
-        # block) and positions unmasked
+        # passes fetched, those that did nothing but commit a block,
+        # commits that rode on the next block's first denoise pass, and
+        # positions unmasked
         self._diffusion_row_passes = 0
         self._diffusion_commit_passes = 0
+        self._diffusion_fused_commits = 0
         self._diffusion_tokens_unmasked = 0
         # block length of a configuration that generates by diffusion
         # over blocks (llama.make_scheduler_fns "block_len"), else 0:
@@ -1015,11 +1017,14 @@ class DecodeScheduler:
                 "moe_experts_hit": self._moe_experts_hit,
                 "diffusion_row_passes": self._diffusion_row_passes,
                 "diffusion_commit_passes": self._diffusion_commit_passes,
+                "diffusion_fused_commits": self._diffusion_fused_commits,
                 "diffusion_tokens_unmasked":
                     self._diffusion_tokens_unmasked,
-                # a commit pass that came back is a block committed
+                # a block is committed by a pass of its own or by the
+                # next block's first
                 "diffusion_blocks_committed":
-                    self._diffusion_commit_passes,
+                    self._diffusion_commit_passes
+                    + self._diffusion_fused_commits,
                 "loop_seconds": dict(self._loop_seconds),
                 # a fact of the build, not a rate: which decode
                 # attention the step executable holds
@@ -1835,14 +1840,21 @@ class DecodeScheduler:
         def block_pass(st, row, logcs):
             """One fetched pass of a live row's block (``_cond`` held):
             ``row`` / ``logcs`` are the step's results for the row
-            (``llama.paged_block_step``).  Counts the pass, records what
-            it unmasked, and when no position of the block is left
-            masked sends the block.  Returns ``(poisoned, finished)``."""
-            start, commit, n_pass = (int(n) for n in row[2 * blk:])
+            (``llama.paged_block_step``).  Counts the pass and the
+            commit it made or carried, records what it unmasked, and
+            when no position of the block is left masked sends the
+            block.  Returns ``(poisoned, finished)``."""
+            start, commit, n_pass, fused = (int(n) for n in row[2 * blk:])
             self._diffusion_row_passes += 1
-            # the keys the pass attended, every attention layer
-            self._context_tokens += (start + blk) * n_layers_all
-            if commit:
+            # the keys the pass attended, every attention layer; a
+            # commit that rode along attended the ``start`` keys up to
+            # its own block's end
+            self._context_tokens += (
+                start + blk + fused * start) * n_layers_all
+            if fused:
+                self._diffusion_fused_commits += 1
+            elif commit:
+                # the pass did nothing else
                 self._diffusion_commit_passes += 1
                 return False, False
             for j in np.flatnonzero(row[blk:2 * blk]):

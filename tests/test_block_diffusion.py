@@ -62,12 +62,11 @@ def fns():
     return llama.make_scheduler_fns(CFG, MAX_SEQ, SLOTS, page_size=PAGE)
 
 
-def serve_alone(fns, prompt, n, steps, tau=1.0, slot=1):
-    """Drive the bundle as the scheduler does for ONE request: prefill,
-    admit, then block steps until ``n`` tokens stand.  Returns the
-    records ``(position, token, log c, pass)`` in position order, the
-    pool and the row's page table."""
-    pages, state = fns["init_cache"](), fns["init_logits"]()
+def admit_row(fns, pages, state, prompt, n, slot):
+    """Prefill ``prompt`` and admit it to ``slot`` as the scheduler does,
+    with pages reserved for ``n`` tokens in whole blocks.  Returns the
+    pool, the carried state, the row's page table and the positions
+    reserved."""
     bucket = fns["prefill_bucket"](len(prompt))
     padded = np.zeros((1, bucket), np.int32)
     padded[0, :len(prompt)] = prompt
@@ -75,34 +74,64 @@ def serve_alone(fns, prompt, n, steps, tau=1.0, slot=1):
         PARAMS, fns["init_slot_cache"](), jnp.asarray(padded), len(prompt))
     table = np.full((fns["pages_per_seq"],), fns["n_pages"], np.int32)
     need = -(-(len(prompt) + n) // B) * B
-    table[:-(-need // PAGE)] = 3 + np.arange(-(-need // PAGE))
+    # each slot its own pages, from 3 up
+    table[:-(-need // PAGE)] = 3 + slot * fns["pages_per_seq"] + np.arange(
+        -(-need // PAGE))
     pages, state = fns["admit"](pages, state, slot_cache, slot_state, table,
                                 slot)
+    return pages, state, table, need
+
+
+def serve_alone(fns, prompt, n, steps, tau=1.0, slot=1):
+    """Drive the bundle as the scheduler does for ONE request: prefill,
+    admit, then block steps until ``n`` tokens stand, and the one step
+    after them.  Returns the records ``(position, token, log c, pass)``
+    in position order, the pool, the row's page table, and of the run's
+    steps: their number, the pool before the last and its result row."""
+    pages, state, table, need = admit_row(
+        fns, fns["init_cache"](), fns["init_logits"](), prompt, n, slot)
     tables = np.full((SLOTS, fns["pages_per_seq"]), fns["n_pages"], np.int32)
     tables[slot] = table
     active = np.arange(SLOTS) == slot
-    records, commits = [], 0
+    records, dispatched = [], 0
     while len(records) < need - len(prompt):
         out, logc, state, pages, _ = fns["step"](
             PARAMS, pages, state, tables, np.full((SLOTS,), steps, np.int32),
             np.full((SLOTS,), tau, np.float32), active)
+        dispatched += 1
         row, logc = np.asarray(out)[slot], np.asarray(logc)[slot]
-        start, commit, n_pass = row[2 * B:]
-        commits += commit
+        start, _, n_pass, _ = row[2 * B:]
         for j in np.flatnonzero(row[B:2 * B]):
             records.append((int(start + j), int(row[j]), float(logc[j]),
                             int(n_pass)))
-    # the commit pass of the last block
+    # the step after the last block's last denoise pass commits it (the
+    # scheduler's one-deep pipeline has dispatched it by then)
+    before = np.asarray(pages)
     out, _, state, pages, _ = fns["step"](
         PARAMS, pages, state, tables, np.full((SLOTS,), steps, np.int32),
         np.full((SLOTS,), tau, np.float32), active)
-    assert np.asarray(out)[slot][2 * B + 1] == 1
-    return sorted(records), np.asarray(pages), table
+    last = np.asarray(out)[slot]
+    assert last[2 * B + 1] == 1
+    return sorted(records), np.asarray(pages), table, {
+        "steps": dispatched, "before": before, "last": last}
 
 
 def reference(prompt, n, steps, tau=1.0):
     with jax.default_matmul_precision("highest"):
         return ref.generate(PARAMS, prompt, n, steps, tau, SHAPE)
+
+
+def assert_pages_hold(pages, table, seq):
+    """The row's pages hold the K/V a from-scratch forward over the final
+    sequence ``seq`` gives, every layer."""
+    with jax.default_matmul_precision("highest"):
+        _, kvs = ref.forward(PARAMS, np.asarray(seq), np.zeros(len(seq), bool),
+                             SHAPE, with_kv=True)
+    for layer, (k, v) in enumerate(kvs):
+        for kv, want_kv in ((0, k), (1, v)):
+            held = pages[layer, kv][table[:-(-len(seq) // PAGE)]].reshape(
+                -1, *pages.shape[4:])[:len(seq)]
+            np.testing.assert_allclose(held, np.asarray(want_kv), atol=2e-4)
 
 
 def assert_same_records(got, want):
@@ -119,17 +148,10 @@ def test_block_step_matches_reference(fns, rest, steps):
     same log-confidences, and in the pages the K/V a from-scratch
     forward over the final sequence gives."""
     prompt = list(np.random.default_rng(rest).integers(0, MASK, 8 + rest))
-    got, pages, table = serve_alone(fns, prompt, 7, steps)
+    got, pages, table, _ = serve_alone(fns, prompt, 7, steps)
     seq, want = reference(prompt, 7, steps)
     assert_same_records(got, want)
-    with jax.default_matmul_precision("highest"):
-        _, kvs = ref.forward(PARAMS, np.asarray(seq), np.zeros(len(seq), bool),
-                             SHAPE, with_kv=True)
-    for layer, (k, v) in enumerate(kvs):
-        for kv, want_kv in ((0, k), (1, v)):
-            held = pages[layer, kv][table[:-(-len(seq) // PAGE)]].reshape(
-                -1, *pages.shape[4:])[:len(seq)]
-            np.testing.assert_allclose(held, np.asarray(want_kv), atol=2e-4)
+    assert_pages_hold(pages, table, seq)
 
 
 @pytest.mark.parametrize("tau,passes", [(0.0, 1), (1.0, 4)])
@@ -137,7 +159,7 @@ def test_threshold_ends_of_the_range(fns, tau, passes):
     """tau 0: every confidence passes, a whole block in one pass; tau 1:
     none does, the static schedule."""
     prompt = list(range(5, 13))
-    got, _, _ = serve_alone(fns, prompt, 8, 4, tau)
+    got, *_ = serve_alone(fns, prompt, 8, 4, tau)
     assert_same_records(got, reference(prompt, 8, 4, tau)[1])
     assert max(r[3] for r in got) + 1 == passes
 
@@ -210,10 +232,128 @@ def test_rows_at_other_passes_and_step_classes_share_a_step(fns):
             (len(prompt) // B + 1 + i) * B for i in range(len(blocks) - 1)]
     assert stats["tokens"] == sum(n for _, n, _ in asks)
     assert stats["diffusion_tokens_unmasked"] >= stats["tokens"]
-    assert stats["diffusion_row_passes"] > stats["diffusion_commit_passes"] > 0
+    # every block but a request's last has a successor to commit it, in
+    # that block's first pass; a last block's commit is never fetched
+    blocks = sum(len(b) for b in together)
+    assert stats["diffusion_fused_commits"] == blocks - len(asks)
+    assert stats["diffusion_commit_passes"] == 0
     assert stats["diffusion_blocks_committed"] == stats[
-        "diffusion_commit_passes"]
+        "diffusion_fused_commits"] + stats["diffusion_commit_passes"]
+    # (of a last block cut at ``max_tokens`` not every pass is delivered)
+    assert stats["diffusion_row_passes"] >= sum(
+        max(u) + 1 for blks in together for *_, u in blks)
     assert stats["moe_local_pairs"] > 0 and stats["context_tokens"] > 0
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 4])
+def test_a_block_costs_its_denoise_passes_and_no_more(fns, steps):
+    """N blocks at ``denoising_steps`` T take N x T step dispatches and
+    the one-deep pipeline's last: each block's commit rode on its
+    successor's first denoise pass."""
+    calls = []
+
+    def counted(*args):
+        calls.append(1)
+        return fns["step"](*args)
+
+    scheduler = DecodeScheduler(dict(fns, step=counted), PARAMS, SLOTS,
+                                MAX_SEQ)
+    try:
+        blocks = [blk for blk, _ in scheduler.submit(
+            np.arange(3, 11), 20, denoising_steps=steps)]
+        stats = scheduler.stats()
+    finally:
+        scheduler.close()
+    assert [len(b[0]) for b in blocks] == [4] * 5
+    assert all(max(b[3]) == steps - 1 for b in blocks)
+    # (a commit pass of its own a block would make it 5 more)
+    assert 5 * steps <= len(calls) <= 5 * steps + 2
+    assert stats["diffusion_row_passes"] == 5 * steps
+    assert stats["diffusion_fused_commits"] == 4
+    assert stats["diffusion_blocks_committed"] == 4
+
+
+def test_block_with_no_room_for_a_successor_commits_alone(fns):
+    """The block that ends at ``max_seq``: its commit opens nothing (the
+    pass says so), the row then lies inert, and through the scheduler
+    the request ends as any other, with the reference's tokens."""
+    prompt = list(np.random.default_rng(9).integers(0, MASK, MAX_SEQ - 8))
+    got, pages, table, run = serve_alone(fns, prompt, 8, 2)
+    seq, want = reference(prompt, 8, 2)
+    assert_same_records(got, want)
+    assert len(seq) == MAX_SEQ and run["steps"] == 4
+    start, commit, _, fused = run["last"][2 * B:]
+    assert (start, commit, fused) == (MAX_SEQ - B, 1, 0)
+    assert not run["last"][B:2 * B].any()
+    assert_pages_hold(pages, table, seq)
+    others = np.setdiff1d(np.arange(pages.shape[2]), table)
+    assert not pages[:, :, others].any()
+    scheduler = _scheduler(fns)
+    try:
+        blocks = [blk for blk, _ in scheduler.submit(
+            np.asarray(prompt), 8, denoising_steps=2)]
+    finally:
+        scheduler.close()
+    assert [t for b in blocks for t in b[0]] == [r[1] for r in want]
+    assert [p for b in blocks for p in b[2]] == list(range(MAX_SEQ - 8, MAX_SEQ))
+
+
+@pytest.mark.parametrize("n,lands", [(8, "dropped"), (4, "own_page")])
+def test_step_after_the_last_block_writes_nothing_unreserved(fns, n, lands):
+    """The step dispatched before the host has seen a request's last
+    block commits that block and opens one past the request's end.  Its
+    K/V fall in the row's own last page or, past the reserved pages, on
+    the table's sentinel: every page outside the row's table is bit-equal
+    before and after, and inside it only the two blocks' slots differ."""
+    prompt = list(range(20, 28))
+    _, pages, table, run = serve_alone(fns, prompt, n, 2)
+    start, commit, n_pass, fused = run["last"][2 * B:]
+    end = len(prompt) + n
+    assert (start, commit, n_pass, fused) == (end, 1, 0, 1)
+    assert (end % PAGE == 0) == (lands == "dropped")
+    own = table[table < fns["n_pages"]]
+    others = np.setdiff1d(np.arange(pages.shape[2]), own)
+    assert np.array_equal(pages[:, :, others], run["before"][:, :, others])
+    changed = np.flatnonzero(
+        (pages[:, :, own] != run["before"][:, :, own]).any(axis=(0, 1, 2, 4, 5)))
+    committed = set(range(end - B, end))
+    opened = set(range(end, end + B)) if lands == "own_page" else set()
+    assert committed <= set(changed) <= committed | opened
+
+
+def test_committing_and_denoising_rows_share_a_step(fns):
+    """Two rows in the same steps, one at ``denoising_steps`` 1 (every
+    pass commits a block and opens the next) and one at 4 (a commit every
+    fourth): each row's results are what it gets with the other absent."""
+    asks = {0: (list(range(40, 48)), 1), 2: (list(range(60, 70)), 4)}
+
+    def drive(slots):
+        pages, state = fns["init_cache"](), fns["init_logits"]()
+        tables = np.full((SLOTS, fns["pages_per_seq"]), fns["n_pages"],
+                         np.int32)
+        steps = np.ones((SLOTS,), np.int32)
+        for slot in slots:
+            pages, state, tables[slot], _ = admit_row(
+                fns, pages, state, asks[slot][0], 16, slot)
+            steps[slot] = asks[slot][1]
+        active = np.isin(np.arange(SLOTS), slots)
+        rows = []
+        for _ in range(5):
+            out, logc, state, pages, _ = fns["step"](
+                PARAMS, pages, state, tables, steps,
+                np.ones((SLOTS,), np.float32), active)
+            rows.append((np.asarray(out), np.asarray(logc)))
+        return rows
+
+    together = drive([0, 2])
+    commits = np.array([[out[s][2 * B + 1] for s in (0, 2)]
+                        for out, _ in together])
+    # steps in which one row commits and the other does not
+    assert (commits[:, 0] != commits[:, 1]).sum() >= 3
+    for slot in asks:
+        for (out, logc), (out1, logc1) in zip(together, drive([slot])):
+            assert np.array_equal(out[slot], out1[slot])
+            np.testing.assert_allclose(logc[slot], logc1[slot], atol=1e-5)
 
 
 def test_softmax_route_and_layer_without_shared_expert():
@@ -312,7 +452,7 @@ def test_block_step_on_the_paged_kernel_matches_the_dense_path():
     kernel = llama.make_scheduler_fns(cfg, 128, SLOTS, page_size=PAGE)
     assert kernel["decode_attention"] == "paged_kernel"
     prompt = list(range(9, 9 + 18))
-    got, _, _ = serve_alone(kernel, prompt, 8, 2)
+    got, *_ = serve_alone(kernel, prompt, 8, 2)
     dense = llama.make_scheduler_fns(CFG, 128, SLOTS, page_size=PAGE)
     assert dense["decode_attention"] == "gather_dense"
     assert_same_records(got, serve_alone(dense, prompt, 8, 2)[0])

@@ -174,8 +174,12 @@ def test_block_diffusion_streaming_example():
             for line in text.splitlines()
             if line.startswith("tpu_diffusion_")}
         assert passes["tpu_diffusion_tokens_unmasked_total"] >= 24
+        # a block's commit rides on the next block's first pass
         assert (passes["tpu_diffusion_row_passes_total"]
-                > passes["tpu_diffusion_commit_passes_total"] > 0)
+                > passes["tpu_diffusion_fused_commits_total"] > 0)
+        assert passes["tpu_diffusion_blocks_committed_total"] == (
+            passes["tpu_diffusion_fused_commits_total"]
+            + passes["tpu_diffusion_commit_passes_total"])
     finally:
         frontend.stop()
         core.close()
