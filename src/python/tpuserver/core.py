@@ -1075,6 +1075,7 @@ class InferenceServer:
             "tpu_kv_window_pages_total": "window_pages_total",
             "tpu_kv_window_pages_free": "window_pages_free",
             "tpu_scheduler_context_tokens_total": "context_tokens",
+            "tpu_scheduler_context_bytes_total": "context_bytes",
             "tpu_scheduler_window_skipped_tokens_total":
                 "window_skipped_tokens",
             "tpu_moe_layer_steps_total": "moe_layer_steps",

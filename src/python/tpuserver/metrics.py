@@ -193,6 +193,15 @@ CATALOG = {
         "Key positions the decode steps' attention layers covered: per "
         "step and active row its context length, times the model's "
         "attention layers, per model (host side, from positions)."),
+    "tpu_scheduler_context_bytes_total": (
+        "counter",
+        "Bytes of cache the decode steps' attention layers covered: per "
+        "step and active row its context length times the bytes a "
+        "token holds in each attention layer's page class as the pool "
+        "stores it (a class's row bytes are read off the pool's array, "
+        "padding included), per model.  Over "
+        "tpu_scheduler_context_tokens_total, the bytes of one cached "
+        "token in one layer."),
     "tpu_scheduler_window_skipped_tokens_total": (
         "counter",
         "Of tpu_scheduler_context_tokens_total, the key positions that "

@@ -103,6 +103,10 @@ class LlamaConfig:
     # until a pass unmasks it
     block_len: int = 0
     mask_id: int = 0
+    # multi-head latent attention (MLAConfig) in place of the GQA
+    # projections, None without: the cache then holds one latent row a
+    # token a layer, and ``n_kv_heads`` / ``d_head`` are not read
+    mla: object = None
 
     @property
     def head_dim(self):
@@ -137,7 +141,8 @@ class LlamaConfig:
         int8 and single-shard training paths were written for."""
         return not (self.layer_types or self.ffn_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norm
-                    or self.embed_scale != 1.0 or self.block_len)
+                    or self.embed_scale != 1.0 or self.block_len
+                    or self.mla is not None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,7 +153,10 @@ class MoEConfig:
     added for the choice alone) choosing ``top_k`` a token, SwiGLU
     experts of width ``d_expert``, and ``n_shared`` shared experts
     (one SwiGLU of ``n_shared`` times that width, none at 0) every token
-    passes through.
+    passes through.  With ``n_group`` > 1 the choice is group-limited:
+    the experts lie in ``n_group`` equal groups in index order, a group
+    scores the sum of its 2 largest choice values, and only the experts
+    of the ``topk_group`` best groups can be chosen (1 / 1: no limit).
     ``first`` / ``count`` say which experts THIS process holds (expert
     parallelism: the router keeps its published width, the layer
     computes the shared expert plus the held experts' part of the sum
@@ -163,10 +171,55 @@ class MoEConfig:
     score_func: str = "sigmoid"
     router_bias: bool = True
     n_shared: int = 1
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def held(self):
         return self.count or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention: the query goes through a normed
+    bottleneck of ``q_lora``; keys and values of ALL heads are
+    up-projections of one normed latent of ``kv_lora`` a token, and a
+    rotary key of ``d_rope`` is shared by all heads.  A head's query and
+    key are ``d_nope`` position-free dimensions followed by ``d_rope``
+    rotary ones, its value ``d_v``.  Rotary pairs are (2k, 2k+1); with
+    ``rope_factor`` > 1 the frequencies are YaRN's blend
+    (:func:`yarn_inv_freq`) and the softmax scale carries
+    ``yarn_mscale(rope_factor, mscale_all_dim) ** 2``.
+
+    The cache holds ``width`` = ``kv_lora + d_rope`` values a token a
+    layer, ``[c_kv ; k_pe]``, in a row of ``row`` lanes: the width
+    rounded up to whole 128-lane tiles, zeros behind it.  (The TPU lays
+    an array's minor dimension out in tiles of 128 lanes whatever its
+    shape says, and a Pallas operand is never laid out otherwise: the
+    padding is stated so that the pool's bytes are what HBM holds.)"""
+    q_lora: int = 24
+    kv_lora: int = 32
+    d_nope: int = 16
+    d_rope: int = 8
+    d_v: int = 16
+    rope_factor: float = 1.0
+    rope_orig_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def d_qk(self):
+        return self.d_nope + self.d_rope
+
+    @property
+    def width(self):
+        return self.kv_lora + self.d_rope
+
+    @property
+    def row(self):
+        return -(-self.width // 128) * 128
 
 
 class UnsupportedArchitecture(ValueError):
@@ -223,6 +276,24 @@ def tiny_afmoe(vocab=256, window=32, first=0, count=0):
     )
 
 
+def tiny_deepseek(vocab=256, first=0, count=0):
+    """Test-size latent-attention MoE block (the DeepSeek-V3 family): a
+    dense layer, then routed layers; multi-head latent attention with
+    YaRN-scaled rotary pairs on 8 of a head's 24 query dimensions;
+    group-limited sigmoid top-4 of 16 experts in 4 groups of which 2
+    are kept, with a shared expert."""
+    return LlamaConfig(
+        vocab=vocab, d_model=64, n_layers=3, n_heads=8, n_kv_heads=8,
+        d_ff=128, rope_theta=10000.0, norm_eps=1e-6,
+        ffn_types=("dense", "moe", "moe"),
+        moe=MoEConfig(n_experts=16, top_k=4, d_expert=32, route_scale=2.5,
+                      first=first, count=count, n_group=4, topk_group=2),
+        mla=MLAConfig(q_lora=24, kv_lora=32, d_nope=16, d_rope=8, d_v=16,
+                      rope_factor=40.0, rope_orig_max=4096,
+                      mscale_all_dim=1.0),
+    )
+
+
 # -- parameters --------------------------------------------------------------
 
 
@@ -241,16 +312,19 @@ def init_params(key, cfg):
         ks = jax.random.split(kl, 7)
         layer = {
             "attn_norm": jnp.ones((cfg.d_model,), cfg.dtype),
-            "wq": dense(ks[0], (cfg.d_model, cfg.n_heads * hd),
-                        cfg.d_model),
-            "wk": dense(ks[1], (cfg.d_model, cfg.n_kv_heads * hd),
-                        cfg.d_model),
-            "wv": dense(ks[2], (cfg.d_model, cfg.n_kv_heads * hd),
-                        cfg.d_model),
-            "wo": dense(ks[3], (cfg.n_heads * hd, cfg.d_model),
-                        cfg.n_heads * hd),
             "mlp_norm": jnp.ones((cfg.d_model,), cfg.dtype),
         }
+        if cfg.mla is None:
+            layer.update({
+                "wq": dense(ks[0], (cfg.d_model, cfg.n_heads * hd),
+                            cfg.d_model),
+                "wk": dense(ks[1], (cfg.d_model, cfg.n_kv_heads * hd),
+                            cfg.d_model),
+                "wv": dense(ks[2], (cfg.d_model, cfg.n_kv_heads * hd),
+                            cfg.d_model),
+                "wo": dense(ks[3], (cfg.n_heads * hd, cfg.d_model),
+                            cfg.n_heads * hd),
+            })
         if not cfg.layer_moe(i):
             layer.update({
                 "w_gate": dense(ks[4], (cfg.d_model, cfg.d_ff), cfg.d_model),
@@ -281,6 +355,22 @@ def _init_block_extras(key, cfg, i, dense):
                 ).astype(cfg.dtype)
 
     out = {}
+    if cfg.mla is not None:
+        # the latent kind's projections in the GQA ones' place; the
+        # per-head up-projections of keys and values are two leaves, as
+        # the absorbed (decode) and the expanded (prefill) form use them
+        m, nh = cfg.mla, cfg.n_heads
+        km = jax.random.split(jax.random.fold_in(key, 8), 8)
+        out.update({
+            "wq_a": dense(km[0], (d, m.q_lora), d),
+            "q_a_norm": gain(km[1], m.q_lora),
+            "wq_b": dense(km[2], (m.q_lora, nh * m.d_qk), m.q_lora),
+            "wkv_a": dense(km[3], (d, m.width), d),
+            "kv_a_norm": gain(km[4], m.kv_lora),
+            "w_uk": dense(km[5], (nh, m.d_nope, m.kv_lora), m.kv_lora),
+            "w_uv": dense(km[6], (nh, m.kv_lora, m.d_v), m.kv_lora),
+            "wo": dense(km[7], (nh * m.d_v, d), nh * m.d_v),
+        })
     if cfg.qk_norm:
         out["q_norm"], out["k_norm"] = gain(ks[0], hd), gain(ks[1], hd)
     if cfg.attn_gate:
@@ -332,8 +422,8 @@ def _need_plain(cfg, what):
         raise UnsupportedArchitecture(
             "{} serves the plain Llama / Mistral block only; this "
             "configuration has per-layer attention or feed-forward kinds, "
-            "QK norm, an output gate, sandwich norms, a scaled embedding "
-            "or generation over blocks".format(what))
+            "QK norm, an output gate, sandwich norms, a scaled embedding, "
+            "latent attention or generation over blocks".format(what))
 
 
 def param_specs(cfg, quantized=False, quantized_embed=False):
@@ -491,6 +581,138 @@ def _rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+def yarn_mscale(factor, mscale):
+    """YaRN's attention-temperature term, 1 at or below factor 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+def yarn_inv_freq(m, theta):
+    """The ``d_rope / 2`` rotary frequencies of a latent-attention
+    configuration ``m`` (float64): plain ``theta ** (-2k / d_rope)`` at
+    ``rope_factor`` <= 1, else YaRN's blend of those (extrapolated) with
+    the same divided by the factor (interpolated), by a linear ramp
+    between the dimensions that make ``beta_fast`` and ``beta_slow``
+    rotations over ``rope_orig_max`` positions."""
+    d = m.d_rope
+    extra = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if m.rope_factor <= 1.0:
+        return extra
+
+    def dim_of(rotations):
+        return (d * np.log(m.rope_orig_max / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(dim_of(m.beta_fast)), 0)
+    high = min(np.ceil(dim_of(m.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2) - low) / (high - low), 0.0, 1.0)
+    return extra / m.rope_factor * ramp + extra * (1.0 - ramp)
+
+
+def mla_softmax_scale(m):
+    """``d_qk ** -0.5`` times the square of YaRN's temperature term."""
+    return float(m.d_qk ** -0.5
+                 * yarn_mscale(m.rope_factor, m.mscale_all_dim) ** 2)
+
+
+def _rope_pairs(x, positions, m, theta):
+    """Rotary embedding over pairs (2k, 2k+1) of the last dimension at
+    ``m``'s frequencies (:func:`yarn_inv_freq`).  x: [B, T, D] or
+    [B, T, H, D]; positions: [T] or [B, T]."""
+    if positions.ndim == 1:
+        positions = positions[None, :]
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        yarn_inv_freq(m, theta), jnp.float32)                # [B, T, D/2]
+    # cos and sin carry mscale / mscale_all_dim's terms' ratio
+    ratio = (yarn_mscale(m.rope_factor, m.mscale)
+             / yarn_mscale(m.rope_factor, m.mscale_all_dim))
+    cos, sin = jnp.cos(angles) * ratio, jnp.sin(angles) * ratio
+    if x.ndim == 4:
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _mla_project(params, h, positions, cfg, nh):
+    """The latent kind's projections of the normed input h [B, T, Dm]:
+    ``(q [B, T, H, d_nope + d_rope], latent [B, T, row])``, the query's
+    rotary part and the latent's shared rotary key already rotated, the
+    latent ``[RMSNorm(c_kv) ; k_pe ; zeros]`` as the cache keeps it."""
+    m = cfg.mla
+    B, T, _ = h.shape
+    with jax.named_scope("mla.q_proj"):
+        c_q = _rms_norm(_mm(h, params["wq_a"]), params["q_a_norm"],
+                        cfg.norm_eps)
+        q = _mm(c_q, params["wq_b"]).reshape(B, T, nh, m.d_qk)
+        q = jnp.concatenate(
+            [q[..., :m.d_nope],
+             _rope_pairs(q[..., m.d_nope:], positions, m, cfg.rope_theta)],
+            axis=-1)
+    with jax.named_scope("mla.kv_latent"):
+        kv = _mm(h, params["wkv_a"])
+        c_kv = _rms_norm(kv[..., :m.kv_lora], params["kv_a_norm"],
+                         cfg.norm_eps)
+        k_pe = _rope_pairs(kv[..., m.kv_lora:], positions, m,
+                           cfg.rope_theta)
+        latent = jnp.concatenate(
+            [c_kv, k_pe, jnp.zeros((B, T, m.row - m.width), c_kv.dtype)],
+            axis=-1)
+    return q, latent
+
+
+def _mla_expand(params, latent, cfg):
+    """The expanded form's keys and values of cached latents
+    [B, S, row]: ``k [B, S, H, d_nope + d_rope]`` (the shared rotary key
+    repeated a head) and ``v [B, S, H, d_v]``."""
+    m = cfg.mla
+    with jax.named_scope("mla.expand"):
+        c_kv = latent[..., :m.kv_lora]
+        k_nope = jnp.einsum("bsc,hnc->bshn", c_kv, params["w_uk"])
+        v = jnp.einsum("bsc,hcv->bshv", c_kv, params["w_uv"])
+        k_pe = jnp.broadcast_to(
+            latent[:, :, None, m.kv_lora:m.width],
+            k_nope.shape[:3] + (m.d_rope,))
+        return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
+def _mla_absorb_q(params, q, cfg):
+    """A query carried into the latent space: q [B, T, H, d_qk] ->
+    ``[W_UK q_nope ; q_pe ; zeros]`` [B, T, H, row], which scores a
+    cached row by one dot over the row's lanes."""
+    m = cfg.mla
+    with jax.named_scope("mla.absorb_q"):
+        q_lat = jnp.einsum("bthn,hnc->bthc", q[..., :m.d_nope],
+                           params["w_uk"])
+        return jnp.concatenate(
+            [q_lat, q[..., m.d_nope:],
+             jnp.zeros(q.shape[:3] + (m.row - m.width,), q.dtype)],
+            axis=-1)
+
+
+def _mla_absorb_out(params, u, cfg):
+    """The attended latents u [B, T, H, kv_lora] through each head's
+    value up-projection: [B, T, H, d_v]."""
+    with jax.named_scope("mla.absorb_out"):
+        return jnp.einsum("bthc,hcv->bthv", u, params["w_uv"])
+
+
+def _mla_attend_cached(params, q, latents, q_pos, lengths, cfg):
+    """Dense absorbed attention of q [B, T, H, d_qk] over cached latents
+    [B, S, row]: one key head of all the row's lanes whose first
+    ``kv_lora`` lanes are the value.  Where no kernel serves (test
+    sizes, the single-stream path)."""
+    m = cfg.mla
+    q_lat = _mla_absorb_q(params, q, cfg)
+    with jax.named_scope("attn.kernel"):
+        u = _attend_cached(
+            q_lat, latents[:, :, None, :], latents[:, :, None, :m.kv_lora],
+            q_pos, lengths, q.shape[2], scale=mla_softmax_scale(m))
+    return _mla_absorb_out(params, u, cfg)
+
+
 def _expand_kv(k, n_rep):
     """GQA: repeat kv heads to full head count. [B,T,Hkv,D] -> [B,T,H,D]."""
     if n_rep == 1:
@@ -508,8 +730,9 @@ def _route(params, x, m):
     n_experts, w [n, top_k])``.  Scores in float32 at full precision
     (``m.score_func``: sigmoid, or softmax over all experts);
     ``top_k`` of ``score + bias`` (of the score alone without a
-    ``router_bias``), weighed by their own scores, normalised and
-    scaled.  A choice between two experts whose scores nearly tie is the
+    ``router_bias``; among the kept groups' experts alone under a group
+    limit, :func:`_group_limited`), weighed by their own scores,
+    normalised and scaled.  A choice between two experts whose scores nearly tie is the
     one discrete step of the layer, and it should flip as rarely as
     arithmetic allows."""
     scores = jnp.dot(
@@ -517,13 +740,27 @@ def _route(params, x, m):
         precision=lax.Precision.HIGHEST)
     scores = (jax.nn.softmax(scores, axis=-1) if m.score_func == "softmax"
               else jax.nn.sigmoid(scores))
-    _, chosen = lax.top_k(
-        scores + params["router_bias"] if m.router_bias else scores,
-        m.top_k)
+    choice = scores + params["router_bias"] if m.router_bias else scores
+    if m.n_group > 1:
+        choice = _group_limited(choice, m)
+    _, chosen = lax.top_k(choice, m.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=1)
     if m.route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen, w * m.route_scale
+
+
+def _group_limited(choice, m):
+    """``choice`` [n, E] with every expert outside the ``topk_group``
+    best of the ``n_group`` groups at -inf.  A group's score is the sum
+    of its 2 largest choice values; ties go to the lower index."""
+    n, e = choice.shape
+    per = e // m.n_group
+    best, _ = lax.top_k(choice.reshape(n, m.n_group, per), min(2, per))
+    _, keep = lax.top_k(jnp.sum(best, axis=-1), m.topk_group)
+    kept = jnp.any(
+        keep[:, :, None] == jnp.arange(m.n_group)[None, None, :], axis=1)
+    return jnp.where(jnp.repeat(kept, per, axis=1), choice, -jnp.inf)
 
 
 def _moe_ffn(params, h, cfg, live=None, stats=None):
@@ -600,6 +837,14 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
     nh = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     red = reduce if reduce is not None else (lambda y: y)
+    if cfg.mla is not None:
+        # the latent kind: the closure gets the query, the cache row of
+        # the step's own tokens in the keys' place and no values (it
+        # writes the row, and attends absorbed or expanded)
+        return _block_ffn(
+            params, _block_latent_attn(params, x, positions, cfg, attn_fn,
+                                       nh, red),
+            cfg, red, layer, live, moe_stats)
     with jax.named_scope("attn.qkv"):
         h = _rms_norm(x, params["attn_norm"], cfg.norm_eps)
         q = _mm(h, params["wq"]).reshape(B, T, nh, hd)
@@ -624,6 +869,27 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
         if cfg.sandwich_norm:
             out = _rms_norm(out, params["attn_post_norm"], cfg.norm_eps)
         x = x + out
+    return _block_ffn(params, x, cfg, red, layer, live, moe_stats)
+
+
+def _block_latent_attn(params, x, positions, cfg, attn_fn, nh, red):
+    """The attention half of :func:`_block` under latent attention:
+    x [B, T, Dm] -> x + Attn(N(x))."""
+    B, T, _ = x.shape
+    h = _rms_norm(x, params["attn_norm"], cfg.norm_eps)
+    q, latent = _mla_project(params, h, positions, cfg, nh)
+    # the caller's closure: attn.kv_write, then mla.absorb_q /
+    # attn.kernel / mla.absorb_out, or mla.expand / attn.kernel
+    attn = attn_fn(q, latent, None)
+    with jax.named_scope("attn.out"):
+        out = red(_mm(attn.reshape(B, T, nh * cfg.mla.d_v), params["wo"]))
+        if cfg.sandwich_norm:
+            out = _rms_norm(out, params["attn_post_norm"], cfg.norm_eps)
+        return x + out
+
+
+def _block_ffn(params, x, cfg, red, layer, live, moe_stats):
+    """The feed-forward half of :func:`_block`: x -> x + FFN(N(x))."""
     with jax.named_scope("ffn"):
         h = _rms_norm(x, params["mlp_norm"], cfg.norm_eps)
         if cfg.layer_moe(layer):
@@ -636,7 +902,7 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
         return x + out
 
 
-def _dense_causal(q, k, v, n_rep, window=0, block=0):
+def _dense_causal(q, k, v, n_rep, window=0, block=0, scale=None):
     """Plain causal (optionally windowed, or block-causal) self-attention,
     q [B, T, H, D] against its own k/v [B, T, Hkv, D]: the dense form
     the windowed and the block layers fall back to where the flash
@@ -644,17 +910,22 @@ def _dense_causal(q, k, v, n_rep, window=0, block=0):
     t = q.shape[1]
     pos = jnp.tile(jnp.arange(t)[None, :], (q.shape[0], 1))
     return _attend_cached(q, k, v, pos, t, n_rep, window=window,
-                          block=block)
+                          block=block, scale=scale)
 
 
 def forward(params, tokens, cfg):
     """Teacher-forcing logits [B, T, vocab] (float32), single-shard attention
     (for sharded execution use ``sharded_forward``)."""
     B, T = tokens.shape
-    n_rep = cfg.n_heads // cfg.n_kv_heads
+    n_rep = 1 if cfg.mla is not None else cfg.n_heads // cfg.n_kv_heads
     positions = jnp.arange(T)
 
-    def attn_fn(q, k, v, window=0):
+    def attn_fn(q, k, v, window=0, layer=None):
+        scale = None
+        if cfg.mla is not None:
+            # the expanded form: every head its own keys and values
+            k, v = _mla_expand(layer, k, cfg)
+            scale = mla_softmax_scale(cfg.mla)
         bq, bk = _flash_blocks(T, cfg)
         if cfg.attn_impl == "pallas" and bq is not None and bk is not None:
             # MXU-tileable lengths only: the TPU lowering needs
@@ -666,10 +937,11 @@ def forward(params, tokens, cfg):
                 q, _expand_kv(k, n_rep), _expand_kv(v, n_rep),
                 causal=True, block_q=bq, block_k=bk,
                 window=window or None,
-                block_causal=cfg.block_len or None,
+                block_causal=cfg.block_len or None, scale=scale,
             )
-        if window or cfg.block_len:
-            return _dense_causal(q, k, v, n_rep, window, cfg.block_len)
+        if window or cfg.block_len or cfg.mla is not None:
+            return _dense_causal(q, k, v, n_rep, window, cfg.block_len,
+                                 scale)
         return ring_attention(
             q, _expand_kv(k, n_rep), _expand_kv(v, n_rep), causal=True
         )
@@ -677,7 +949,8 @@ def forward(params, tokens, cfg):
     x = _embed_rows(params, tokens, cfg)
     for i, layer in enumerate(params["layers"]):
         x = _block(layer, x, positions, cfg,
-                   functools.partial(attn_fn, window=cfg.layer_window(i)),
+                   functools.partial(attn_fn, window=cfg.layer_window(i),
+                                     layer=layer),
                    layer=i)
     x = _rms_norm(x, params["norm"], cfg.norm_eps)
     return _mm(x, params["lm_head"]).astype(jnp.float32)
@@ -808,8 +1081,13 @@ def make_train_step(mesh, cfg, learning_rate=3e-4):
 
 
 def init_kv_cache(cfg, batch, max_seq, dtype=None):
-    """[n_layers, 2, B, max_seq, n_kv_heads, head_dim] cache."""
+    """The contiguous cache, by what a token's row is: K and V of every
+    KV head, [n_layers, 2, B, max_seq, n_kv_heads, head_dim], or under
+    latent attention ONE latent row (``MLAConfig.row`` lanes), no K/V
+    pair and no head axis, [n_layers, B, max_seq, row]."""
     dtype = dtype or cfg.dtype
+    if cfg.mla is not None:
+        return jnp.zeros((cfg.n_layers, batch, max_seq, cfg.mla.row), dtype)
     return jnp.zeros(
         (cfg.n_layers, 2, batch, max_seq, cfg.n_kv_heads, cfg.head_dim),
         dtype,
@@ -873,7 +1151,11 @@ def _decode_kernel_block(cfg, max_seq):
         impl = _select_decode_impl(max_seq, None)
     if impl != "pallas":
         return None
-    return next((b for b in (256, 128) if max_seq % b == 0), None)
+    # a latent row is one KV head: larger blocks spread a grid step's
+    # fixed cost over more rows (v5e, 32 rows of ~8,000: 5.9 ms a step
+    # at 256, 4.7 at 512, 3.95 at 1,024, 4.05 at 2,048: PERF.md, PR 37)
+    blocks = (1024, 512, 256, 128) if cfg.mla is not None else (256, 128)
+    return next((b for b in blocks if max_seq % b == 0), None)
 
 
 def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
@@ -884,9 +1166,36 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
     x: [B, T, Dm] embedded inputs. Returns (x_out, new_cache).  The
     contiguous cache keeps every position of every layer; a window
     layer masks (dense) or skips (flash) what lies behind its window.
-    ``live`` [B, T]: rows that are real tokens (:func:`_moe_ffn`)."""
+    ``live`` [B, T]: rows that are real tokens (:func:`_moe_ffn`).
+    Under latent attention the cache is the latent one
+    (:func:`init_kv_cache`) and a layer writes one row a token."""
     n_rep = cfg.n_heads // cfg.n_kv_heads
     new_cache = cache
+
+    def latent_attn_fn(q, latent, _, i, layer):
+        """The latent kind: the step's rows land in the cache; a prefill
+        from position 0 at tileable lengths expands its own latents and
+        runs the flash kernel at the expanded head sizes, everything
+        else attends the cache absorbed and dense."""
+        nonlocal new_cache
+        with jax.named_scope("attn.kv_write"):
+            new_cache = new_cache.at[i].set(
+                lax.dynamic_update_slice_in_dim(
+                    new_cache[i], latent.astype(new_cache.dtype),
+                    write_pos, axis=1))
+        pf_bq, pf_bk = _flash_blocks(q.shape[1], cfg)
+        if (cfg.attn_impl == "pallas" and q.shape[1] > 1
+                and pf_bq is not None and pf_bk is not None
+                and isinstance(write_pos, int) and write_pos == 0):
+            from tpuserver.ops import flash_attention
+
+            k, v = _mla_expand(layer, latent, cfg)
+            with jax.named_scope("attn.kernel"):
+                return flash_attention(
+                    q, k, v, causal=True, block_q=pf_bq, block_k=pf_bk,
+                    scale=mla_softmax_scale(cfg.mla))
+        return _mla_attend_cached(layer, q, new_cache[i], positions,
+                                  lengths, cfg)
 
     for i, layer in enumerate(params["layers"]):
         window = cfg.layer_window(i)
@@ -965,12 +1274,14 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
                     n_rep, window=window, block=cfg.block_len,
                 )
 
+        if cfg.mla is not None:
+            attn_fn = functools.partial(latent_attn_fn, i=i, layer=layer)
         x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live)
     return x, new_cache
 
 
 def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0,
-                   block=0):
+                   block=0, scale=None):
     """q: [B, Tq, H, D] against cache [B, S, Hkv, D].
 
     Masks cache positions >= ``length`` (a scalar, or a per-row [B]
@@ -978,13 +1289,17 @@ def _attend_cached(q, cache_k, cache_v, q_pos, length, n_rep, window=0,
     sequence positions) and (causally) > the query's own global position
     ``q_pos`` [B, Tq]; with ``window``, also those at or beyond
     ``window`` positions behind the query.  With ``block`` the causal
-    bound is the end of the query's own block of that many positions."""
+    bound is the end of the query's own block of that many positions.
+    Scores are divided by the root of the head size, or multiplied by
+    ``scale`` where one is given; the values' head size may differ from
+    the keys'."""
     k = _expand_kv(cache_k, n_rep)
     v = _expand_kv(cache_v, n_rep)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
         preferred_element_type=jnp.float32,
-    ) / np.sqrt(q.shape[-1])
+    )
+    s = s / np.sqrt(q.shape[-1]) if scale is None else s * scale
     k_idx = jnp.arange(k.shape[1])[None, None, None, :]
     if getattr(length, "ndim", 0):
         length = length.reshape(-1, 1, 1, 1)  # per-row valid prefixes
@@ -1138,6 +1453,10 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
     identical to ``decode_step``'s, which is what makes greedy tokens
     from N interleaved slots equal to N sequential single-stream runs.
     """
+    if cfg.mla is not None:
+        raise UnsupportedArchitecture(
+            "the slotted decode step reads K and V rows; latent attention "
+            "is served over the paged pool (paged_batched_decode_step)")
     S = tokens.shape[0]
     max_seq = cache.shape[3]
     q_pos = positions[:, None]  # [S, 1]
@@ -1245,16 +1564,29 @@ def scheduler_extract(cache, slot):
 
 
 def init_paged_kv_cache(cfg, n_pages, page_size, dtype=None):
-    """[n_layers, 2, n_pages, page_size, n_kv_heads, head_dim] page
-    pool — the paged form of :func:`init_kv_cache`.  A sequence's KV
-    lives scattered across pages named by its page table; page id
-    ``n_pages`` is the out-of-bounds scatter sentinel (writes drop)."""
+    """The page pool — the paged form of :func:`init_kv_cache`, one
+    page class by what a token's row is: a K/V class
+    [n_layers, 2, n_pages, page_size, n_kv_heads, head_dim], or under
+    latent attention a latent class [n_layers, n_pages, page_size, row]
+    (one ``[c_kv ; k_pe ; padding]`` row a token, no K/V pair, no head
+    axis).  A sequence's rows live scattered across pages named by its
+    page table; page id ``n_pages`` is the out-of-bounds scatter
+    sentinel (writes drop)."""
     dtype = dtype or cfg.dtype
+    if cfg.mla is not None:
+        return jnp.zeros((cfg.n_layers, n_pages, page_size, cfg.mla.row),
+                         dtype)
     return jnp.zeros(
         (cfg.n_layers, 2, n_pages, page_size, cfg.n_kv_heads,
          cfg.head_dim),
         dtype,
     )
+
+
+def pool_geometry(pool):
+    """``(n_pages, page_size)`` of one page class, K/V or latent
+    (:func:`init_paged_kv_cache`)."""
+    return pool.shape[-3:-1] if pool.ndim == 4 else pool.shape[2:4]
 
 
 def window_ring_pages(cfg, max_seq, page_size):
@@ -1321,8 +1653,9 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     per sequence row, with each row's KV scattered across the physical
     pages its ``page_tables`` row names.
 
-    ``pages`` is the pool from :func:`init_paged_kv_cache`;
-    ``page_tables`` [S, pages_per_seq] int32 maps each row's logical
+    ``pages`` is the pool from :func:`init_paged_kv_cache` (a K/V class,
+    or a latent class, whose layers attend through ``latent_attn_fn``
+    below); ``page_tables`` [S, pages_per_seq] int32 maps each row's logical
     pages to physical ids (entries may be the sentinel ``n_pages`` for
     unreserved logical pages — they are never read below the row's
     valid length and never written).
@@ -1353,7 +1686,7 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     classes = isinstance(pages, dict)
     pools = dict(pages) if classes else {"full": pages}
     tables = page_tables if classes else {"full": page_tables}
-    n_pages, page = pools["full"].shape[2], pools["full"].shape[3]
+    n_pages, page = pool_geometry(pools["full"])
     ppseq = tables["full"].shape[1]
     max_seq = ppseq * page
     # inert rows clamp to length 1 (see batched_decode_step)
@@ -1392,6 +1725,30 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     stats = [] if cfg.ffn_types else None
     slot_of = {i: ("window", n) for n, i in enumerate(cfg.window_layers)}
     slot_of.update((i, ("full", n)) for n, i in enumerate(cfg.full_layers))
+
+    def latent_attn_fn(q, latent, _, i, layer):
+        """The latent class: the row's new latent lands in its page,
+        then the absorbed form: the query carried into the latent space,
+        the kernel over the row's pages read ONCE as key and as value
+        (``ops.latent_decode_attention``; the gather and dense attention
+        where no kernel serves), the value up-projection after it."""
+        pool = pools["full"]
+        with jax.named_scope("attn.kv_write"):
+            pool = pools["full"] = pool.at[i, write["full"], offs].set(
+                latent[:, 0].astype(pool.dtype), mode="drop")
+        if path != "paged_kernel":
+            with jax.named_scope("attn.page_gather"):
+                rows = pool[i][tbl["full"]].reshape(S, max_seq, -1)
+            return _mla_attend_cached(layer, q, rows, q_pos, lengths, cfg)
+        from tpuserver.ops import latent_decode_attention
+
+        q_lat = _mla_absorb_q(layer, q, cfg)
+        with jax.named_scope("attn.kernel"):
+            u = latent_decode_attention(
+                q_lat[:, 0], pool, i, tbl["full"],
+                lengths.astype(jnp.int32), d_v=cfg.mla.kv_lora,
+                scale=mla_softmax_scale(cfg.mla), block_k=pallas_block)
+        return _mla_absorb_out(layer, u[:, None], cfg)
 
     for i, layer in enumerate(params["layers"]):
         def attn_fn(q, k, v, i=i):
@@ -1434,6 +1791,8 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
                 return _attend_cached(
                     q, k_seq, v_seq, q_pos, lengths, n_rep)
 
+        if cfg.mla is not None:
+            attn_fn = functools.partial(latent_attn_fn, i=i, layer=layer)
         x = _block(layer, x, q_pos, cfg, attn_fn, layer=i, live=live,
                    moe_stats=stats)
     with jax.named_scope("head"):
@@ -1692,9 +2051,17 @@ def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
     prefix pages already live in the pool and must not be rewritten).
     The row's next-token logits land in ``logits_all`` row ``slot`` (of
     a block configuration: every leaf of the row's block state in that
-    of ``logits_all``, :func:`init_block_state`)."""
-    page = pages.shape[3]
+    of ``logits_all``, :func:`init_block_state`).  A latent class takes
+    the latent slot cache [L, 1, max_seq, row] the same way, a row a
+    token."""
     ppseq = dest_ids.shape[0]
+    if pages.ndim == 4:
+        src = slot_cache.reshape(
+            slot_cache.shape[0], ppseq, *pages.shape[2:])
+        pages = pages.at[:, dest_ids].set(
+            src.astype(pages.dtype), mode="drop")
+        return pages, _admit_logits(logits_all, slot_logits, slot)
+    page = pages.shape[3]
     shape = pages.shape
     if shape[4] % 8:
         # fewer KV heads than the 8 rows of a tile: scattered as it
@@ -1709,11 +2076,14 @@ def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
     pages = pages.at[:, :, dest_ids].set(
         src.astype(pages.dtype), mode="drop"
     ).reshape(shape)
-    logits_all = jax.tree_util.tree_map(
+    return pages, _admit_logits(logits_all, slot_logits, slot)
+
+
+def _admit_logits(logits_all, slot_logits, slot):
+    return jax.tree_util.tree_map(
         lambda rows, row: lax.dynamic_update_slice_in_dim(
             rows, row.astype(rows.dtype), slot, axis=0),
         logits_all, slot_logits)
-    return pages, logits_all
 
 
 def paged_admit_classes(pages, logits_all, slot_cache, slot_logits,
@@ -1743,8 +2113,8 @@ def paged_admit_classes(pages, logits_all, slot_cache, slot_logits,
 
 
 def paged_gather(pages, page_ids):
-    """One sequence's pages as a fresh single-row contiguous cache
-    [L, 2, 1, max_seq, Hkv, hd] — the park/extract shape (so paged
+    """One sequence's pages of a K/V class as a fresh single-row
+    contiguous cache [L, 2, 1, max_seq, Hkv, hd] — the park/extract shape (so paged
     park/resume interoperates with the single-stream path) and the
     prefix-restore source a shared-prefix admission prefills on top
     of.  Sentinel/unreserved ids gather as zeros."""
@@ -1802,9 +2172,10 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     """Compiled function bundle for the continuous-batching scheduler,
     over a block-paged KV pool.
 
-    The device cache is a page pool [n_layers, 2, kv_pages, page_size,
-    n_kv_heads, head_dim] (:func:`init_paged_kv_cache`) rather than
-    ``max_slots`` contiguous rows: a sequence occupies only the pages
+    The device cache is a page pool of ``kv_pages`` pages of
+    ``page_size`` tokens, a K/V or a latent class by what a token's row
+    is (:func:`init_paged_kv_cache`), rather than ``max_slots``
+    contiguous rows: a sequence occupies only the pages
     its length spans, page tables map logical to physical pages, and
     the scheduler's host-side allocator/radix tree
     (``tpuserver.paging``) decides who owns what.  ``kv_pages``
@@ -1866,6 +2237,15 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     prefixes and chunked prefill do not know blocks yet and the
     scheduler refuses them by name.
 
+    A configuration with latent attention (``cfg.mla``) gets a latent
+    page class under the same keys (``init_cache``, ``init_slot_cache``,
+    ``step``, ``admit``); ``latent_class`` ``{"width", "row"}`` says so
+    to the scheduler (absent otherwise).  ``gather`` / ``prefill_span``
+    are absent and ``span_safe`` is false: park, export and attach copy
+    K/V rows, and a suffix prefill against cached latents is not written
+    yet, so the scheduler refuses the former by name and prefills every
+    prompt whole.
+
     With a ``mesh`` the bundle is the GSPMD form: params
     Megatron-split, the page pool and slot cache kv-head-sharded over
     tp (``cache_spec`` — the page axes are unsharded, so the
@@ -1901,6 +2281,10 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     window_class = None
     if mesh is not None or quantized:
         _need_plain(cfg, "tensor-parallel or int8 serving")
+    if cfg.mla is not None and (cfg.window_layers or cfg.block_len):
+        raise UnsupportedArchitecture(
+            "the latent page class is one class of full causal attention "
+            "layers: no window layers and no generation over blocks")
     if cfg.block_len and (cfg.window_layers or page_size % cfg.block_len
                           or max_seq % cfg.block_len):
         raise UnsupportedArchitecture(
@@ -1965,6 +2349,11 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             def init_logits():  # noqa: F811
                 return init_block_state(cfg, max_slots)
 
+        if cfg.mla is not None:
+            # a latent class: what copies K/V rows out of the pool or
+            # prefills against cached rows is left out of the bundle
+            gather = prefill_span_fn = None
+
     else:
         param_sh, cache_sh, repl = serving_shardings(
             mesh, cfg, quantized=quantized
@@ -2025,11 +2414,13 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "pages_per_seq": pages_per_seq,
         "n_pages": n_pages,
         "span_safe": (cfg.attn_impl != "pallas" and window_class is None
-                      and not cfg.block_len),
+                      and not cfg.block_len and cfg.mla is None),
         "block_len": cfg.block_len,
         "mask_id": cfg.mask_id,
         "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
         "window_class": window_class,
+        "latent_class": (None if cfg.mla is None else
+                         {"width": cfg.mla.width, "row": cfg.mla.row}),
     }
     # what a two-class pool cannot serve is absent, not None
     return {k: v for k, v in fns.items()
@@ -2040,8 +2431,9 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
 
 
 def cache_spec(cfg):
-    """PartitionSpec of the KV cache [n_layers, 2, B, S, n_kv_heads, hd]:
-    kv heads sharded over tp — each tp shard owns its heads' cache rows,
+    """PartitionSpec of a K/V cache [n_layers, 2, B, S, n_kv_heads, hd]
+    (the plain block's: a latent cache has no head axis and is never
+    sharded here): kv heads sharded over tp — each tp shard owns its heads' cache rows,
     so cache reads/writes during decode are collective-free."""
     return P(None, None, None, None, "tp", None)
 

@@ -15,6 +15,9 @@ The decode kernels (``decode_attention`` over a padded cache,
 query a row with every head at once and share one body,
 :func:`_decode_fold`: two MXU dots a block over the block as it lies in
 the cache, grouped-query attention being a mask on the scores.
+``latent_decode_attention`` is their sibling over a latent page pool
+(multi-head latent attention, absorbed form): one shared key a position,
+brought in once, whose leading lanes are also the value.
 
 Kernel mode (Mosaic or the Pallas interpreter) is decided in one place,
 :func:`kernel_interpret`: a process states it with
@@ -96,8 +99,9 @@ def _attn_kernel(
     block_q, block_k, window=None, block_causal=None):
     """One (batch*head, q-block, k-block) program.
 
-    q_ref: [block_q, D]; k_ref/v_ref: [block_k, D]; o_ref: [block_q, D];
-    scratch m/l: [block_q, 1] fp32, acc: [block_q, D] fp32 — carried
+    q_ref: [block_q, D]; k_ref: [block_k, D]; v_ref: [block_k, Dv];
+    o_ref: [block_q, Dv]; scratch m/l: [block_q, 1] fp32, acc:
+    [block_q, Dv] fp32 — carried
     across the (sequential) k-block grid dimension.  With ``window``
     (causal only) query i sees keys j with 0 <= i - j < window, and K/V
     blocks wholly behind the window are skipped like those above the
@@ -168,7 +172,9 @@ def _attn_kernel(
 def flash_attention(
     q, k, v, causal=True, scale=None, block_q=128, block_k=128,
     interpret=None, window=None, block_causal=None):
-    """Exact attention, q/k/v [B, T, H, D] -> [B, T, H, D].
+    """Exact attention, q/k [B, T, H, D], v [B, T, H, Dv] -> [B, T, H, Dv]
+    (Dv = D everywhere but under latent attention's expanded form, whose
+    keys carry 192 lanes and whose values 128).
 
     Drop-in for the XLA attention paths; T must be divisible by
     ``block_q`` and ``block_k`` (pick smaller blocks for short or odd
@@ -185,7 +191,7 @@ def flash_attention(
         scale = q.shape[-1] ** -0.5
     interpret = kernel_interpret(interpret)
     b, t, h, d = q.shape
-    t_kv = k.shape[1]
+    t_kv, d_v = k.shape[1], v.shape[-1]
     block_q = min(block_q, t)
     block_k = min(block_k, t_kv)
     if t % block_q or t_kv % block_k:
@@ -196,7 +202,7 @@ def flash_attention(
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head)
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
-    vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
+    vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d_v)
 
     if (window is not None or block_causal is not None) and not causal:
         raise ValueError("a window or a block diagonal needs causal "
@@ -220,19 +226,19 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((None, block_k, d), kv_index),
-            pl.BlockSpec((None, block_k, d), kv_index),
+            pl.BlockSpec((None, block_k, d_v), kv_index),
         ],
         out_specs=pl.BlockSpec(
-            (None, block_q, d), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            (None, block_q, d_v), lambda bh, i, j: (bh, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(qh, kh, vh)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
 
 
 def _decode_fold(
@@ -252,6 +258,7 @@ def _decode_fold(
     window).  Their probabilities are exact zeros, so ``p . v`` is
     already the grouped [H, D]: GQA costs the MXU Hkv times the needed
     products and no repeat, relayout or per-head slice of the block.
+    (With ONE KV head, a latent pool's, there is no other group to mask.)
 
     ``n_q`` queries a row (a block of a diffusion step; all see the same
     ``length`` positions) are ``n_q * H`` query rows of the same two
@@ -263,16 +270,18 @@ def _decode_fold(
         q_ref[:].astype(k.dtype), k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale   # [H, bk * Hkv]
     col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
-    if n_q > 1:
-        head = jax.lax.rem(head, heads // n_q)
-    group = jax.lax.div(head, n_rep)
+    if h_kv > 1:
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0)
+        if n_q > 1:
+            head = jax.lax.rem(head, heads // n_q)
+        group = jax.lax.div(head, n_rep)
     # position c // Hkv < length  <=>  c < (length - first) * Hkv
     first = ki * block_k
     seen = col < (length - first) * h_kv
     if start is not None:
         seen = jnp.logical_and(seen, col >= (start - first) * h_kv)
-    seen = jnp.logical_and(seen, jax.lax.rem(col, h_kv) == group)
+    if h_kv > 1:
+        seen = jnp.logical_and(seen, jax.lax.rem(col, h_kv) == group)
     s = jnp.where(seen, s, -jnp.inf)
     _online_softmax_fold(
         s, m_scr, l_scr, acc_scr,
@@ -573,3 +582,146 @@ def paged_decode_attention(
         name="paged_decode_attention",
         interpret=interpret,
     )(*prefetch, q, pages)
+
+
+def _latent_decode_kernel(
+    len_ref, tbl_ref, layer_ref, q_ref, pages_ref, o_ref, buf, sems,
+    slot_ref, m_scr, l_scr, acc_scr, *, scale, block_k, d_v):
+    """One (row, block) program of decode attention over a LATENT page
+    pool (multi-head latent attention, absorbed form): every head's
+    query [H, W] against the row's cached latents, ONE shared key a
+    position, whose first ``d_v`` lanes are also its value.
+
+    :func:`_paged_decode_kernel`'s hand-over with one buffer where that
+    has two: pages_ref is the whole pool [L, n_pages, page, W], a block
+    [block_k, W] is brought into VMEM once, a DMA a page, and
+    :func:`_decode_fold` reads it as the key (all W lanes) and as the
+    value (a lane slice of the same block): ``H`` query rows against one
+    KV head."""
+    b = pl.program_id(0)
+    ki = pl.program_id(1)
+    rows = pl.num_programs(0)
+    nk = pl.num_programs(1)
+    page = pages_ref.shape[2]
+    pages_per_block = block_k // page
+    pages_per_seq = nk * pages_per_block
+    layer = layer_ref[0]
+    length = len_ref[b]
+    live_blocks = jnp.maximum(
+        jax.lax.div(length + (block_k - 1), block_k), 1)
+
+    def block_copies(row, blk, slot):
+        first = row * pages_per_seq + blk * pages_per_block
+        return [
+            pltpu.make_async_copy(
+                pages_ref.at[layer, tbl_ref[first + j]],
+                buf.at[slot, pl.ds(j * page, page)],
+                sems.at[slot])
+            for j in range(pages_per_block)
+        ]
+
+    @pl.when(ki == 0)
+    def _init():
+        _fold_init(m_scr, l_scr, acc_scr)
+
+    @pl.when(jnp.logical_and(b == 0, ki == 0))
+    def _first():
+        slot_ref[0] = 0
+        for copy in block_copies(0, 0, 0):
+            copy.start()
+
+    @pl.when(ki < live_blocks)
+    def _block():
+        slot = slot_ref[0]
+        more = ki + 1 < live_blocks
+        nxt_row = jnp.where(more, b, b + 1)
+        nxt_blk = jnp.where(more, ki + 1, 0)
+
+        @pl.when(nxt_row < rows)
+        def _prefetch():
+            for copy in block_copies(nxt_row, nxt_blk, 1 - slot):
+                copy.start()
+
+        for copy in block_copies(b, ki, slot):
+            copy.wait()
+        slot_ref[0] = 1 - slot
+
+        @pl.when(ki * block_k < length)
+        def _fold():
+            rows_kv = buf[slot]
+            _decode_fold(
+                q_ref, rows_kv, rows_kv[:, :d_v], ki, length, m_scr, l_scr,
+                acc_scr, scale=scale, block_k=block_k,
+                n_rep=q_ref.shape[0])
+
+    @pl.when(ki == nk - 1)
+    def _finish():
+        _fold_finish(o_ref, m_scr, l_scr, acc_scr)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("d_v", "scale", "block_k", "interpret"))
+def latent_decode_attention(
+    q, pages, layer, page_tables, lengths, d_v, scale, block_k=256,
+    interpret=None):
+    """Decode attention in the latent space over a latent page pool,
+    read in place: the absorbed form of multi-head latent attention.
+
+    q: [B, H, W], every head's query already carried into the latent
+    space (``[W_UK q_nope ; q_pe]``, zero in the row's padding lanes);
+    pages: the whole pool [L, n_pages, page, W]
+    (``models.llama.init_paged_kv_cache`` of a latent configuration),
+    a row ``[c_kv ; k_pe ; padding]`` a cached token, of which layer
+    ``layer`` is attended; page_tables [B, pages_per_seq] int32 with
+    every entry in [0, n_pages) and lengths [B] int32 as
+    :func:`paged_decode_attention` takes them.  A row's live blocks come
+    into VMEM once, page by page, and serve as key (all W lanes, scores
+    times ``scale``) and as value (the first ``d_v`` lanes).  Returns
+    ``sum_j softmax_j(s) c_kv(j)`` [B, H, d_v]: the caller carries it
+    back through the value up-projection."""
+    interpret = kernel_interpret(interpret)
+    b, h, w = q.shape
+    if pages.ndim != 4 or pages.shape[3] != w or d_v > w:
+        raise ValueError(
+            "a latent pool is [L, n_pages, page, {}] (got {}) and holds "
+            "its value in the first d_v ({}) lanes".format(
+                w, pages.shape, d_v))
+    page = pages.shape[2]
+    s = page_tables.shape[1] * page
+    block_k = min(block_k, s)
+    if s % block_k or block_k % page:
+        raise ValueError(
+            "block_k {} must divide the row length {} and hold whole "
+            "pages of {}".format(block_k, s, page))
+    kernel = functools.partial(
+        _latent_decode_kernel, scale=scale, block_k=block_k, d_v=d_v)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, s // block_k),
+        in_specs=[
+            pl.BlockSpec((None, h, w), lambda b, ki, *refs: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, h, d_v), lambda b, ki, *refs: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_k, w), pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d_v), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d_v), q.dtype),
+        # the slot hand-over needs every program to run in grid order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="latent_decode_attention",
+        interpret=interpret,
+    )(lengths.astype(jnp.int32),
+      page_tables.astype(jnp.int32).reshape(-1),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pages)

@@ -6,8 +6,10 @@ whole weight set from HBM to produce ONE token, so served throughput
 equals single-stream throughput while every concurrent gRPC stream
 queues on the model's lock.  This module is the missing subsystem: a
 per-model background decode loop that owns a block-paged KV pool
-(``[n_layers, 2, kv_pages, page_size, n_kv_heads, head_dim]``, kv-head
-sharded over the tp mesh when present) and runs **one batched decode
+(a K/V class ``[n_layers, 2, kv_pages, page_size, n_kv_heads,
+head_dim]``, kv-head sharded over the tp mesh when present, or under
+latent attention a latent class ``[n_layers, kv_pages, page_size,
+row]``) and runs **one batched decode
 step for all active slots per iteration**, so the weight stream is paid
 once per step and amortized over every in-flight generation.  Each
 generation's KV lives in fixed-size pages named by a per-slot page
@@ -510,6 +512,7 @@ class DecodeScheduler:
         # did not have to read, and the routed layers' counts fetched
         # with each step's tokens
         self._context_tokens = 0
+        self._context_bytes = 0
         self._window_skipped_tokens = 0
         self._moe_layer_steps = 0
         self._moe_local_pairs = 0
@@ -532,6 +535,10 @@ class DecodeScheduler:
         # llama.make_scheduler_fns "window_class"): what still assumes
         # one table a sequence is refused by name in submit
         self._window_class = (fns or {}).get("window_class")
+        # a latent page class (latent attention:
+        # llama.make_scheduler_fns "latent_class"): what copies K/V rows
+        # out of the pool is refused by name in submit
+        self._latent_class = (fns or {}).get("latent_class")
         # park-attach KV export hooks (tentpole 3 of ISSUE 12): a
         # disconnected resumable stream's gathered pages are handed to
         # ``kv_export(generation_id, cache, valid_pos)`` (the server
@@ -584,6 +591,11 @@ class DecodeScheduler:
                 "{} is not served for a configuration that generates by "
                 "diffusion over blocks: it assumes one token a row a "
                 "step".format(what))
+        if self._latent_class:
+            return UnsupportedArchitecture(
+                "{} is not served over a latent page class (latent "
+                "attention): it copies K and V rows, and a latent row is "
+                "neither".format(what))
         return UnsupportedArchitecture(
             "{} is not served over a pool of two page classes (window "
             "layers): it assumes one page table a sequence".format(what))
@@ -650,7 +662,7 @@ class DecodeScheduler:
                 "denoising_steps must lie in 1..{} (got {}) and "
                 "confidence_threshold in 0..1 (got {})".format(
                     blk, steps, tau))
-        if self._window_class or blk:
+        if self._window_class or blk or self._latent_class:
             for asked, what in (
                     (resume_cache is not None or on_finish is not None,
                      "park / resume of a KV cache (kv_cache_region)"),
@@ -1011,6 +1023,7 @@ class DecodeScheduler:
                 "window_pages_total": window_total,
                 "window_pages_free": window_free,
                 "context_tokens": self._context_tokens,
+                "context_bytes": self._context_bytes,
                 "window_skipped_tokens": self._window_skipped_tokens,
                 "moe_layer_steps": self._moe_layer_steps,
                 "moe_local_pairs": self._moe_local_pairs,
@@ -1343,6 +1356,14 @@ class DecodeScheduler:
         else:
             alloc_w = None
             n_layers_all = int(getattr(pages, "shape", (0,))[0])
+        # bytes one cached token holds over all attention layers, each
+        # layer's in its page class as the pool's array stores it
+        # (padding included; 0 for a test double's pool)
+        token_bytes = sum(
+            int(getattr(pool, "nbytes", 0)) // (count * page)
+            for pool, count in (
+                ((pages["full"], n_pages), (pages["window"], n_wpages))
+                if wc else ((pages, n_pages),)))
         with self._cond:
             # stats/gauges read the live pool through this reference;
             # a supervised restart rebuilds pool, allocator and radix
@@ -1851,6 +1872,8 @@ class DecodeScheduler:
             # its own block's end
             self._context_tokens += (
                 start + blk + fused * start) * n_layers_all
+            self._context_bytes += (
+                start + blk + fused * start) * token_bytes
             if fused:
                 self._diffusion_fused_commits += 1
             elif commit:
@@ -2060,6 +2083,7 @@ class DecodeScheduler:
                     # key positions this step's attention layers cover,
                     # and those its window layers need not read
                     self._context_tokens += context * n_layers_all
+                    self._context_bytes += context * token_bytes
                     if wc:
                         self._window_skipped_tokens += skipped * n_layers_w
                     # chaos hook: "scheduler.step" raise = loop death (the
