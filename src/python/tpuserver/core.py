@@ -131,6 +131,10 @@ class InferResponse:
         #          delivery dict) — array None when delivered via shm
         self.outputs = outputs
         self.parameters = parameters or {}
+        # time.monotonic() at which the decode loop queued the token
+        # this response carries (None where no loop did): the
+        # frontend's count of the wait from there to the wire
+        self.emitted_at = None
 
 
 #: Reserved key a decoupled model may include in a yielded output dict
@@ -138,6 +142,11 @@ class InferResponse:
 #: sequence number resumable streams carry on the wire); popped before
 #: the dict is interpreted as output tensors.
 RESPONSE_PARAMS_KEY = "__response_parameters__"
+
+#: Reserved key of the same kind: the ``time.monotonic()`` at which a
+#: decode loop queued the response's token (``scheduler.Emitted``),
+#: popped into ``InferResponse.emitted_at``; never sent.
+EMITTED_AT_KEY = "__emitted_at__"
 
 
 def _instance_kind(model):
@@ -857,6 +866,14 @@ class InferenceServer:
             "tpu_shm_bytes_read_total").labels()
         self._m_shm_written = self.metrics.counter(
             "tpu_shm_bytes_written_total").labels()
+        # a streamed token's wait from the decode loop's queue to the
+        # transport (count_token_handoff): model -> (seconds, count)
+        # children, bound on a model's first stamped response
+        self._m_handoff_seconds = self.metrics.counter(
+            "tpu_frontend_token_handoff_seconds_total", labelnames=("model",))
+        self._m_handoffs = self.metrics.counter(
+            "tpu_frontend_token_handoffs_total", labelnames=("model",))
+        self._handoff_children = {}
         self.metrics.register_collector(self._collect_metrics)
         self.metrics.register_collector(self._collect_shm_ring)
         for m in models or []:
@@ -1031,6 +1048,27 @@ class InferenceServer:
             self._metric_error_children[key] = child
         child.inc()
 
+    def count_token_handoff(self, resp):
+        """A frontend hands ``resp`` to its transport: add its wait
+        since the decode loop queued its token to
+        ``tpu_frontend_token_handoff_seconds_total`` and one to
+        ``tpu_frontend_token_handoffs_total``.  A response no loop
+        stamped (an error, a replayed token, a model without a
+        scheduler) is not counted."""
+        emitted_at = resp.emitted_at
+        if emitted_at is None:
+            return
+        waited = time.monotonic() - emitted_at
+        children = self._handoff_children.get(resp.model_name)
+        if children is None:
+            # labels() hands every caller the same children: a race
+            # here binds them twice, harmlessly
+            children = self._handoff_children[resp.model_name] = (
+                self._m_handoff_seconds.labels(model=resp.model_name),
+                self._m_handoffs.labels(model=resp.model_name))
+        children[0].inc(waited)
+        children[1].inc()
+
     def _collect_metrics(self):
         """Scrape-time collector: the in-flight gauge plus every
         scheduler-backed model's counters, read straight from
@@ -1093,9 +1131,12 @@ class InferenceServer:
                 "diffusion_blocks_committed",
         }
         samples = {name: [] for name in per_family}
-        # the decode loop's seconds by phase: the one family with a
-        # second label, float like every *_seconds
-        loop_seconds = samples["tpu_scheduler_loop_seconds_total"] = []
+        # the decode loop's seconds by phase, wall and off the CPU: the
+        # families with a second label, float like every *_seconds
+        by_phase = {"tpu_scheduler_loop_seconds_total": "loop_seconds",
+                    "tpu_scheduler_loop_offcpu_seconds_total":
+                        "loop_offcpu_seconds"}
+        samples.update((name, []) for name in by_phase)
         for model_name, model in items:
             stats_fn = getattr(model, "scheduler_stats", None)
             stats = stats_fn() if callable(stats_fn) else None
@@ -1104,10 +1145,10 @@ class InferenceServer:
             for fam_name, key in per_family.items():
                 samples[fam_name].append(
                     ({"model": model_name}, int(stats.get(key) or 0)))
-            loop_seconds.extend(
-                ({"model": model_name, "phase": phase}, float(seconds))
-                for phase, seconds in (stats.get("loop_seconds")
-                                       or {}).items())
+            for fam_name, key in by_phase.items():
+                samples[fam_name].extend(
+                    ({"model": model_name, "phase": phase}, float(seconds))
+                    for phase, seconds in (stats.get(key) or {}).items())
         families.extend(
             (name, rows) for name, rows in samples.items() if rows)
         return families
@@ -2008,12 +2049,14 @@ class InferenceServer:
                 # client has stopped waiting
                 self._check_deadline(request.deadline)
                 count += 1
-                extra_params = None
-                if RESPONSE_PARAMS_KEY in out:
+                extra_params = emitted_at = None
+                if RESPONSE_PARAMS_KEY in out or EMITTED_AT_KEY in out:
                     out = dict(out)
-                    extra_params = out.pop(RESPONSE_PARAMS_KEY)
+                    extra_params = out.pop(RESPONSE_PARAMS_KEY, None)
+                    emitted_at = out.pop(EMITTED_AT_KEY, None)
                 resp = self._make_response(model, request, out,
                                            mark_final=False)
+                resp.emitted_at = emitted_at
                 if extra_params:
                     resp.parameters.update(extra_params)
                 if want_final:

@@ -412,12 +412,14 @@ class _CoreBridge:
         lock = _threading.Lock()
         _SENTINEL = object()
 
-        def emit(item):
+        def emit(item, resp=None):
             """put with cancellation: a gone client must not wedge
-            producer threads on a full queue."""
+            producer threads on a full queue.  ``resp`` is the core
+            response ``item`` was built from, whose stamp the handler
+            counts when it hands ``item`` on."""
             while not cancelled.is_set():
                 try:
-                    out.put(item, timeout=0.25)
+                    out.put((item, resp), timeout=0.25)
                     return True
                 except _queue.Full:
                     continue
@@ -436,7 +438,8 @@ class _CoreBridge:
                         break  # stop generating for a gone client
                     with span("frontend.emit"):
                         sent = emit(pb.ModelStreamInferResponse(
-                            infer_response=self._response_to_proto(resp)))
+                            infer_response=self._response_to_proto(resp)),
+                            resp)
                     if not sent:
                         break
             except ServerError as e:
@@ -501,7 +504,7 @@ class _CoreBridge:
             from tpuserver import faults as _faults
 
             while True:
-                item = out.get()
+                item, resp = out.get()
                 if item is _SENTINEL:
                     return
                 # chaos hook: kill the bidi stream mid-flight (the
@@ -509,6 +512,8 @@ class _CoreBridge:
                 # level error) so client reconnect+resume is drivable
                 # end-to-end; skip=N drops after the Nth response
                 _faults.fire("grpc.stream_infer", self._core.fault_scope)
+                if resp is not None:
+                    self._core.count_token_handoff(resp)
                 yield item
         finally:
             # reader gone (cancel/deadline/exit): release producers and

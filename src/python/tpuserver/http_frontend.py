@@ -405,6 +405,7 @@ class _Handler(BaseHttpHandler):
                         + json.dumps(payload).encode("utf-8")
                         + b"\n\n"
                     )
+                    core.count_token_handoff(resp)
         except _faults.FaultInjected:
             try:
                 self.connection.close()

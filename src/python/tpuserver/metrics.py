@@ -146,6 +146,13 @@ CATALOG = {
         "phases tile the thread's time: they sum to its wall time, "
         "fetch is the host waiting for the device, and all but idle "
         "and fetch are host work."),
+    "tpu_scheduler_loop_offcpu_seconds_total": (
+        "counter",
+        "Seconds of each phase the decode loop thread spent off the "
+        "CPU: waiting for the GIL, a lock, a transfer or the device; "
+        "wall seconds of the phase less the thread's CPU seconds, per "
+        "model and phase (the phases of "
+        "tpu_scheduler_loop_seconds_total, never more than its value)."),
     "tpu_scheduler_codel_sheds_total": (
         "counter",
         "Admissions shed by the adaptive (CoDel-style) queue "
@@ -157,6 +164,18 @@ CATALOG = {
         "Whether the adaptive queue-shed controller is actively "
         "shedding (1) or the admission queue's sojourn is under "
         "target (0), per model."),
+    # -- frontends: a streamed token's way from the loop to the wire -------
+    "tpu_frontend_token_handoff_seconds_total": (
+        "counter",
+        "Seconds streamed tokens waited from the decode loop's put on "
+        "their stream's queue to their hand-over to the transport (the "
+        "gRPC handler's yield, the SSE write), summed per model.  Over "
+        "tpu_frontend_token_handoffs_total, a token's mean wait."),
+    "tpu_frontend_token_handoffs_total": (
+        "counter",
+        "Streamed responses handed to the transport that carried a "
+        "token the decode loop stamped (a block model: a finished "
+        "block), per model; errors and replayed tokens do not count."),
     # -- paged KV + radix prefix cache -------------------------------------
     "tpu_prefix_cache_hits_total": (
         "counter",

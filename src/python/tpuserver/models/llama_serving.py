@@ -656,7 +656,7 @@ class LlamaGenerateModel(Model):
 
         import jax.numpy as jnp
 
-        from tpuserver.core import RESPONSE_PARAMS_KEY
+        from tpuserver.core import EMITTED_AT_KEY, RESPONSE_PARAMS_KEY
         from tpuserver.scheduler import SchedulerClosed
 
         scheduler = self._scheduler
@@ -724,11 +724,12 @@ class LlamaGenerateModel(Model):
                 attach_pos=attach_pos,
             )
             seq = 0
-        for token, logprob in stream:
+        for item in stream:
+            token, logprob = item
             if self._cfg.block_len:
                 # a finished block; seq counts responses
                 toks, lps, at, passes = token
-                yield {
+                event = {
                     "TOKEN": np.array(toks, dtype=np.int32),
                     "LOGPROB": np.array(lps, dtype=np.float32),
                     "POSITION": np.array(at, dtype=np.int32),
@@ -753,15 +754,18 @@ class LlamaGenerateModel(Model):
                     event["TOKEN"] = np.array([token], dtype=np.int32)
                     event["LOGPROB"] = np.array(
                         [logprob], dtype=np.float32)
-                yield event
             else:
-                yield {
+                event = {
                     "TOKEN": np.array([token], dtype=np.int32),
                     "LOGPROB": np.array([logprob], dtype=np.float32),
                     RESPONSE_PARAMS_KEY: {
                         "generation_id": gen_id, "seq": seq,
                     },
                 }
+            # when the decode loop queued a live token (a replayed one
+            # carries no stamp): the frontend counts the wait from there
+            event[EMITTED_AT_KEY] = getattr(item, "emitted_at", None)
+            yield event
             seq += 1
 
     def _attach_from_params(self, request):

@@ -217,6 +217,19 @@ class _CodelShedController:
         return max(1, int(math.ceil(interval)))
 
 
+class Emitted(tuple):
+    """A live ``(token, logprob)`` pair off a stream's queue that also
+    carries ``emitted_at``: the ``time.monotonic()`` at which the decode
+    loop put it there.  The frontend counts a token's wait from there
+    to the wire by it (``tpu_frontend_token_handoff_seconds_total``);
+    pairs replayed from a generation's history are plain tuples."""
+
+    def __new__(cls, pair, emitted_at):
+        self = super().__new__(cls, pair)
+        self.emitted_at = emitted_at
+        return self
+
+
 class _Stream:
     """One in-flight generation bound to a cache slot."""
 
@@ -351,19 +364,42 @@ class _LoopClock:
     floats sum to the thread's wall time, and the host's milliseconds
     per step are a ratio of counters.  ONE loop thread owns a clock
     and its floats: plain adds, no lock, like the loop's histograms.
+
+    Each charge also reads the thread's own CPU clock
+    (``time.thread_time()``, ``CLOCK_THREAD_CPUTIME_ID``) and adds the
+    part of the wall time the thread did NOT run to the phase's
+    ``offcpu`` float: waiting for the GIL, a lock, a transfer or the
+    device.  Where a host's CPU clock ticks coarsely it can charge a
+    phase more CPU than wall time; that excess is kept and taken off
+    the phase's next charges, so the float only grows, its total is
+    the phase's wall less its CPU seconds, and it never exceeds the
+    phase's wall seconds.  The clock is built in the loop thread,
+    whose CPU it reads.
     """
 
-    __slots__ = ("_seconds", "_mark", "_open")
+    __slots__ = ("_seconds", "_offcpu", "_cpu_over", "_mark", "_cpu_mark",
+                 "_open")
 
-    def __init__(self, seconds):
+    def __init__(self, seconds, offcpu):
         self._seconds = seconds  # phase -> float, this thread's to add to
+        self._offcpu = offcpu    # phase -> float, likewise
+        # phase -> CPU seconds charged beyond the wall, still to take off
+        self._cpu_over = dict.fromkeys(offcpu, 0.0)
         self._mark = time.monotonic()
+        self._cpu_mark = time.thread_time()
         self._open = []          # enclosing phases, innermost last
 
     def _charge(self, phase):
-        now = time.monotonic()
-        self._seconds[phase] += now - self._mark
-        self._mark = now
+        now, cpu = time.monotonic(), time.thread_time()
+        wall = now - self._mark
+        self._seconds[phase] += wall
+        off = wall - (cpu - self._cpu_mark) - self._cpu_over[phase]
+        if off >= 0.0:
+            self._offcpu[phase] += off
+            self._cpu_over[phase] = 0.0
+        else:
+            self._cpu_over[phase] = -off
+        self._mark, self._cpu_mark = now, cpu
 
     @contextlib.contextmanager
     def __call__(self, phase):
@@ -579,9 +615,13 @@ class DecodeScheduler:
             self._admit_hist = loop_histogram("tpu_scheduler_admit_seconds")
             self._first_token_hist = loop_histogram(
                 "tpu_scheduler_first_token_seconds")
-        # seconds of the decode loop thread's life by phase (_LoopClock;
-        # stats()["loop_seconds"], tpu_scheduler_loop_seconds_total)
+        # seconds of the decode loop thread's life by phase, and of
+        # them those it spent off the CPU (_LoopClock;
+        # stats()["loop_seconds"] / ["loop_offcpu_seconds"],
+        # tpu_scheduler_loop_seconds_total /
+        # tpu_scheduler_loop_offcpu_seconds_total)
         self._loop_seconds = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._loop_offcpu_seconds = dict.fromkeys(LOOP_PHASES, 0.0)
 
     def _unsupported(self, what):
         from tpuserver.models.llama import UnsupportedArchitecture
@@ -610,7 +650,8 @@ class DecodeScheduler:
                confidence_threshold=None):
         """Enqueue one generation; returns an iterator of
         ``(token, logprob)`` pairs that blocks as the decode loop
-        produces them.
+        produces them (each an :class:`Emitted`, stamped when the loop
+        queued it).
 
         A configuration that generates by diffusion over blocks yields
         ``(block, None)`` pairs instead, one a finished block: ``block``
@@ -886,7 +927,7 @@ class DecodeScheduler:
             while True:
                 kind, a, b = stream.queue.get()
                 if kind == "tok":
-                    yield a, b
+                    yield Emitted(a, b)
                 elif kind == "err":
                     stream.finished = True
                     raise a
@@ -1039,6 +1080,7 @@ class DecodeScheduler:
                     self._diffusion_commit_passes
                     + self._diffusion_fused_commits,
                 "loop_seconds": dict(self._loop_seconds),
+                "loop_offcpu_seconds": dict(self._loop_offcpu_seconds),
                 # a fact of the build, not a rate: which decode
                 # attention the step executable holds
                 "decode_attention": fns.get("decode_attention"),
@@ -1323,7 +1365,9 @@ class DecodeScheduler:
             # demoted zombie that wakes keeps adding to its orphaned
             # copy, never under its successor's feet
             self._loop_seconds = seconds = dict(self._loop_seconds)
-        phase = _LoopClock(seconds)
+            self._loop_offcpu_seconds = offcpu = dict(
+                self._loop_offcpu_seconds)
+        phase = _LoopClock(seconds, offcpu)
         fns = self._fns
         # block length of a configuration that generates by diffusion
         # over blocks (a step then carries a block a row), else 0
@@ -1836,11 +1880,12 @@ class DecodeScheduler:
 
         def emit(st, tok, lp):
             """One token (of a block configuration: one finished
-            block) onto its stream's queue (``_cond`` held).  A
-            stream's first token on its first admission is the
-            server-side time to first token; resumes and re-admissions
-            after a restart (``incarnation`` > 1) restarted the stamp
-            and do not observe."""
+            block) onto its stream's queue (``_cond`` held), with the
+            ``time.monotonic()`` it went there.  A stream's first token
+            on its first admission is the server-side time to first
+            token; resumes and re-admissions after a restart
+            (``incarnation`` > 1) restarted the stamp and do not
+            observe."""
             if (st.emitted == 0 and st.incarnation == 1
                     and self._first_token_hist is not None):
                 self._first_token_hist.observe(
@@ -1856,7 +1901,7 @@ class DecodeScheduler:
                 st.history.append((tok, lp))
                 st.emitted += 1
                 self._tokens_total += 1
-            st.queue.put(("tok", tok, lp))
+            st.queue.put(("tok", (tok, lp), time.monotonic()))
 
         def block_pass(st, row, logcs):
             """One fetched pass of a live row's block (``_cond`` held):

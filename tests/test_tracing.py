@@ -94,6 +94,95 @@ def test_loop_phases_tile_the_loop_threads_time(fns, params):
     assert all(s > 0 for s in seconds.values()), seconds
 
 
+def test_offcpu_seconds_lie_within_each_phases_wall(fns, params):
+    """Of every phase's wall seconds, the off-CPU part is >= 0 and never
+    more than the whole; the loop's sleeps (``idle``) are off the CPU."""
+    sched = DecodeScheduler(fns, params, 2, MAX_SEQ)
+    try:
+        for i in range(4):
+            assert len(_collect(sched, _prompt(i), 8)) == 8
+            time.sleep(0.02)
+    finally:
+        sched.close()
+    stats = sched.stats()
+    wall, offcpu = stats["loop_seconds"], stats["loop_offcpu_seconds"]
+    assert tuple(offcpu) == LOOP_PHASES
+    for phase in LOOP_PHASES:
+        assert 0.0 <= offcpu[phase] <= wall[phase], (phase, offcpu, wall)
+    assert offcpu["idle"] > 0.5 * wall["idle"] > 0
+
+
+def _dispatch_seconds(fns, params, fault_delay=None):
+    """(steps, dispatch wall, dispatch off-CPU) of one 12-token
+    generation, with every step slowed by ``fault_delay`` seconds."""
+    from tpuserver import faults
+
+    registry = MetricsRegistry()
+    sched = DecodeScheduler(fns, params, 2, MAX_SEQ, metrics=registry,
+                            metric_labels=LABELS, fault_scope="offcpu")
+    if fault_delay is not None:
+        faults.install("scheduler.step", mode="slow", delay=fault_delay,
+                       scope="offcpu")
+    try:
+        assert len(_collect(sched, _prompt(3), 12)) == 12
+    finally:
+        faults.clear()
+        sched.close()
+    stats = sched.stats()
+    return (_count(registry, "tpu_scheduler_step_seconds"),
+            stats["loop_seconds"]["dispatch"],
+            stats["loop_offcpu_seconds"]["dispatch"])
+
+
+def test_a_slow_step_is_dispatch_time_off_the_cpu(fns, params):
+    """``scheduler.step`` in mode ``slow`` sleeps inside ``dispatch`` on
+    every step: the phase's off-CPU seconds rise by about the sleep
+    times the steps, its CPU seconds (wall less off-CPU) do not."""
+    delay = 0.05
+    _dispatch_seconds(fns, params)   # compiles the step: no side pays it
+    steps0, wall0, off0 = _dispatch_seconds(fns, params)
+    steps, wall, off = _dispatch_seconds(fns, params, delay)
+    assert steps == steps0 >= 12
+    slept = steps * delay
+    assert 0.95 * slept <= off - off0 <= 1.5 * slept, (off, off0, slept)
+    assert (wall - off) - (wall0 - off0) < 0.25 * slept, (
+        wall, off, wall0, off0)
+
+
+def test_a_coarse_cpu_clock_still_gives_wall_less_cpu(monkeypatch):
+    """A CPU clock that moves in 10 ms ticks (as on a host whose kernel
+    samples thread CPU time) charges a 1 ms phase a whole tick now and
+    then: the excess is carried to the phase's next charges, so its
+    off-CPU float never falls, stays within its wall time, and ends at
+    wall less CPU (a clamp at each charge would read 95 ms here)."""
+    from tpuserver import scheduler
+
+    class Clocks:
+        wall = cpu = 0.0
+
+        def monotonic(self):
+            return self.wall
+
+        def thread_time(self):
+            return self.cpu
+
+    clocks = Clocks()
+    monkeypatch.setattr(scheduler, "time", clocks)
+    wall = dict.fromkeys(LOOP_PHASES, 0.0)
+    offcpu = dict.fromkeys(LOOP_PHASES, 0.0)
+    clock = scheduler._LoopClock(wall, offcpu)
+    seen = []
+    for i in range(100):
+        with clock("dispatch"):
+            clocks.wall += 0.001
+            if i % 20 == 0:
+                clocks.cpu += 0.010
+        seen.append(offcpu["dispatch"])
+        assert 0.0 <= offcpu["dispatch"] <= wall["dispatch"]
+    assert seen == sorted(seen)
+    assert offcpu["dispatch"] == pytest.approx(0.100 - 0.050)
+
+
 def test_first_token_observes_fresh_streams_only(fns, params):
     """One first-token observation per fresh generation; a resume (a new
     admission of the same stream object) does not observe again."""
@@ -272,3 +361,124 @@ def test_stats_name_the_fallback_decode_attention(fns, params):
         assert sched.stats()["decode_attention"] == "gather_dense"
     finally:
         sched.close()
+
+
+# -- a streamed token's wait from the loop to the wire ----------------------
+
+HANDOFF_FAMILIES = ("tpu_frontend_token_handoff_seconds_total",
+                    "tpu_frontend_token_handoffs_total")
+
+
+@pytest.fixture(scope="module")
+def served_llama():
+    """A generation model behind both frontends of one core."""
+    from tpuserver.core import InferenceServer
+    from tpuserver.grpc_frontend import GrpcFrontend
+    from tpuserver.http_frontend import HttpFrontend
+    from tpuserver.models.llama_serving import LlamaGenerateModel
+
+    core = InferenceServer([LlamaGenerateModel(
+        cfg=llama.tiny(vocab=512), max_seq=MAX_SEQ, max_slots=2)])
+    frontends = {"grpc": GrpcFrontend(core, port=0).start(),
+                 "http": HttpFrontend(core, port=0).start()}
+    try:
+        yield core, {kind: "127.0.0.1:{}".format(fe.port)
+                     for kind, fe in frontends.items()}
+    finally:
+        for fe in frontends.values():
+            fe.stop()
+        core.close()
+
+
+def _handoffs(core):
+    """(seconds, count) of the model's handed-off tokens so far."""
+    families = parse_prometheus_text(core.metrics_text())
+    return tuple(
+        next((value for _, labels, value in families[name]["samples"]
+              if labels == {"model": "llama_generate"}), 0.0)
+        for name in HANDOFF_FAMILIES)
+
+
+def _stream_tokens(kind, url, n):
+    """The tokens of one ``n``-token generation streamed over ``kind``
+    (gRPC ``ModelStreamInfer`` or HTTP ``/generate_stream``)."""
+    prompt = _prompt(5)
+    if kind == "grpc":
+        import tritonclient.grpc as grpcclient
+
+        p_in = grpcclient.InferInput("PROMPT_IDS", [len(prompt)], "INT32")
+        p_in.set_data_from_numpy(prompt)
+        m_in = grpcclient.InferInput("MAX_TOKENS", [1], "INT32")
+        m_in.set_data_from_numpy(np.array([n], np.int32))
+        client = grpcclient.InferenceServerClient(url)
+        try:
+            return [int(r.as_numpy("TOKEN")[0]) for r in
+                    client.generate_stream("llama_generate", [p_in, m_in])]
+        finally:
+            client.close()
+    import tritonclient.http as httpclient
+
+    client = httpclient.InferenceServerClient(url)
+    try:
+        return [int(out["data"][0])
+                for event in client.generate_stream(
+                    "llama_generate",
+                    {"PROMPT_IDS": prompt,
+                     "MAX_TOKENS": np.array([n], np.int32)})
+                for out in event.get("outputs", [])
+                if out["name"] == "TOKEN"]
+    finally:
+        client.close()
+
+
+@pytest.mark.parametrize("kind", ["grpc", "http"])
+def test_every_streamed_token_counts_one_handoff(served_llama, kind):
+    """One hand-off a token streamed, over ``ModelStreamInfer`` and over
+    ``/generate_stream``; their summed wait is > 0 and below the
+    stream's own wall time."""
+    core, urls = served_llama
+    _stream_tokens(kind, urls[kind], 2)     # compiles: not measured
+    seconds0, count0 = _handoffs(core)
+    began = time.monotonic()
+    tokens = _stream_tokens(kind, urls[kind], 10)
+    wall = time.monotonic() - began
+    seconds, count = _handoffs(core)
+    assert len(tokens) == 10
+    assert count - count0 == len(tokens)
+    assert 0.0 < seconds - seconds0 < wall
+
+
+def test_handoff_is_not_counted_off_the_wire(served_llama):
+    """A stream read inside the process never reaches a transport: the
+    core stamps its responses and counts none of them."""
+    from tpuserver.core import InferRequest
+
+    core, _ = served_llama
+    before = _handoffs(core)
+    responses = list(core.infer_stream(InferRequest(
+        "llama_generate", inputs={
+            "PROMPT_IDS": _prompt(6),
+            "MAX_TOKENS": np.array([4], np.int32)})))
+    assert len(responses) == 4
+    assert all(r.emitted_at is not None for r in responses)
+    assert _handoffs(core) == before
+
+
+def test_new_families_render_under_their_catalog_names(served_llama):
+    """The off-CPU family (a sample a phase) and the two hand-off
+    families render as the counters ``CATALOG`` declares."""
+    from tpuserver.metrics import CATALOG
+
+    core, urls = served_llama
+    _stream_tokens("grpc", urls["grpc"], 3)
+    families = parse_prometheus_text(core.metrics_text())
+    offcpu = "tpu_scheduler_loop_offcpu_seconds_total"
+    for name in (offcpu,) + HANDOFF_FAMILIES:
+        assert CATALOG[name][0] == "counter"
+        assert families[name]["type"] == "counter"
+        assert families[name]["help"] == CATALOG[name][1]
+    assert {labels["phase"] for _, labels, _ in families[offcpu]["samples"]
+            if labels["model"] == "llama_generate"} == set(LOOP_PHASES)
+    for name in HANDOFF_FAMILIES:
+        assert [labels for _, labels, _ in families[name]["samples"]] == [
+            {"model": "llama_generate"}]
