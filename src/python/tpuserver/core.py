@@ -105,6 +105,10 @@ class InferRequest:
         # deadline) and/or resolved from the 'timeout' parameter in
         # InferenceServer._resolve_deadline
         self.deadline = None
+        # the client reads responses that carry several tokens of one
+        # generation (MULTI_TOKEN_PARAM, taken off the wire by the gRPC
+        # frontend): a streaming model may then send what waits together
+        self.multi_token = False
 
     @property
     def sequence_id(self):
@@ -131,10 +135,16 @@ class InferResponse:
         #          delivery dict) — array None when delivered via shm
         self.outputs = outputs
         self.parameters = parameters or {}
-        # time.monotonic() at which the decode loop queued the token
-        # this response carries (None where no loop did): the
+        # time.monotonic() at which the decode loop queued each token
+        # (of a block model: each block) this response carries, a list,
+        # None for a replayed one; None where no loop did: the
         # frontend's count of the wait from there to the wire
         self.emitted_at = None
+        # the frontend may send this response together with the next
+        # mergeable one of the same request that waits behind it
+        # (merge_responses): each output's first axis is a token, and
+        # the parameters are those of the first token
+        self.mergeable = False
 
 
 #: Reserved key a decoupled model may include in a yielded output dict
@@ -143,10 +153,46 @@ class InferResponse:
 #: the dict is interpreted as output tensors.
 RESPONSE_PARAMS_KEY = "__response_parameters__"
 
-#: Reserved key of the same kind: the ``time.monotonic()`` at which a
-#: decode loop queued the response's token (``scheduler.Emitted``),
+#: Reserved key of the same kind: the ``time.monotonic()`` stamps at
+#: which a decode loop queued the response's tokens (``scheduler.Emitted``),
 #: popped into ``InferResponse.emitted_at``; never sent.
 EMITTED_AT_KEY = "__emitted_at__"
+
+#: Reserved key of the same kind: True makes the response
+#: ``InferResponse.mergeable``; never sent.
+MERGEABLE_KEY = "__mergeable__"
+
+#: Request parameter by which a streaming client declares that it reads
+#: responses carrying several tokens of one generation (``TOKEN`` /
+#: ``LOGPROB`` of shape ``[k]``, ``seq`` the first token's).
+#: ``tritonclient.grpc``'s ``generate_stream`` sends it; the gRPC
+#: frontend takes it off the request into ``InferRequest.multi_token``.
+MULTI_TOKEN_PARAM = "multi_token_responses"
+
+#: Response parameter of such a response that carries more than one
+#: token: how many.
+TOKEN_COUNT_PARAM = "token_count"
+
+
+def merge_responses(responses):
+    """One response carrying, in order, what ``responses`` carry: the
+    mergeable responses of one request, each output concatenated along
+    its first axis, the first response's parameters (its ``seq``), and
+    every token's own stamp."""
+    if len(responses) == 1:
+        return responses[0]
+    first = responses[0]
+    outputs = []
+    for n, (spec, _, delivery) in enumerate(first.outputs):
+        array = np.concatenate([r.outputs[n][1] for r in responses])
+        outputs.append((dict(spec, shape=list(array.shape)), array,
+                        delivery))
+    merged = InferResponse(first.model_name, first.model_version, first.id,
+                           outputs, dict(first.parameters))
+    merged.parameters[TOKEN_COUNT_PARAM] = len(outputs[0][1])
+    merged.emitted_at = [t for r in responses for t in r.emitted_at]
+    merged.mergeable = True
+    return merged
 
 
 def _instance_kind(model):
@@ -867,12 +913,16 @@ class InferenceServer:
         self._m_shm_written = self.metrics.counter(
             "tpu_shm_bytes_written_total").labels()
         # a streamed token's wait from the decode loop's queue to the
-        # transport (count_token_handoff): model -> (seconds, count)
-        # children, bound on a model's first stamped response
-        self._m_handoff_seconds = self.metrics.counter(
-            "tpu_frontend_token_handoff_seconds_total", labelnames=("model",))
-        self._m_handoffs = self.metrics.counter(
-            "tpu_frontend_token_handoffs_total", labelnames=("model",))
+        # transport, and the loop's emissions and the responses that
+        # carried them (count_token_handoff): model -> (seconds, tokens,
+        # emissions, responses) children, bound on a model's first
+        # stamped response
+        self._handoff_families = tuple(
+            self.metrics.counter(name, labelnames=("model",)) for name in (
+                "tpu_frontend_token_handoff_seconds_total",
+                "tpu_frontend_token_handoffs_total",
+                "tpu_frontend_stream_emissions_total",
+                "tpu_frontend_stream_responses_total"))
         self._handoff_children = {}
         self.metrics.register_collector(self._collect_metrics)
         self.metrics.register_collector(self._collect_shm_ring)
@@ -1049,25 +1099,34 @@ class InferenceServer:
         child.inc()
 
     def count_token_handoff(self, resp):
-        """A frontend hands ``resp`` to its transport: add its wait
-        since the decode loop queued its token to
-        ``tpu_frontend_token_handoff_seconds_total`` and one to
-        ``tpu_frontend_token_handoffs_total``.  A response no loop
-        stamped (an error, a replayed token, a model without a
-        scheduler) is not counted."""
-        emitted_at = resp.emitted_at
-        if emitted_at is None:
+        """A frontend hands ``resp`` to its transport: add each of its
+        tokens' wait since the decode loop queued it, by the token's own
+        stamp, to ``tpu_frontend_token_handoff_seconds_total`` and one a
+        token to ``tpu_frontend_token_handoffs_total`` (a replayed token
+        carries no stamp and is not counted there); and its emissions,
+        replayed ones included, to ``tpu_frontend_stream_emissions_total``
+        (a block model's emission is a block) and one to
+        ``tpu_frontend_stream_responses_total``.  A response no loop
+        stamped (an error, a model without a scheduler) is not counted.
+        Called before the transport write, so the count lands before
+        the client can hold what it counts."""
+        stamps = resp.emitted_at
+        if stamps is None:
             return
-        waited = time.monotonic() - emitted_at
+        now = time.monotonic()
+        waits = [now - t for t in stamps if t is not None]
         children = self._handoff_children.get(resp.model_name)
         if children is None:
             # labels() hands every caller the same children: a race
             # here binds them twice, harmlessly
-            children = self._handoff_children[resp.model_name] = (
-                self._m_handoff_seconds.labels(model=resp.model_name),
-                self._m_handoffs.labels(model=resp.model_name))
-        children[0].inc(waited)
-        children[1].inc()
+            children = self._handoff_children[resp.model_name] = tuple(
+                family.labels(model=resp.model_name)
+                for family in self._handoff_families)
+        if waits:
+            children[0].inc(sum(waits))
+            children[1].inc(len(waits))
+        children[2].inc(len(stamps))
+        children[3].inc()
 
     def _collect_metrics(self):
         """Scrape-time collector: the in-flight gauge plus every
@@ -1129,6 +1188,7 @@ class InferenceServer:
                 "diffusion_tokens_unmasked",
             "tpu_diffusion_blocks_committed_total":
                 "diffusion_blocks_committed",
+            "tpu_scheduler_control_uploads_total": "control_uploads",
         }
         samples = {name: [] for name in per_family}
         # the decode loop's seconds by phase, wall and off the CPU: the
@@ -2050,13 +2110,17 @@ class InferenceServer:
                 self._check_deadline(request.deadline)
                 count += 1
                 extra_params = emitted_at = None
-                if RESPONSE_PARAMS_KEY in out or EMITTED_AT_KEY in out:
+                mergeable = False
+                if (RESPONSE_PARAMS_KEY in out or EMITTED_AT_KEY in out
+                        or MERGEABLE_KEY in out):
                     out = dict(out)
                     extra_params = out.pop(RESPONSE_PARAMS_KEY, None)
                     emitted_at = out.pop(EMITTED_AT_KEY, None)
+                    mergeable = out.pop(MERGEABLE_KEY, False)
                 resp = self._make_response(model, request, out,
                                            mark_final=False)
                 resp.emitted_at = emitted_at
+                resp.mergeable = mergeable
                 if extra_params:
                     resp.parameters.update(extra_params)
                 if want_final:

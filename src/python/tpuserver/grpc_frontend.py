@@ -16,12 +16,14 @@ import grpc
 
 from tpuserver._trace import span
 from tpuserver.core import (
+    MULTI_TOKEN_PARAM,
     InferRequest,
     RequestedOutput,
     ServerError,
     SERVER_EXTENSIONS,
     SERVER_NAME,
     SERVER_VERSION,
+    merge_responses,
 )
 from tritonclient.grpc import grpc_service_pb2 as pb
 from tritonclient.grpc._service import METHODS, SERVICE
@@ -54,6 +56,28 @@ def _param_value(p):
 
 def _params_dict(param_map):
     return {k: _param_value(v) for k, v in param_map.items()}
+
+
+def _merge_waiting(waiting):
+    """What waits in a stream's queue, ``(item, response, request)`` in
+    order, as ``(item, response)`` pairs to send: every mergeable
+    response (``item`` None) of a request joins, in order, the first of
+    that request still open, until something else of the request comes.
+    Each request keeps its order; no request waits for another."""
+    out, open_at = [], {}
+    for item, resp, request in waiting:
+        if item is None:
+            at = open_at.get(request)
+            if at is None:
+                open_at[request] = len(out)
+                out.append([resp])
+            else:
+                out[at].append(resp)
+        else:
+            open_at.pop(request, None)
+            out.append((item, resp))
+    return [(None, merge_responses(e)) if isinstance(e, list) else e
+            for e in out]
 
 
 
@@ -397,6 +421,11 @@ class _CoreBridge:
         per-step token fans out as a response tagged with its request id
         and the client demultiplexes.  Within one generation, token
         order is still the emission order of its scheduler slot.
+
+        A request carrying ``MULTI_TOKEN_PARAM`` declares that its client
+        reads multi-token responses: the handler then sends, each time it
+        is free, every mergeable response of that request already waiting
+        as ONE (``merge_responses``).  It never waits to fill one.
         """
         import queue as _queue
         import threading as _threading
@@ -412,14 +441,16 @@ class _CoreBridge:
         lock = _threading.Lock()
         _SENTINEL = object()
 
-        def emit(item, resp=None):
+        def emit(item, resp=None, request=None):
             """put with cancellation: a gone client must not wedge
             producer threads on a full queue.  ``resp`` is the core
-            response ``item`` was built from, whose stamp the handler
-            counts when it hands ``item`` on."""
+            response ``item`` was built from, whose stamps the handler
+            counts when it hands ``item`` on; ``item`` None leaves the
+            proto to the handler, which merges ``resp`` with what of
+            ``request`` waits behind it."""
             while not cancelled.is_set():
                 try:
-                    out.put((item, resp), timeout=0.25)
+                    out.put((item, resp, request), timeout=0.25)
                     return True
                 except _queue.Full:
                     continue
@@ -437,16 +468,20 @@ class _CoreBridge:
                     if cancelled.is_set() or not context.is_active():
                         break  # stop generating for a gone client
                     with span("frontend.emit"):
-                        sent = emit(pb.ModelStreamInferResponse(
-                            infer_response=self._response_to_proto(resp)),
-                            resp)
+                        sent = emit(None if resp.mergeable else
+                                    pb.ModelStreamInferResponse(
+                                        infer_response=self
+                                        ._response_to_proto(resp)),
+                                    resp, core_request)
                     if not sent:
                         break
             except ServerError as e:
-                emit(pb.ModelStreamInferResponse(error_message=str(e)))
+                emit(pb.ModelStreamInferResponse(error_message=str(e)),
+                     None, core_request)
             except Exception as e:
                 emit(pb.ModelStreamInferResponse(
-                    error_message="unexpected error: {}".format(e)))
+                    error_message="unexpected error: {}".format(e)),
+                    None, core_request)
             finally:
                 if bounded:
                     inflight.release()
@@ -460,6 +495,9 @@ class _CoreBridge:
                     try:
                         core_request = self._stamp_deadline(
                             self._request_from_proto(request), context)
+                        core_request.multi_token = bool(
+                            core_request.parameters.pop(
+                                MULTI_TOKEN_PARAM, False))
                     except Exception as e:
                         emit(pb.ModelStreamInferResponse(
                             error_message=str(e)))
@@ -504,17 +542,27 @@ class _CoreBridge:
             from tpuserver import faults as _faults
 
             while True:
-                item, resp = out.get()
-                if item is _SENTINEL:
-                    return
-                # chaos hook: kill the bidi stream mid-flight (the
-                # raised FaultInjected aborts the RPC with a stream-
-                # level error) so client reconnect+resume is drivable
-                # end-to-end; skip=N drops after the Nth response
-                _faults.fire("grpc.stream_infer", self._core.fault_scope)
-                if resp is not None:
-                    self._core.count_token_handoff(resp)
-                yield item
+                waiting = [out.get()]
+                while True:
+                    try:
+                        waiting.append(out.get_nowait())
+                    except _queue.Empty:
+                        break
+                for item, resp in _merge_waiting(waiting):
+                    if item is _SENTINEL:
+                        return
+                    # chaos hook: kill the bidi stream mid-flight (the
+                    # raised FaultInjected aborts the RPC with a stream-
+                    # level error) so client reconnect+resume is
+                    # drivable end-to-end; skip=N drops after the Nth
+                    # response
+                    _faults.fire("grpc.stream_infer", self._core.fault_scope)
+                    if resp is not None:
+                        self._core.count_token_handoff(resp)
+                    if item is None:
+                        item = pb.ModelStreamInferResponse(
+                            infer_response=self._response_to_proto(resp))
+                    yield item
         finally:
             # reader gone (cancel/deadline/exit): release producers and
             # stop outstanding generation

@@ -400,12 +400,15 @@ class _Handler(BaseHttpHandler):
                     # terminal chunk) so client auto-resume is drivable
                     # end-to-end; skip=N drops after the Nth event
                     _faults.fire("http.generate_stream", core.fault_scope)
+                    # counted before the write, as the gRPC handler
+                    # counts before its yield: a client that holds the
+                    # event finds it counted
+                    core.count_token_handoff(resp)
                     self._send_chunk(
                         event + b"data: "
                         + json.dumps(payload).encode("utf-8")
                         + b"\n\n"
                     )
-                    core.count_token_handoff(resp)
         except _faults.FaultInjected:
             try:
                 self.connection.close()

@@ -153,6 +153,14 @@ CATALOG = {
         "wall seconds of the phase less the thread's CPU seconds, per "
         "model and phase (the phases of "
         "tpu_scheduler_loop_seconds_total, never more than its value)."),
+    "tpu_scheduler_control_uploads_total": (
+        "counter",
+        "Decode steps dispatched that sent their control (page tables, "
+        "positions, forced tokens, live rows) to the device, per model; "
+        "the others ran on the device's own copy, advanced by the step "
+        "before.  Over tpu_scheduler_step_seconds_count, the share of "
+        "steps that uploaded: admissions, retirements, forced tokens "
+        "and window moves."),
     "tpu_scheduler_codel_sheds_total": (
         "counter",
         "Admissions shed by the adaptive (CoDel-style) queue "
@@ -169,13 +177,27 @@ CATALOG = {
         "counter",
         "Seconds streamed tokens waited from the decode loop's put on "
         "their stream's queue to their hand-over to the transport (the "
-        "gRPC handler's yield, the SSE write), summed per model.  Over "
+        "gRPC handler's yield, the SSE write), each token by its own "
+        "stamp, summed per model.  Over "
         "tpu_frontend_token_handoffs_total, a token's mean wait."),
     "tpu_frontend_token_handoffs_total": (
         "counter",
-        "Streamed responses handed to the transport that carried a "
-        "token the decode loop stamped (a block model: a finished "
-        "block), per model; errors and replayed tokens do not count."),
+        "Streamed tokens handed to the transport that the decode loop "
+        "stamped (a block model: finished blocks), per model, whether "
+        "one a response or several; errors and replayed tokens do not "
+        "count."),
+    "tpu_frontend_stream_emissions_total": (
+        "counter",
+        "The decode loop's emissions (a token; a block model's finished "
+        "block) that streamed responses handed to the transport carried, "
+        "replayed ones included, per model.  Over "
+        "tpu_frontend_stream_responses_total, the emissions a response "
+        "carries: above 1 where a gRPC client reads multi-token "
+        "responses and tokens waited for the handler."),
+    "tpu_frontend_stream_responses_total": (
+        "counter",
+        "Streamed responses handed to the transport that carried one "
+        "or more of the decode loop's emissions, per model."),
     # -- paged KV + radix prefix cache -------------------------------------
     "tpu_prefix_cache_hits_total": (
         "counter",

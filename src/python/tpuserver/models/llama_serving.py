@@ -651,12 +651,26 @@ class LlamaGenerateModel(Model):
         ``resume_from_seq``, the first sequence number not yet seen)
         instead continues a parked generation: buffered tokens replay
         first, then live tokens splice in — no duplicates, no gaps.
-        Resume is same-endpoint only (replay state is replica-local)."""
+        Resume is same-endpoint only (replay state is replica-local).
+
+        A request whose client reads multi-token responses
+        (``request.multi_token``) gets every token of the generation
+        already waiting in one response (``TOKEN`` / ``LOGPROB`` of
+        shape ``[k]``, ``seq`` the first token's), marked mergeable for
+        the frontend; a block configuration (a block is already one
+        response), the shm token ring (a token a slot) and a request
+        that names its outputs (their delivery is per response) never
+        are."""
         import uuid
 
         import jax.numpy as jnp
 
-        from tpuserver.core import EMITTED_AT_KEY, RESPONSE_PARAMS_KEY
+        from tpuserver.core import (
+            EMITTED_AT_KEY,
+            MERGEABLE_KEY,
+            RESPONSE_PARAMS_KEY,
+            TOKEN_COUNT_PARAM,
+        )
         from tpuserver.scheduler import SchedulerClosed
 
         scheduler = self._scheduler
@@ -665,6 +679,9 @@ class LlamaGenerateModel(Model):
             # admitted: same typed outcome as racing submit into it
             raise SchedulerClosed("scheduler is shut down")
 
+        batched = (getattr(request, "multi_token", False)
+                   and not self._cfg.block_len and ring_write is None
+                   and not request.requested_outputs)
         resume_id = request.parameters.get("resume_generation_id")
         if resume_id:
             from_seq = int(request.parameters.get("resume_from_seq", 0))
@@ -673,7 +690,8 @@ class LlamaGenerateModel(Model):
             # the original request's bound died with its connection
             stream = scheduler.resume(
                 gen_id, from_seq,
-                deadline=getattr(request, "deadline", None))
+                deadline=getattr(request, "deadline", None),
+                batched=batched)
             seq = from_seq
         else:
             region = self._kv_region(request)
@@ -722,9 +740,26 @@ class LlamaGenerateModel(Model):
                 kv_export_on_finish=kv_prefill,
                 attach_cache=attach_cache,
                 attach_pos=attach_pos,
+                batched=batched,
             )
             seq = 0
         for item in stream:
+            if batched:
+                # every token of the generation waiting now, one response
+                params = {"generation_id": gen_id, "seq": seq}
+                if len(item) > 1:
+                    params[TOKEN_COUNT_PARAM] = len(item)
+                yield {
+                    "TOKEN": np.array([t for t, _ in item], dtype=np.int32),
+                    "LOGPROB": np.array([lp for _, lp in item],
+                                        dtype=np.float32),
+                    RESPONSE_PARAMS_KEY: params,
+                    EMITTED_AT_KEY: [getattr(p, "emitted_at", None)
+                                     for p in item],
+                    MERGEABLE_KEY: True,
+                }
+                seq += len(item)
+                continue
             token, logprob = item
             if self._cfg.block_len:
                 # a finished block; seq counts responses
@@ -764,7 +799,7 @@ class LlamaGenerateModel(Model):
                 }
             # when the decode loop queued a live token (a replayed one
             # carries no stamp): the frontend counts the wait from there
-            event[EMITTED_AT_KEY] = getattr(item, "emitted_at", None)
+            event[EMITTED_AT_KEY] = [getattr(item, "emitted_at", None)]
             yield event
             seq += 1
 

@@ -92,8 +92,10 @@ Self-healing (tests/test_self_healing.py, docs/resilience.md):
 
 import contextlib
 import math
+import queue as _queue
 import threading
 import time
+import weakref
 from collections import OrderedDict, deque
 
 import numpy as np
@@ -267,8 +269,6 @@ class _Stream:
                  resume_pos, on_finish, deadline=None, generation_id=None,
                  prompt_dev=None, kv_export=False,
                  kv_export_on_finish=False):
-        import queue as _queue
-
         self.prompt = prompt
         self.max_tokens = max_tokens
         self.eos_id = eos_id
@@ -412,6 +412,126 @@ class _LoopClock:
         finally:
             self._open.pop()
             self._charge(phase)
+
+
+class _ControlledStep:
+    """A configuration's step (``fns["step"]``) behind one transfer each
+    way.
+
+    What a step is told besides the weights, the pool and the logits is
+    ONE int32 array ``[max_slots, width]``, a row a slot (``picture``):
+    the page-table row, the window-class ring row of a two-class pool,
+    then the row's vector, ``position, active, forced token, forced``
+    (a token a step) or ``denoising steps, threshold bits, active`` (a
+    block a step).  The device keeps its copy from one step to the next
+    and advances it as the host does (a live row's position + 1, a
+    forced token spent), so a step sends the host's picture only where
+    it differs from what the device holds: an admission, a retirement,
+    a forced token, a window move, or a new loop, which starts with no
+    copy.  The step's results (tokens, logprobs, and a routed
+    configuration's counts) come back as ONE int32 array (``unpack``).
+
+    One instance a step function and geometry (``of``), so every loop
+    over one function bundle, a restart's or another scheduler's,
+    reuses the compiled program, which the profile names after the
+    step's function; a loop's own copy travels as ``held``, never on
+    the instance, so a demoted loop that wakes cannot touch its
+    successor's."""
+
+    _instances = weakref.WeakKeyDictionary()  # step -> {geometry: self}
+
+    @classmethod
+    def of(cls, step, pages_per_seq, ring, blk):
+        by_geometry = cls._instances.setdefault(step, {})
+        key = (pages_per_seq, ring, blk)
+        if key not in by_geometry:
+            by_geometry[key] = cls(step, *key)
+        return by_geometry[key]
+
+    def __init__(self, step, pages_per_seq, ring, blk):
+        import jax
+
+        # held weakly: the instance lives as long as its step does
+        self._step = weakref.ref(step)
+        self._tables = pages_per_seq  # columns of the page-table row
+        self._ring = ring             # window-class ring columns, or 0
+        self._blk = blk
+        self._layout = None           # [(shape, dtype)] of the results
+
+        def run(params, pages, logits, control):
+            return self._control_step(params, pages, logits, control)
+
+        run.__name__ = run.__qualname__ = getattr(step, "__name__", "step")
+        self._run = jax.jit(run, donate_argnums=(1, 2))
+
+    def picture(self, tables, tables_w, *vectors):
+        """The host's picture of a step: the tables, then each
+        ``[max_slots]`` vector as a column (a float32 one by its bits)."""
+        cols = [v.view(np.int32) if v.dtype == np.float32
+                else v.astype(np.int32) for v in vectors]
+        return np.concatenate(
+            [tables] + ([tables_w] if self._ring else [])
+            + [c[:, None] for c in cols], axis=1)
+
+    def _advance(self, picture):
+        """What the device's copy holds after a step on ``picture``."""
+        if self._blk:
+            return picture
+        n = self._tables + self._ring
+        held = picture.copy()
+        held[:, n] += held[:, n + 1]
+        held[:, n + 2:] = 0
+        return held
+
+    def _control_step(self, params, pages, logits, control):
+        import jax.numpy as jnp
+        from jax import lax
+
+        n = self._tables
+        tables = control[:, :n]
+        if self._ring:
+            tables = {"full": tables, "window": control[:, n:n + self._ring]}
+            n += self._ring
+        if self._blk:
+            steps, taus, active = (control[:, n + k] for k in range(3))
+            args = (steps, lax.bitcast_convert_type(taus, jnp.float32),
+                    active.astype(bool))
+            advanced = control
+        else:
+            pos, active, forced, forced_mask = (
+                control[:, n + k] for k in range(4))
+            args = (pos, active.astype(bool), forced,
+                    forced_mask.astype(bool))
+            advanced = control.at[:, n].add(active).at[:, n + 2:].set(0)
+        tokens, logps, logits, pages, *counts = self._step()(
+            params, pages, logits, tables, *args)
+        results = (tokens, logps, *counts)
+        self._layout = [(r.shape, np.dtype(r.dtype)) for r in results]
+        packed = jnp.concatenate([
+            (lax.bitcast_convert_type(r, jnp.int32)
+             if r.dtype == jnp.float32 else r.astype(jnp.int32)).reshape(-1)
+            for r in results])
+        return packed, logits, pages, advanced
+
+    def __call__(self, params, pages, logits, picture, held):
+        """Dispatch one step on the host's ``picture``.  ``held`` is
+        ``(device copy, what it holds)`` after this loop's last step, or
+        None.  Returns the packed results (a device array), the logits,
+        the pages, the new ``held`` and whether the picture was sent."""
+        sent = held is None or not np.array_equal(picture, held[1])
+        packed, logits, pages, device = self._run(
+            params, pages, logits, picture if sent else held[0])
+        return packed, logits, pages, (device, self._advance(picture)), sent
+
+    def unpack(self, packed):
+        """The step's results as host arrays, from its fetched array."""
+        out, at = [], 0
+        for shape, dtype in self._layout:
+            part = packed[at:at + math.prod(shape)]
+            at += len(part)
+            out.append((part.view(np.float32) if dtype == np.float32
+                        else part.astype(dtype)).reshape(shape))
+        return out
 
 
 class DecodeScheduler:
@@ -562,6 +682,9 @@ class DecodeScheduler:
         self._diffusion_commit_passes = 0
         self._diffusion_fused_commits = 0
         self._diffusion_tokens_unmasked = 0
+        # steps dispatched that sent their control to the device (the
+        # rest ran on the device's own advanced copy: _ControlledStep)
+        self._control_uploads = 0
         # block length of a configuration that generates by diffusion
         # over blocks (llama.make_scheduler_fns "block_len"), else 0:
         # the step then carries a block a row, and what does not know
@@ -647,11 +770,13 @@ class DecodeScheduler:
                generation_id=None, prompt_dev=None, kv_export=False,
                kv_export_on_finish=False, attach_cache=None,
                attach_pos=0, denoising_steps=None,
-               confidence_threshold=None):
+               confidence_threshold=None, batched=False):
         """Enqueue one generation; returns an iterator of
         ``(token, logprob)`` pairs that blocks as the decode loop
         produces them (each an :class:`Emitted`, stamped when the loop
-        queued it).
+        queued it).  ``batched`` yields instead, each time, the list of
+        every pair already waiting (at least one; never waits for a
+        second): a reader that sends what waits in one response.
 
         A configuration that generates by diffusion over blocks yields
         ``(block, None)`` pairs instead, one a finished block: ``block``
@@ -783,10 +908,10 @@ class DecodeScheduler:
             self._streams.add(stream)
             self._ensure_running_locked()
             self._cond.notify_all()
-        return self._drain(stream)
+        return self._drain(stream, batched)
 
     def resume(self, generation_id, from_seq=0, wait_s=5.0,
-               deadline=None):
+               deadline=None, batched=False):
         """Continue a parked generation: replays its buffered
         ``(token, logprob)`` history from ``from_seq`` (the first
         sequence number the caller has NOT seen), then — for an
@@ -805,7 +930,8 @@ class DecodeScheduler:
         request's own monotonic bound (None lifts any bound): the
         original request's deadline died with its connection — a
         reconnect carrying a fresh timeout must not be killed by the
-        stale one."""
+        stale one.  ``batched`` as in :meth:`submit`: the replayed
+        pairs come as one list, then the live ones as they wait."""
         if self._block_len:
             raise self._unsupported(
                 "resume of a generation (resume_generation_id)")
@@ -866,8 +992,6 @@ class DecodeScheduler:
                         "scheduler is draining; not accepting new "
                         "generations"
                     )
-                import queue as _queue
-
                 # fresh queue: the abandoned one may hold tokens the old
                 # consumer never took — those are re-delivered from the
                 # history snapshot above, never from the stale queue
@@ -905,10 +1029,13 @@ class DecodeScheduler:
             self._kv_discard(generation_id)
 
         def gen():
-            live = None if completed else self._drain(stream)
+            live = None if completed else self._drain(stream, batched)
             try:
-                for tok, lp in replay:
-                    yield tok, lp
+                if batched:
+                    if replay:
+                        yield replay
+                else:
+                    yield from replay
                 if live is not None:
                     for item in live:
                         yield item
@@ -922,11 +1049,25 @@ class DecodeScheduler:
         return gen()
 
     @staticmethod
-    def _drain(stream):
+    def _drain(stream, batched=False):
+        held = None  # an event taken behind a batch, for the next turn
         try:
             while True:
-                kind, a, b = stream.queue.get()
-                if kind == "tok":
+                kind, a, b = held or stream.queue.get()
+                held = None
+                if kind == "tok" and batched:
+                    batch = [Emitted(a, b)]
+                    while held is None:
+                        try:
+                            kind, a, b = stream.queue.get_nowait()
+                        except _queue.Empty:
+                            break
+                        if kind == "tok":
+                            batch.append(Emitted(a, b))
+                        else:
+                            held = kind, a, b
+                    yield batch
+                elif kind == "tok":
                     yield Emitted(a, b)
                 elif kind == "err":
                     stream.finished = True
@@ -1079,6 +1220,7 @@ class DecodeScheduler:
                 "diffusion_blocks_committed":
                     self._diffusion_commit_passes
                     + self._diffusion_fused_commits,
+                "control_uploads": self._control_uploads,
                 "loop_seconds": dict(self._loop_seconds),
                 "loop_offcpu_seconds": dict(self._loop_offcpu_seconds),
                 # a fact of the build, not a rate: which decode
@@ -1414,13 +1556,17 @@ class DecodeScheduler:
             # together (the radix cache restarts cold and re-warms)
             self._pager = (alloc, radix)
             self._window_alloc = alloc_w
-        # per-slot page tables, re-scattered to the device each step
-        # (sentinel rows are inert); mutated in place as slots turn
-        # over — each dispatch converts the then-current content
+        # per-slot page tables (sentinel rows are inert); mutated in
+        # place as slots turn over — each dispatch packs the then-current
+        # content into its picture, which reaches the device only where
+        # it differs from the device's own copy
         tables = np.full((self._max_slots, ppseq), n_pages, np.int32)
         ready = [False] * self._max_slots  # prefill complete
         prefilling = {}                    # slot -> _PrefillTask
-        inflight = None  # (tokens_dev, logps_dev, snapshot)
+        inflight = None  # (packed results on the device, snapshot)
+        controlled = _ControlledStep.of(
+            fns["step"], ppseq, ring if wc else 0, blk)
+        held = None  # this loop's (device copy of the control, its value)
 
         def clear_slot(slot):
             slots[slot] = None
@@ -2102,7 +2248,7 @@ class DecodeScheduler:
                             active[i] = True
                             steps[i], taus[i] = st.steps, st.tau
                             snapshot.append((i, st, False, st.incarnation))
-                        step_args = (steps, taus, active)
+                        vectors = (steps, taus, active)
                     else:
                         positions = np.full(
                             (self._max_slots,), self._max_seq, np.int32)
@@ -2123,8 +2269,12 @@ class DecodeScheduler:
                                 move_window(i, st)
                                 skipped += max(0, st.pos + 1 - window)
                             st.pos += 1
-                        step_args = (positions, active, forced_tok,
-                                     forced_mask)
+                        vectors = (positions, active, forced_tok,
+                                   forced_mask)
+                    # a fresh array a step: the device may alias what it
+                    # was sent while earlier steps are still in flight
+                    picture = controlled.picture(
+                        tables, tables_w if wc else None, *vectors)
                     # key positions this step's attention layers cover,
                     # and those its window layers need not read
                     self._context_tokens += context * n_layers_all
@@ -2146,40 +2296,29 @@ class DecodeScheduler:
                     self._beat(epoch, step_start)
                     if action is not None and action[0] == "hang":
                         time.sleep(action[1])
-                    tokens_dev, logps_dev, logits, pages, *moe_dev = fns[
-                        "step"](
-                        self._params, pages, logits,
-                        # the ring rows are rewritten while earlier steps
-                        # are still in flight (move_window): this step gets
-                        # its own copy (a CPU backend may alias numpy
-                        # memory instead of copying it)
-                        {"full": tables, "window": tables_w.copy()} if wc
-                        else tables,
-                        *step_args,
-                    )
+                    packed_dev, logits, pages, held, sent = controlled(
+                        self._params, pages, logits, picture, held)
+                    self._control_uploads += sent
                     self._beat(epoch, None)
                     if self._step_hist is not None:
                         # lock-free observe: the loop must never acquire a
                         # lock per step just to be observable
                         self._step_hist.observe(
                             time.monotonic() - step_start)
-                    # a routed configuration's step has a fifth result
-                    current = (tokens_dev, logps_dev, snapshot,
-                               moe_dev[0] if moe_dev else None)
+                    current = (packed_dev, snapshot)
 
             if inflight is not None:
-                tokens_dev, logps_dev, snapshot, moe_dev = inflight
+                packed_dev, snapshot = inflight
                 with phase("fetch"):
                     # host-transfer chaos; a raise is loop death (restart)
                     faults.fire("scheduler.fetch", self.fault_scope)
                     self._beat(epoch, time.monotonic())
-                    toks = np.asarray(tokens_dev)
-                    lps = np.asarray(logps_dev)
-                    if moe_dev is not None:
-                        # three integers of the routed layers, fetched
-                        # with the step's tokens
-                        layer_steps, pairs, hit = (
-                            int(n) for n in np.asarray(moe_dev))
+                    toks, lps, *moe = controlled.unpack(
+                        np.asarray(packed_dev))
+                    if moe:
+                        # a routed configuration's three integers of its
+                        # routed layers, fetched with the step's tokens
+                        layer_steps, pairs, hit = (int(n) for n in moe[0])
                         self._moe_layer_steps += layer_steps
                         self._moe_local_pairs += pairs
                         self._moe_experts_hit += hit
@@ -2258,7 +2397,7 @@ class DecodeScheduler:
         # closed: fail whatever is still queued or running
         err = SchedulerClosed("scheduler is shut down")
         if inflight is not None:
-            for i, st, _, _ in inflight[2]:
+            for i, st, _, _ in inflight[1]:
                 if slots[i] is st:
                     slots[i] = None
                     self._fail(st, err, epoch)

@@ -21,7 +21,7 @@ from tritonclient.utils import InferenceServerException, raise_error
 
 from . import grpc_service_pb2 as pb
 from ._infer_input import InferInput, InferRequestedOutput  # noqa: F401
-from ._infer_result import InferResult
+from ._infer_result import InferResult, token_results
 from ._infer_stream import _InferStream, _PulledStream
 from ._service import ServiceStub
 from ._utils import (
@@ -765,8 +765,14 @@ class InferenceServerClient:
     ):
         """Synchronous generator over ONE decoupled generation with
         transparent reconnect+resume, yielding an ``InferResult`` per
-        streamed response (the terminal empty-final response is
+        streamed token (the terminal empty-final response is
         consumed, not yielded).
+
+        The call declares (``multi_token_responses``) that it reads
+        responses carrying several tokens: a server may then send every
+        token of the generation already waiting in one response, which
+        is parsed once and yielded a token at a time, each result with
+        its token's own ``seq``, as one-token responses give them.
 
         ``fallback_urls`` (``host:port`` peers — a respawned server on
         a new address, or sibling endpoints fronting the same fleet)
@@ -851,6 +857,8 @@ class InferenceServerClient:
             if len(targets) > 1:
                 self._rebind(targets[attempt % len(targets)])
             send_params = dict(base_params)
+            # this reader takes a response of several tokens apart
+            send_params["multi_token_responses"] = True
             sent_resume = gen_id is not None and last_seq >= 0
             if sent_resume:
                 # mid-generation reconnect: ask the server to replay
@@ -905,13 +913,15 @@ class InferenceServerClient:
                         if "generation_id" in resp.parameters:
                             gen_id = resp.parameters[
                                 "generation_id"].string_param
-                        if "seq" in resp.parameters:
-                            seq = resp.parameters["seq"].int64_param
-                            if seq <= last_seq:
-                                continue  # replayed duplicate
-                            last_seq = seq
-                        yielded_any = True
-                        yield InferResult(resp)
+                        seq = (resp.parameters["seq"].int64_param
+                               if "seq" in resp.parameters else None)
+                        for n, result in enumerate(token_results(resp)):
+                            if seq is not None:
+                                if seq + n <= last_seq:
+                                    continue  # replayed duplicate
+                                last_seq = seq + n
+                            yielded_any = True
+                            yield result
                     return  # the server ended the call
                 except grpc.RpcError as rpc_error:
                     # the transport died (refused, reset, aborted by the

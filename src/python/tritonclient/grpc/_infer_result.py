@@ -80,3 +80,67 @@ class InferResult:
                 self._result, preserving_proto_field_name=True
             )
         return self._result
+
+
+#: Response parameter of a streamed response that carries several tokens
+#: of one generation (the client asked for such responses): how many.
+#: Each output's first axis is then a token, and ``seq`` the first's.
+TOKEN_COUNT_PARAM = "token_count"
+
+
+def token_results(response):
+    """One result a token of ``response``: the response itself where it
+    carries one, else a :class:`_TokenResult` for each of its
+    ``token_count`` tokens, all read from ONE parse of the response."""
+    count = response.parameters.get(TOKEN_COUNT_PARAM)
+    if count is None:
+        return [InferResult(response)]
+    whole = InferResult(response)
+    arrays = {o.name: whole.as_numpy(o.name) for o in response.outputs}
+    seq = response.parameters["seq"].int64_param
+    return [_TokenResult(response, arrays, n, seq + n)
+            for n in range(count.int64_param)]
+
+
+class _TokenResult(InferResult):
+    """One token of a response that carries several: each output's
+    entry at the token's place along the first axis, and the token's own
+    ``seq``, so its reader sees what a one-token response gives.  The
+    token's own ``ModelInferResponse`` is built only when asked for
+    (numeric outputs: a token response carries ``TOKEN`` / ``LOGPROB``)."""
+
+    def __init__(self, response, arrays, index, seq):
+        super().__init__(response)
+        self._arrays = arrays   # output name -> the response's whole array
+        self._index = index
+        self._seq = seq
+        self._own = None
+
+    def as_numpy(self, name):
+        array = self._arrays.get(name)
+        return None if array is None else array[self._index:self._index + 1]
+
+    def get_output(self, name, as_json=False):
+        return InferResult(self._own_response()).get_output(name, as_json)
+
+    def get_response(self, as_json=False):
+        return InferResult(self._own_response()).get_response(as_json)
+
+    def _own_response(self):
+        if self._own is None:
+            whole = self._result
+            own = pb.ModelInferResponse(model_name=whole.model_name,
+                                        model_version=whole.model_version,
+                                        id=whole.id)
+            for key, value in whole.parameters.items():
+                if key != TOKEN_COUNT_PARAM:
+                    own.parameters[key].CopyFrom(value)
+            own.parameters["seq"].int64_param = self._seq
+            for output in whole.outputs:
+                tensor = own.outputs.add()
+                tensor.CopyFrom(output)
+                part = self.as_numpy(output.name)
+                tensor.shape[:] = part.shape
+                own.raw_output_contents.append(part.tobytes())
+            self._own = own
+        return self._own

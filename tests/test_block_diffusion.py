@@ -246,18 +246,22 @@ def test_rows_at_other_passes_and_step_classes_share_a_step(fns):
 
 
 @pytest.mark.parametrize("steps", [1, 2, 3, 4])
-def test_a_block_costs_its_denoise_passes_and_no_more(fns, steps):
+def test_a_block_costs_its_denoise_passes_and_no_more(fns, steps,
+                                                      monkeypatch):
     """N blocks at ``denoising_steps`` T take N x T step dispatches and
     the one-deep pipeline's last: each block's commit rode on its
     successor's first denoise pass."""
+    from tpuserver import scheduler as scheduler_mod
+
     calls = []
+    dispatch = scheduler_mod._ControlledStep.__call__
 
-    def counted(*args):
+    def counted(self, *args):
         calls.append(1)
-        return fns["step"](*args)
+        return dispatch(self, *args)
 
-    scheduler = DecodeScheduler(dict(fns, step=counted), PARAMS, SLOTS,
-                                MAX_SEQ)
+    monkeypatch.setattr(scheduler_mod._ControlledStep, "__call__", counted)
+    scheduler = DecodeScheduler(fns, PARAMS, SLOTS, MAX_SEQ)
     try:
         blocks = [blk for blk, _ in scheduler.submit(
             np.arange(3, 11), 20, denoising_steps=steps)]

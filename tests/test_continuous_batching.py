@@ -190,24 +190,26 @@ def test_prefill_bucket_preserves_kernel_choice():
     assert llama.prefill_bucket(pcfg, 512, 5) == 8
 
 
-def test_cancelled_stream_frees_slot_and_stops_decoding():
+def test_cancelled_stream_frees_slot_and_stops_decoding(monkeypatch):
     """Abandoning a token iterator (client cancel/disconnect) must
     retire its slot within a few steps instead of decoding the full
     budget into a queue nobody reads."""
     import jax
 
+    from tpuserver import scheduler as scheduler_mod
     from tpuserver.scheduler import DecodeScheduler
 
     params = llama.init_params(jax.random.PRNGKey(0), CFG)
     fns = llama.make_scheduler_fns(CFG, MAX_SEQ, max_slots=2)
     calls = [0]
-    orig_step = fns["step"]
+    dispatch = scheduler_mod._ControlledStep.__call__
 
-    def counting_step(*args):
+    def counting_step(self, *args):
         calls[0] += 1
-        return orig_step(*args)
+        return dispatch(self, *args)
 
-    fns["step"] = counting_step
+    monkeypatch.setattr(scheduler_mod._ControlledStep, "__call__",
+                        counting_step)
     sched = DecodeScheduler(fns, params, 2, MAX_SEQ)
     try:
         big_budget = 50

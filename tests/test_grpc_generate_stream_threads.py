@@ -33,6 +33,10 @@ MAX_SEQ = 64
 PROMPT = np.array([3, 1, 4, 1, 5], dtype=np.int32)
 BUDGET = 8
 WATCHDOG = "generate-stream-watchdog"
+# seconds a step where a scenario counts responses: a client that reads
+# multi-token responses gets one a token only if no token waits behind
+# another for the server's handler
+PACE = 0.1
 
 
 @pytest.fixture(autouse=True)
@@ -157,6 +161,9 @@ def _read_timeout(client, served, reference):
     """A server that falls silent mid-generation (connection open, no
     bytes, no error) costs the caller ``read_timeout`` and no more."""
     read_timeout = 0.5
+    # a token a step, each sent before the next comes: a response a
+    # token, so the second response is the second token
+    faults.install("scheduler.step", mode="slow", delay=PACE)
     faults.install("grpc.stream_infer", mode="partition", skip=2)
     got = []
     t0 = time.monotonic()
@@ -201,6 +208,7 @@ def _stream_killed(client, served, reference):
     """The transport dies mid-generation (``grpc.stream_infer``): the
     call reconnects with its resume token, and no ``seq`` is missing
     or doubled.  One watchdog an attempt, no reader in either."""
+    faults.install("scheduler.step", mode="slow", delay=PACE)
     faults.install("grpc.stream_infer", mode="raise", times=1, skip=3)
     reconnects = []
     results = list(client.generate_stream(

@@ -366,6 +366,9 @@ def test_grpc_client_resumes_across_injected_stream_kill(
     client = grpcclient.InferenceServerClient(
         "127.0.0.1:{}".format(frontend.port))
     try:
+        # a token a step, each sent before the next comes (a response a
+        # token): the drop comes after the third token, mid-generation
+        faults.install("scheduler.step", mode="slow", delay=0.03)
         faults.install("grpc.stream_infer", mode="raise", times=1, skip=3)
         p_in = grpcclient.InferInput("PROMPT_IDS", [len(PROMPTS[0])],
                                      "INT32")
@@ -386,6 +389,7 @@ def test_grpc_client_resumes_across_injected_stream_kill(
         assert len(reconnects) == 1
     finally:
         faults.clear("grpc.stream_infer")
+        faults.clear("scheduler.step")
         client.close()
         frontend.stop()
 
@@ -574,6 +578,9 @@ def test_grpc_resume_unknown_generation_retries_as_fleet_transition(
     client = grpcclient.InferenceServerClient(
         "127.0.0.1:{}".format(frontend.port))
     try:
+        # a token a step, each sent before the next comes (a response a
+        # token): the drop comes after the third token, mid-generation
+        faults.install("scheduler.step", mode="slow", delay=0.03)
         faults.install("grpc.stream_infer", mode="raise", times=1, skip=3)
         attempts = []
 
@@ -600,6 +607,7 @@ def test_grpc_resume_unknown_generation_retries_as_fleet_transition(
         assert "unknown or expired generation id" in attempts[1]
     finally:
         faults.clear("grpc.stream_infer")
+        faults.clear("scheduler.step")
         frontend._bridge._core = heal_core
         client.close()
         frontend.stop()

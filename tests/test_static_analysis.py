@@ -692,14 +692,15 @@ def test_scheduler_stats_keys_are_documented():
 
 def test_decode_loop_has_one_step_dispatch():
     """``DecodeScheduler._loop`` has ONE step body: it subscripts
-    ``fns["step"]`` at exactly one call site and names no second step
-    executable (whoever brings multi-token verification back writes it
-    against this body, not beside it)."""
+    ``fns["step"]`` once, to put it behind ``_ControlledStep`` (which
+    calls it at one site), dispatches that at exactly one call site, and
+    names no second step executable (whoever brings multi-token
+    verification back writes it against this body, not beside it)."""
     import ast
     import inspect
     import textwrap
 
-    from tpuserver.scheduler import DecodeScheduler
+    from tpuserver.scheduler import DecodeScheduler, _ControlledStep
 
     fn = ast.parse(textwrap.dedent(
         inspect.getsource(DecodeScheduler._loop))).body[0]
@@ -712,7 +713,17 @@ def test_decode_loop_has_one_step_dispatch():
              and isinstance(node.func, ast.Subscript)
              and isinstance(node.func.value, ast.Name)
              and node.func.value.id == "fns"]
-    assert keys.count("step") == 1 and calls.count("step") == 1
+    dispatches = [node for node in ast.walk(fn)
+                  if isinstance(node, ast.Call)
+                  and isinstance(node.func, ast.Name)
+                  and node.func.id == "controlled"]
+    body = ast.parse(textwrap.dedent(
+        inspect.getsource(_ControlledStep._control_step))).body[0]
+    steps = [node for node in ast.walk(body) if isinstance(node, ast.Call)
+             and isinstance(node.func, ast.Attribute)
+             and node.func.attr == "_step"]
+    assert keys.count("step") == 1 and "step" not in calls
+    assert len(dispatches) == 1 and len(steps) == 1
     # the other executables of the bundle admit, prefill and park
     assert {k for k in keys if "step" in k} == {"step"}, keys
 
