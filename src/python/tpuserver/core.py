@@ -1189,6 +1189,9 @@ class InferenceServer:
             "tpu_diffusion_blocks_committed_total":
                 "diffusion_blocks_committed",
             "tpu_scheduler_control_uploads_total": "control_uploads",
+            # the conv layers' windows (0 for a model without them)
+            "tpu_scheduler_state_bytes_total": "state_bytes",
+            "tpu_scheduler_state_writes_total": "state_writes",
         }
         samples = {name: [] for name in per_family}
         # the decode loop's seconds by phase, wall and off the CPU: the

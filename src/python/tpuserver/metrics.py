@@ -248,6 +248,18 @@ CATALOG = {
         "Of tpu_scheduler_context_tokens_total, the key positions that "
         "lay behind a window layer's window and were neither read nor "
         "kept, per model."),
+    "tpu_scheduler_state_bytes_total": (
+        "counter",
+        "Bytes of fixed-size per-sequence state (the conv layers' "
+        "windows) that the decode steps' rows read: per step and active "
+        "row the bytes a row's windows hold as the state array stores "
+        "them, per model.  Over tpu_scheduler_tokens_total, the state "
+        "a token's step read; 0 for a model without conv layers."),
+    "tpu_scheduler_state_writes_total": (
+        "counter",
+        "Rows of fixed-size per-sequence state (the conv layers' "
+        "windows) that admissions wrote whole, one an admission, per "
+        "model; 0 for a model without conv layers."),
     # -- routed (mixture-of-experts) layers --------------------------------
     "tpu_moe_layer_steps_total": (
         "counter",

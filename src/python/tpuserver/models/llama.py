@@ -77,10 +77,15 @@ class LlamaConfig:
     # a layer, a dense SwiGLU.  Everything below is read at trace time.
     # explicit head size where it is not d_model / n_heads (0 = derived)
     d_head: int = 0
-    # per-layer attention kind, "full" or "window" (() = all full);
-    # window layers see keys j with 0 <= i - j < window
+    # per-layer mixer kind, "full" or "window" attention, or "conv" (()
+    # = all full); window layers see keys j with 0 <= i - j < window
     layer_types: tuple = ()
     window: int = 0
+    # taps of a conv layer's short causal depthwise convolution
+    # (``conv_L_cache``): a sequence carries the last ``conv_len - 1``
+    # rows of the mixer's ``u`` a conv layer, a window of fixed size
+    # beside the page pool (:func:`conv_mix`)
+    conv_len: int = 3
     # which layers carry rotary positions: "all", or "window" (the full
     # layers then carry no positions at all)
     rope_layers: str = "all"
@@ -107,10 +112,23 @@ class LlamaConfig:
     # projections, None without: the cache then holds one latent row a
     # token a layer, and ``n_kv_heads`` / ``d_head`` are not read
     mla: object = None
+    # the head is the embedding's transpose (no ``lm_head`` leaf)
+    tie_embed: bool = False
+    # lanes a K/V head takes in the cache and the pool (0 = head_dim):
+    # the TPU lays an array's minor dimension out in tiles of 128 lanes,
+    # and the paged decode kernel copies whole tiles, so 64-wide heads
+    # are stored in 128 lanes, zeros behind them, and the padding is
+    # stated so that the pool's bytes are what HBM holds
+    kv_lanes: int = 0
 
     @property
     def head_dim(self):
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def kv_width(self):
+        """Lanes of one K/V head as the cache and the pool store it."""
+        return self.kv_lanes or self.head_dim
 
     def layer_window(self, i):
         """Layer ``i``'s attention window in tokens, 0 for full."""
@@ -124,6 +142,22 @@ class LlamaConfig:
     def layer_moe(self, i):
         return bool(self.ffn_types) and self.ffn_types[i] == "moe"
 
+    def layer_conv(self, i):
+        return bool(self.layer_types) and self.layer_types[i] == "conv"
+
+    @property
+    def conv_layers(self):
+        """Indices of the short-convolution layers (no K/V, no pages:
+        their state is a window a sequence)."""
+        return tuple(i for i in range(self.n_layers) if self.layer_conv(i))
+
+    @property
+    def attn_layers(self):
+        """Indices of the layers that attend, in order: a contiguous
+        cache's and a one-class pool's layer axis runs over these."""
+        return tuple(i for i in range(self.n_layers)
+                     if not self.layer_conv(i))
+
     @property
     def window_layers(self):
         """Indices of the layers that attend a window (their KV lives in
@@ -132,8 +166,7 @@ class LlamaConfig:
 
     @property
     def full_layers(self):
-        return tuple(i for i in range(self.n_layers)
-                     if not self.layer_window(i))
+        return tuple(i for i in self.attn_layers if not self.layer_window(i))
 
     @property
     def plain(self):
@@ -142,7 +175,7 @@ class LlamaConfig:
         return not (self.layer_types or self.ffn_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norm
                     or self.embed_scale != 1.0 or self.block_len
-                    or self.mla is not None)
+                    or self.mla is not None or self.tie_embed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,6 +190,8 @@ class MoEConfig:
     the experts lie in ``n_group`` equal groups in index order, a group
     scores the sum of its 2 largest choice values, and only the experts
     of the ``topk_group`` best groups can be chosen (1 / 1: no limit).
+    ``route_eps`` is added to the chosen scores' sum before the
+    normalisation divides by it.
     ``first`` / ``count`` say which experts THIS process holds (expert
     parallelism: the router keeps its published width, the layer
     computes the shared expert plus the held experts' part of the sum
@@ -173,6 +208,8 @@ class MoEConfig:
     n_shared: int = 1
     n_group: int = 1
     topk_group: int = 1
+    # added to the chosen scores' sum before ``route_norm`` divides by it
+    route_eps: float = 1e-20
 
     @property
     def held(self):
@@ -294,6 +331,24 @@ def tiny_deepseek(vocab=256, first=0, count=0):
     )
 
 
+def tiny_lfm2(vocab=256):
+    """Test-size LFM2 block (gated short convolutions beside QK-normed
+    GQA, the LFM2-MoE family): a dense conv layer, then routed layers
+    attention, conv, conv, attention, conv; a 3-tap convolution, rotary
+    positions on every attention layer, sigmoid top-2 of 8 experts with
+    an expert bias for the choice and no shared expert, the head tied to
+    the embedding."""
+    return LlamaConfig(
+        vocab=vocab, d_model=64, n_layers=6, n_heads=4, n_kv_heads=2,
+        d_ff=128, rope_theta=10000.0, qk_norm=True,
+        layer_types=("conv", "full", "conv", "conv", "full", "conv"),
+        conv_len=3, ffn_types=("dense",) + ("moe",) * 5,
+        moe=MoEConfig(n_experts=8, top_k=2, d_expert=32, n_shared=0,
+                      route_eps=1e-6),
+        tie_embed=True,
+    )
+
+
 # -- parameters --------------------------------------------------------------
 
 
@@ -314,7 +369,18 @@ def init_params(key, cfg):
             "attn_norm": jnp.ones((cfg.d_model,), cfg.dtype),
             "mlp_norm": jnp.ones((cfg.d_model,), cfg.dtype),
         }
-        if cfg.mla is None:
+        if cfg.layer_conv(i):
+            # the short-convolution mixer: in-projection to [B ; C ; x~],
+            # the taps, the out-projection
+            layer.update({
+                "conv_in": dense(ks[0], (cfg.d_model, 3 * cfg.d_model),
+                                 cfg.d_model),
+                "conv_w": dense(ks[1], (cfg.conv_len, cfg.d_model),
+                                cfg.conv_len),
+                "conv_out": dense(ks[2], (cfg.d_model, cfg.d_model),
+                                  cfg.d_model),
+            })
+        elif cfg.mla is None:
             layer.update({
                 "wq": dense(ks[0], (cfg.d_model, cfg.n_heads * hd),
                             cfg.d_model),
@@ -334,12 +400,15 @@ def init_params(key, cfg):
         if not cfg.plain:
             layer.update(_init_block_extras(kl, cfg, i, dense))
         layers.append(layer)
-    return {
+    params = {
         "embed": dense(k_embed, (cfg.vocab, cfg.d_model), cfg.d_model),
         "layers": layers,
         "norm": jnp.ones((cfg.d_model,), cfg.dtype),
-        "lm_head": dense(k_out, (cfg.d_model, cfg.vocab), cfg.d_model),
     }
+    if not cfg.tie_embed:
+        params["lm_head"] = dense(k_out, (cfg.d_model, cfg.vocab),
+                                  cfg.d_model)
+    return params
 
 
 def _init_block_extras(key, cfg, i, dense):
@@ -355,6 +424,7 @@ def _init_block_extras(key, cfg, i, dense):
                 ).astype(cfg.dtype)
 
     out = {}
+    attends = not cfg.layer_conv(i)
     if cfg.mla is not None:
         # the latent kind's projections in the GQA ones' place; the
         # per-head up-projections of keys and values are two leaves, as
@@ -371,9 +441,9 @@ def _init_block_extras(key, cfg, i, dense):
             "w_uv": dense(km[6], (nh, m.kv_lora, m.d_v), m.kv_lora),
             "wo": dense(km[7], (nh * m.d_v, d), nh * m.d_v),
         })
-    if cfg.qk_norm:
+    if cfg.qk_norm and attends:
         out["q_norm"], out["k_norm"] = gain(ks[0], hd), gain(ks[1], hd)
-    if cfg.attn_gate:
+    if cfg.attn_gate and attends:
         out["wg"] = dense(ks[2], (d, cfg.n_heads * hd), d)
     if cfg.sandwich_norm:
         out["attn_post_norm"] = gain(ks[3], d)
@@ -544,6 +614,36 @@ def _mm(x, w):
     from tpuserver.ops import quant
 
     return quant.matmul(x, w)
+
+
+def _head(params, x, cfg):
+    """Float32 logits of final-normed rows ``x``: through the head, or
+    through the embedding's transpose where the head is tied to it."""
+    if cfg.tie_embed:
+        return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(
+            jnp.float32)
+    return _mm(x, params["lm_head"]).astype(jnp.float32)
+
+
+def _lanes(x, cfg):
+    """``x`` [..., head_dim] in the cache's ``kv_width`` lanes, zeros
+    behind (itself where the two agree)."""
+    extra = cfg.kv_width - x.shape[-1]
+    if not extra:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
+def _lanes_cut(out, cfg):
+    """An attention output over padded lanes back to ``head_dim``."""
+    return out if cfg.kv_width == cfg.head_dim else out[..., :cfg.head_dim]
+
+
+def _lanes_scale(cfg):
+    """The score scale of a query padded to ``kv_width`` lanes: its own
+    head size's, or None (the kernel's default) where nothing is
+    padded."""
+    return None if cfg.kv_width == cfg.head_dim else cfg.head_dim ** -0.5
 
 
 def _embed_rows(params, tokens, cfg=None):
@@ -746,7 +846,7 @@ def _route(params, x, m):
     _, chosen = lax.top_k(choice, m.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=1)
     if m.route_norm:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + m.route_eps)
     return chosen, w * m.route_scale
 
 
@@ -837,6 +937,12 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
     nh = n_heads if n_heads is not None else cfg.n_heads
     nkv = n_kv_heads if n_kv_heads is not None else cfg.n_kv_heads
     red = reduce if reduce is not None else (lambda y: y)
+    if cfg.layer_conv(layer):
+        # the short-convolution mixer: the closure gets the normed rows
+        # and holds the window (zeros before a sequence's start, a
+        # slot's saved rows in decode)
+        return _block_ffn(params, _block_conv(params, x, cfg, attn_fn),
+                          cfg, red, layer, live, moe_stats)
     if cfg.mla is not None:
         # the latent kind: the closure gets the query, the cache row of
         # the step's own tokens in the keys' place and no values (it
@@ -870,6 +976,47 @@ def _block(params, x, positions, cfg, attn_fn, n_heads=None, n_kv_heads=None,
             out = _rms_norm(out, params["attn_post_norm"], cfg.norm_eps)
         x = x + out
     return _block_ffn(params, x, cfg, red, layer, live, moe_stats)
+
+
+def conv_mix(bcx, prev, taps):
+    """The short convolution between a conv mixer's projections, over T
+    new rows of one or more sequences: ``bcx`` [B, T, 3D] is
+    ``[B ; C ; x~]``, ``prev`` [B, L-1, D] the rows of ``u`` before them
+    (zeros before a sequence's start), ``taps`` [L, D].  ``u = B * x~``,
+    ``z_t = sum_j taps_j u_{t-L+1+j}`` (causal, depthwise).  Returns
+    ``(C * z [B, T, D], rows [B, L-1+T, D])``: ``rows`` is ``prev`` then
+    ``u``, so the window after the first ``n`` new rows is ``rows[:,
+    n:n+L-1]``.  Float32 inside, rounded once."""
+    f32 = jnp.float32
+    b, c, xt = jnp.split(bcx, 3, axis=-1)
+    u = (b.astype(f32) * xt.astype(f32)).astype(bcx.dtype)
+    rows = jnp.concatenate([prev.astype(u.dtype), u], axis=1)
+    t, taps = u.shape[1], taps.astype(f32)
+    z = rows[:, :t].astype(f32) * taps[0]
+    for j in range(1, taps.shape[0]):
+        z = z + rows[:, j:j + t].astype(f32) * taps[j]
+    return (c.astype(f32) * z).astype(bcx.dtype), rows
+
+
+def _block_conv(params, x, cfg, conv_fn):
+    """The mixer half of :func:`_block` in a conv layer: x + Mix(N(x)),
+    ``Mix(y) = W_out (C * z)`` with ``[B ; C ; x~] = W_in y`` and z the
+    short convolution of ``u = B * x~`` (:func:`conv_mix`).  What the
+    convolution reaches behind the new rows is the caller's:
+    ``conv_fn(h)`` of the normed rows returns ``C * z`` and keeps the
+    window (:func:`_conv_in`, then :func:`conv_mix`)."""
+    with jax.named_scope("conv.in_proj"):
+        h = _rms_norm(x, params["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("conv.window"):
+        y = conv_fn(h)
+    with jax.named_scope("conv.out_proj"):
+        return x + _mm(y, params["conv_out"])
+
+
+def _conv_in(params, h):
+    """The in-projection of normed rows h to ``[B ; C ; x~]``."""
+    with jax.named_scope("conv.in_proj"):
+        return _mm(h, params["conv_in"])
 
 
 def _block_latent_attn(params, x, positions, cfg, attn_fn, nh, red):
@@ -946,14 +1093,19 @@ def forward(params, tokens, cfg):
             q, _expand_kv(k, n_rep), _expand_kv(v, n_rep), causal=True
         )
 
+    def conv_fn(h, layer):
+        # zeros before the sequence's start, then the prompt
+        prev = jnp.zeros((B, cfg.conv_len - 1, cfg.d_model), h.dtype)
+        return conv_mix(_conv_in(layer, h), prev, layer["conv_w"])[0]
+
     x = _embed_rows(params, tokens, cfg)
     for i, layer in enumerate(params["layers"]):
-        x = _block(layer, x, positions, cfg,
-                   functools.partial(attn_fn, window=cfg.layer_window(i),
-                                     layer=layer),
-                   layer=i)
+        fn = (functools.partial(conv_fn, layer=layer) if cfg.layer_conv(i)
+              else functools.partial(attn_fn, window=cfg.layer_window(i),
+                                     layer=layer))
+        x = _block(layer, x, positions, cfg, fn, layer=i)
     x = _rms_norm(x, params["norm"], cfg.norm_eps)
-    return _mm(x, params["lm_head"]).astype(jnp.float32)
+    return _head(params, x, cfg)
 
 
 def sharded_forward(mesh, cfg):
@@ -1082,16 +1234,31 @@ def make_train_step(mesh, cfg, learning_rate=3e-4):
 
 def init_kv_cache(cfg, batch, max_seq, dtype=None):
     """The contiguous cache, by what a token's row is: K and V of every
-    KV head, [n_layers, 2, B, max_seq, n_kv_heads, head_dim], or under
+    KV head, [n_layers, 2, B, max_seq, n_kv_heads, kv_width], or under
     latent attention ONE latent row (``MLAConfig.row`` lanes), no K/V
-    pair and no head axis, [n_layers, B, max_seq, row]."""
+    pair and no head axis, [n_layers, B, max_seq, row].  With conv
+    layers the K/V layer axis runs over the attention layers alone and
+    the cache is ``{"kv": .., "conv": windows}`` (:func:`init_conv_state`)."""
     dtype = dtype or cfg.dtype
     if cfg.mla is not None:
         return jnp.zeros((cfg.n_layers, batch, max_seq, cfg.mla.row), dtype)
-    return jnp.zeros(
-        (cfg.n_layers, 2, batch, max_seq, cfg.n_kv_heads, cfg.head_dim),
+    kv = jnp.zeros(
+        (len(cfg.attn_layers), 2, batch, max_seq, cfg.n_kv_heads,
+         cfg.kv_width),
         dtype,
     )
+    if cfg.conv_layers:
+        return {"kv": kv, "conv": init_conv_state(cfg, batch, dtype)}
+    return kv
+
+
+def init_conv_state(cfg, rows, dtype=None):
+    """The conv layers' windows: ``[n_conv_layers, rows, conv_len - 1,
+    d_model]`` zeros, the last ``conv_len - 1`` rows of each conv layer's
+    ``u`` a sequence (:func:`conv_mix`).  Fixed in size: no page, no
+    table, whatever a sequence's length."""
+    return jnp.zeros((len(cfg.conv_layers), rows, cfg.conv_len - 1,
+                      cfg.d_model), dtype or cfg.dtype)
 
 
 def decode_crossover_length(max_seq):
@@ -1159,7 +1326,7 @@ def _decode_kernel_block(cfg, max_seq):
 
 
 def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
-                live=None):
+                live=None, conv_end=None):
     """Shared decode/prefill body: run all blocks, writing new K/V into the
     cache at ``write_pos`` and attending over cache[:lengths].
 
@@ -1168,9 +1335,28 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
     layer masks (dense) or skips (flash) what lies behind its window.
     ``live`` [B, T]: rows that are real tokens (:func:`_moe_ffn`).
     Under latent attention the cache is the latent one
-    (:func:`init_kv_cache`) and a layer writes one row a token."""
+    (:func:`init_kv_cache`) and a layer writes one row a token.  With
+    conv layers it is ``{"kv", "conv"}``: a conv layer's convolution
+    reaches back into zeros where the call starts at position 0 and into
+    the cached window otherwise, and the window kept is the one after
+    the first ``conv_end`` rows (default: all of them)."""
     n_rep = cfg.n_heads // cfg.n_kv_heads
-    new_cache = cache
+    conv = bool(cfg.conv_layers)
+    new_cache = cache["kv"] if conv else cache
+    state = cache["conv"] if conv else None
+    # layer -> index on the K/V layer axis, and on the windows' axis
+    kv_at = {n: k for k, n in enumerate(cfg.attn_layers)}
+    conv_at = {n: k for k, n in enumerate(cfg.conv_layers)}
+    conv_end = x.shape[1] if conv_end is None else conv_end
+
+    def conv_fn(h, c, layer):
+        nonlocal state
+        prev = (jnp.zeros_like(state[c]) if isinstance(write_pos, int)
+                and write_pos == 0 else state[c])
+        y, rows = conv_mix(_conv_in(layer, h), prev, layer["conv_w"])
+        state = state.at[c].set(lax.dynamic_slice_in_dim(
+            rows, conv_end, cfg.conv_len - 1, axis=1).astype(state.dtype))
+        return y
 
     def latent_attn_fn(q, latent, _, i, layer):
         """The latent kind: the step's rows land in the cache; a prefill
@@ -1200,23 +1386,25 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
     for i, layer in enumerate(params["layers"]):
         window = cfg.layer_window(i)
 
-        def attn_fn(q, k, v, i=i, window=window):
+        def attn_fn(q, k, v, i=kv_at.get(i), window=window):
             nonlocal new_cache
             with jax.named_scope("attn.kv_write"):
                 new_cache = new_cache.at[i, 0].set(
                     lax.dynamic_update_slice_in_dim(
-                        new_cache[i, 0], k.astype(new_cache.dtype),
+                        new_cache[i, 0], _lanes(k, cfg).astype(
+                            new_cache.dtype),
                         write_pos, axis=1,
                     )
                 )
                 new_cache = new_cache.at[i, 1].set(
                     lax.dynamic_update_slice_in_dim(
-                        new_cache[i, 1], v.astype(new_cache.dtype),
+                        new_cache[i, 1], _lanes(v, cfg).astype(
+                            new_cache.dtype),
                         write_pos, axis=1,
                     )
                 )
             with jax.named_scope("attn.kernel"):
-                max_seq = cache.shape[3]
+                max_seq = new_cache.shape[3]
                 pallas_block = next(
                     (b for b in (256, 128) if max_seq % b == 0), None
                 )
@@ -1239,13 +1427,13 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
                     from tpuserver.ops import decode_attention
 
                     out = decode_attention(
-                        q[:, 0],
+                        _lanes(q[:, 0], cfg),
                         new_cache[i, 0],
                         new_cache[i, 1],
                         jnp.full((q.shape[0],), lengths, jnp.int32),
-                        block_k=pallas_block,
+                        block_k=pallas_block, scale=_lanes_scale(cfg),
                     )
-                    return out[:, None]
+                    return _lanes_cut(out[:, None], cfg)
                 pf_bq, pf_bk = _flash_blocks(q.shape[1], cfg)
                 if (
                     cfg.attn_impl == "pallas"
@@ -1269,14 +1457,19 @@ def _run_cached(params, cache, x, positions, write_pos, lengths, cfg,
                         window=window or None,
                         block_causal=cfg.block_len or None,
                     )
-                return _attend_cached(
-                    q, new_cache[i, 0], new_cache[i, 1], positions, lengths,
-                    n_rep, window=window, block=cfg.block_len,
-                )
+                return _lanes_cut(_attend_cached(
+                    _lanes(q, cfg), new_cache[i, 0], new_cache[i, 1],
+                    positions, lengths, n_rep, window=window,
+                    block=cfg.block_len, scale=_lanes_scale(cfg),
+                ), cfg)
 
         if cfg.mla is not None:
             attn_fn = functools.partial(latent_attn_fn, i=i, layer=layer)
+        if cfg.layer_conv(i):
+            attn_fn = functools.partial(conv_fn, c=conv_at[i], layer=layer)
         x = _block(layer, x, positions, cfg, attn_fn, layer=i, live=live)
+    if conv:
+        return x, {"kv": new_cache, "conv": state}
     return x, new_cache
 
 
@@ -1329,7 +1522,7 @@ def decode_step(params, cache, tokens, pos, cfg):
     )
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
-        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, x[:, 0, :], cfg)
     return logits, new_cache
 
 
@@ -1345,7 +1538,7 @@ def prefill(params, cache, tokens, cfg):
     x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
-        logits = _mm(x[:, -1, :], params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, x[:, -1, :], cfg)
     return logits, new_cache
 
 
@@ -1422,14 +1615,15 @@ def prefill_to_length(params, cache, tokens, true_len, cfg):
     B, T = tokens.shape
     positions = jnp.tile(jnp.arange(T)[None, :], (B, 1))
     x = _embed_rows(params, tokens, cfg)
-    # padding rows route nowhere: only a routed layer reads this
+    # padding rows route nowhere: only a routed layer reads this; a
+    # conv layer keeps the window at true_len, never at the bucket's end
     live = positions < true_len if cfg.ffn_types else None
     x, new_cache = _run_cached(params, cache, x, positions, 0, T, cfg,
-                               live=live)
+                               live=live, conv_end=true_len)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
         last = lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)[:, 0]
-        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, last, cfg)
     return logits, new_cache
 
 
@@ -1453,10 +1647,11 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
     identical to ``decode_step``'s, which is what makes greedy tokens
     from N interleaved slots equal to N sequential single-stream runs.
     """
-    if cfg.mla is not None:
+    if cfg.mla is not None or cfg.conv_layers or cfg.kv_lanes:
         raise UnsupportedArchitecture(
-            "the slotted decode step reads K and V rows; latent attention "
-            "is served over the paged pool (paged_batched_decode_step)")
+            "the slotted decode step reads K and V rows of every layer; "
+            "latent attention and conv layers are served over the paged "
+            "pool (paged_batched_decode_step)")
     S = tokens.shape[0]
     max_seq = cache.shape[3]
     q_pos = positions[:, None]  # [S, 1]
@@ -1507,7 +1702,7 @@ def batched_decode_step(params, cache, tokens, positions, cfg):
         x = _block(layer, x, q_pos, cfg, attn_fn, layer=i, live=live)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
-        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, x[:, 0, :], cfg)
     return logits, new_cache
 
 
@@ -1566,19 +1761,20 @@ def scheduler_extract(cache, slot):
 def init_paged_kv_cache(cfg, n_pages, page_size, dtype=None):
     """The page pool — the paged form of :func:`init_kv_cache`, one
     page class by what a token's row is: a K/V class
-    [n_layers, 2, n_pages, page_size, n_kv_heads, head_dim], or under
+    [n_layers, 2, n_pages, page_size, n_kv_heads, kv_width], or under
     latent attention a latent class [n_layers, n_pages, page_size, row]
     (one ``[c_kv ; k_pe ; padding]`` row a token, no K/V pair, no head
     axis).  A sequence's rows live scattered across pages named by its
     page table; page id ``n_pages`` is the out-of-bounds scatter
-    sentinel (writes drop)."""
+    sentinel (writes drop).  The K/V layer axis runs over the attention
+    layers alone: a conv layer holds no pages."""
     dtype = dtype or cfg.dtype
     if cfg.mla is not None:
         return jnp.zeros((cfg.n_layers, n_pages, page_size, cfg.mla.row),
                          dtype)
     return jnp.zeros(
-        (cfg.n_layers, 2, n_pages, page_size, cfg.n_kv_heads,
-         cfg.head_dim),
+        (len(cfg.attn_layers), 2, n_pages, page_size, cfg.n_kv_heads,
+         cfg.kv_width),
         dtype,
     )
 
@@ -1655,7 +1851,9 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
 
     ``pages`` is the pool from :func:`init_paged_kv_cache` (a K/V class,
     or a latent class, whose layers attend through ``latent_attn_fn``
-    below); ``page_tables`` [S, pages_per_seq] int32 maps each row's logical
+    below), or with conv layers ``{"kv": pool, "conv": windows}``
+    (:func:`init_conv_state`, a row a slot: a live row's windows move
+    on by one row a step, the rest keep theirs); ``page_tables`` [S, pages_per_seq] int32 maps each row's logical
     pages to physical ids (entries may be the sentinel ``n_pages`` for
     unreserved logical pages — they are never read below the row's
     valid length and never written).
@@ -1681,6 +1879,10 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     step's out-of-bounds rows.
     """
     S = tokens.shape[0]
+    # conv layers: the pool beside the windows, {"kv", "conv"}
+    conv = bool(cfg.conv_layers)
+    state = pages["conv"] if conv else None
+    pages = pages["kv"] if conv else pages
     # one page class (the pool is one array, the plain configurations)
     # or two ({"full", "window"} pools and tables: window layers)
     classes = isinstance(pages, dict)
@@ -1725,6 +1927,20 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
     stats = [] if cfg.ffn_types else None
     slot_of = {i: ("window", n) for n, i in enumerate(cfg.window_layers)}
     slot_of.update((i, ("full", n)) for n, i in enumerate(cfg.full_layers))
+    conv_at = {n: k for k, n in enumerate(cfg.conv_layers)}
+    # rows that hold a request: only theirs move their windows on
+    row_live = positions < max_seq if conv else None
+
+    def conv_fn(h, c, layer):
+        """A conv layer: the short convolution over each row's saved
+        window and its new ``u``; a live row's window moves on by that
+        ``u``, every other row's stays as it was."""
+        nonlocal state
+        y, rows = conv_mix(_conv_in(layer, h), state[c], layer["conv_w"])
+        state = state.at[c].set(jnp.where(
+            row_live[:, None, None], rows[:, 1:].astype(state.dtype),
+            state[c]))
+        return y
 
     def latent_attn_fn(q, latent, _, i, layer):
         """The latent class: the row's new latent lands in its page,
@@ -1756,10 +1972,10 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
             pool, at = pools[cls], write[cls]
             with jax.named_scope("attn.kv_write"):
                 pool = pool.at[li, 0, at, offs].set(
-                    k[:, 0].astype(pool.dtype), mode="drop"
+                    _lanes(k[:, 0], cfg).astype(pool.dtype), mode="drop"
                 )
                 pool = pool.at[li, 1, at, offs].set(
-                    v[:, 0].astype(pool.dtype), mode="drop"
+                    _lanes(v[:, 0], cfg).astype(pool.dtype), mode="drop"
                 )
                 pools[cls] = pool
             if path == "paged_kernel":
@@ -1768,11 +1984,12 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
                     from tpuserver.ops import paged_decode_attention
 
                     out = paged_decode_attention(
-                        q[:, 0], pool, li, tbl[cls],
+                        _lanes(q[:, 0], cfg), pool, li, tbl[cls],
                         lengths.astype(jnp.int32), block_k=pallas_block,
                         starts=starts if cls == "window" else None,
+                        scale=_lanes_scale(cfg),
                     )
-                    return out[:, None]
+                    return _lanes_cut(out[:, None], cfg)
             with jax.named_scope("attn.page_gather"):
                 tail = pool.shape[4:]
                 k_seq = pool[li, 0][tbl[cls]].reshape(S, max_seq, *tail)
@@ -1784,21 +2001,27 @@ def paged_batched_decode_step(params, pages, tokens, page_tables,
                     from tpuserver.ops import decode_attention
 
                     out = decode_attention(
-                        q[:, 0], k_seq, v_seq, lengths.astype(jnp.int32),
-                        block_k=pallas_block,
+                        _lanes(q[:, 0], cfg), k_seq, v_seq,
+                        lengths.astype(jnp.int32), block_k=pallas_block,
+                        scale=_lanes_scale(cfg),
                     )
-                    return out[:, None]
-                return _attend_cached(
-                    q, k_seq, v_seq, q_pos, lengths, n_rep)
+                    return _lanes_cut(out[:, None], cfg)
+                return _lanes_cut(_attend_cached(
+                    _lanes(q, cfg), k_seq, v_seq, q_pos, lengths, n_rep,
+                    scale=_lanes_scale(cfg)), cfg)
 
         if cfg.mla is not None:
             attn_fn = functools.partial(latent_attn_fn, i=i, layer=layer)
+        if cfg.layer_conv(i):
+            attn_fn = functools.partial(conv_fn, c=conv_at[i], layer=layer)
         x = _block(layer, x, q_pos, cfg, attn_fn, layer=i, live=live,
                    moe_stats=stats)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
-        logits = _mm(x[:, 0, :], params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, x[:, 0, :], cfg)
     new_pages = pools if classes else pools["full"]
+    if conv:
+        new_pages = {"kv": new_pages, "conv": state}
     if stats is not None:
         # what the routed layers did this step, for the host's counters:
         # [layer-steps, pairs held here, distinct held experts hit]
@@ -2016,7 +2239,7 @@ def paged_block_step(params, pages, state, page_tables, steps, taus,
                    moe_stats=stats)
     with jax.named_scope("head"):
         x = _rms_norm(x[:tokens.shape[0]], params["norm"], cfg.norm_eps)
-        logits = _mm(x, params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, x, cfg)
     with jax.named_scope("diffusion.unmask"):
         x0, logc, newly = unmask_block(
             logits, masked, n_pass, steps, taus, cfg.mask_id)
@@ -2077,6 +2300,21 @@ def paged_admit(pages, logits_all, slot_cache, slot_logits, dest_ids,
         src.astype(pages.dtype), mode="drop"
     ).reshape(shape)
     return pages, _admit_logits(logits_all, slot_logits, slot)
+
+
+def paged_admit_conv(pages, logits_all, slot_cache, slot_logits, dest_ids,
+                     slot):
+    """:func:`paged_admit` beside the conv layers' windows: ``pages``
+    and ``slot_cache`` are ``{"kv", "conv"}``; the K/V rows scatter to
+    their pages and the prefill's windows [n_conv, 1, L-1, D] replace
+    the WHOLE of slot ``slot``'s, so that a reused slot never keeps
+    anything of the sequence it held before."""
+    kv, logits_all = paged_admit(pages["kv"], logits_all, slot_cache["kv"],
+                                 slot_logits, dest_ids, slot)
+    state = lax.dynamic_update_slice_in_dim(
+        pages["conv"], slot_cache["conv"].astype(pages["conv"].dtype), slot,
+        axis=1)
+    return {"kv": kv, "conv": state}, logits_all
 
 
 def _admit_logits(logits_all, slot_logits, slot):
@@ -2154,6 +2392,10 @@ def prefill_span(params, cache, tokens, start, logits_at, cfg):
     Returns the logits at chunk-relative index ``logits_at`` (only
     meaningful on the span containing the prompt's last token) and
     the updated cache."""
+    if cfg.conv_layers:
+        raise UnsupportedArchitecture(
+            "a span prefill does not carry the conv layers' windows from "
+            "one span to the next: prompts with conv layers prefill whole")
     B, T = tokens.shape
     positions = start + jnp.tile(jnp.arange(T)[None, :], (B, 1))
     x = _embed_rows(params, tokens, cfg)
@@ -2163,7 +2405,7 @@ def prefill_span(params, cache, tokens, start, logits_at, cfg):
     with jax.named_scope("head"):
         x = _rms_norm(x, params["norm"], cfg.norm_eps)
         last = lax.dynamic_slice_in_dim(x, logits_at, 1, axis=1)[:, 0]
-        logits = _mm(last, params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, last, cfg)
     return logits, new_cache
 
 
@@ -2237,6 +2479,20 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
     prefixes and chunked prefill do not know blocks yet and the
     scheduler refuses them by name.
 
+    A configuration with conv layers gets, beside a K/V pool over its
+    attention layers alone, the windows of its conv layers
+    (:func:`init_conv_state`, a row a slot); the pool argument and
+    result of ``init_cache``, ``step`` and ``admit`` are then
+    ``{"kv": pool, "conv": windows}``, ``init_slot_cache`` and
+    ``prefill`` carry a row's windows the same way
+    (:func:`paged_admit_conv` writes a slot's whole window), and
+    ``conv_state``, the number of conv layers, says so to the scheduler
+    (absent otherwise).  ``gather`` / ``prefill_span`` are
+    absent and ``span_safe`` is false: park, export and attach copy K/V
+    rows alone, and a span prefill does not carry the window, so the
+    scheduler refuses the former by name and prefills every prompt
+    whole.
+
     A configuration with latent attention (``cfg.mla``) gets a latent
     page class under the same keys (``init_cache``, ``init_slot_cache``,
     ``step``, ``admit``); ``latent_class`` ``{"width", "row"}`` says so
@@ -2291,6 +2547,12 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             "generation over blocks of {} needs full attention layers, "
             "and a page_size ({}) and max_seq ({}) of whole blocks".format(
                 cfg.block_len, page_size, max_seq))
+    if cfg.conv_layers and (cfg.mla is not None or cfg.window_layers
+                            or cfg.block_len):
+        raise UnsupportedArchitecture(
+            "conv layers are served beside one page class of full "
+            "causal attention layers: no window layers, latent "
+            "attention or generation over blocks")
     if cfg.window_layers:
         ring = window_ring_pages(cfg, max_seq, page_size)
         n_wpages = (int(kv_window_pages) if kv_window_pages is not None
@@ -2354,6 +2616,17 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
             # prefills against cached rows is left out of the bundle
             gather = prefill_span_fn = None
 
+        if cfg.conv_layers:
+            # the windows ride beside the pool through step and admit;
+            # what copies K/V rows alone, or prefills a span without the
+            # window before it, is left out of the bundle
+            gather = prefill_span_fn = None
+            admit = jax.jit(paged_admit_conv, donate_argnums=(0, 1))
+
+            def init_cache():  # noqa: F811
+                return {"kv": init_paged_kv_cache(cfg, n_pages, page_size),
+                        "conv": init_conv_state(cfg, max_slots)}
+
     else:
         param_sh, cache_sh, repl = serving_shardings(
             mesh, cfg, quantized=quantized
@@ -2414,13 +2687,15 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "pages_per_seq": pages_per_seq,
         "n_pages": n_pages,
         "span_safe": (cfg.attn_impl != "pallas" and window_class is None
-                      and not cfg.block_len and cfg.mla is None),
+                      and not cfg.block_len and cfg.mla is None
+                      and not cfg.conv_layers),
         "block_len": cfg.block_len,
         "mask_id": cfg.mask_id,
         "decode_attention": paged_decode_path(cfg, max_seq, page_size)[0],
         "window_class": window_class,
         "latent_class": (None if cfg.mla is None else
                          {"width": cfg.mla.width, "row": cfg.mla.row}),
+        "conv_state": len(cfg.conv_layers) or None,
     }
     # what a two-class pool cannot serve is absent, not None
     return {k: v for k, v in fns.items()

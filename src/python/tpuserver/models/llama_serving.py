@@ -94,6 +94,12 @@ class LlamaGenerateModel(Model):
                  target_queue_ms=None, shed_interval_ms=100.0,
                  params=None, kv_window_pages=None):
         self._cfg = cfg or llama.tiny(vocab=2048)
+        if self._cfg.conv_layers and max_slots < 2:
+            # the single-stream path parks and resumes K/V rows alone
+            raise llama.UnsupportedArchitecture(
+                "conv layers are served by the continuous-batching "
+                "scheduler, whose pool carries their windows: max_slots "
+                "must be > 1")
         if self._cfg.block_len:
             if max_slots < 2:
                 raise llama.UnsupportedArchitecture(

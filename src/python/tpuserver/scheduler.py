@@ -698,6 +698,15 @@ class DecodeScheduler:
         # llama.make_scheduler_fns "latent_class"): what copies K/V rows
         # out of the pool is refused by name in submit
         self._latent_class = (fns or {}).get("latent_class")
+        # the conv layers' windows beside the pool (llama.make_scheduler_fns
+        # "conv_state"): what copies K/V rows alone is refused by name in
+        # submit
+        self._conv_state = (fns or {}).get("conv_state")
+        # the bytes of windows the steps' rows read, and the windows
+        # admissions wrote (loop-written, grow-only; 0 without conv
+        # layers)
+        self._state_bytes = 0
+        self._state_writes = 0
         # park-attach KV export hooks (tentpole 3 of ISSUE 12): a
         # disconnected resumable stream's gathered pages are handed to
         # ``kv_export(generation_id, cache, valid_pos)`` (the server
@@ -759,6 +768,11 @@ class DecodeScheduler:
                 "{} is not served over a latent page class (latent "
                 "attention): it copies K and V rows, and a latent row is "
                 "neither".format(what))
+        if self._conv_state:
+            return UnsupportedArchitecture(
+                "{} is not served for a configuration with conv layers: "
+                "it copies K and V rows, and a sequence's conv windows "
+                "would be left behind".format(what))
         return UnsupportedArchitecture(
             "{} is not served over a pool of two page classes (window "
             "layers): it assumes one page table a sequence".format(what))
@@ -828,7 +842,8 @@ class DecodeScheduler:
                 "denoising_steps must lie in 1..{} (got {}) and "
                 "confidence_threshold in 0..1 (got {})".format(
                     blk, steps, tau))
-        if self._window_class or blk or self._latent_class:
+        if (self._window_class or blk or self._latent_class
+                or self._conv_state):
             for asked, what in (
                     (resume_cache is not None or on_finish is not None,
                      "park / resume of a KV cache (kv_cache_region)"),
@@ -1221,6 +1236,8 @@ class DecodeScheduler:
                     self._diffusion_commit_passes
                     + self._diffusion_fused_commits,
                 "control_uploads": self._control_uploads,
+                "state_bytes": self._state_bytes,
+                "state_writes": self._state_writes,
                 "loop_seconds": dict(self._loop_seconds),
                 "loop_offcpu_seconds": dict(self._loop_offcpu_seconds),
                 # a fact of the build, not a rate: which decode
@@ -1529,6 +1546,13 @@ class DecodeScheduler:
         alloc = PageAllocator(n_pages, page)
         radix = (RadixPrefixCache(page)
                  if self._prefix_cache and span_safe else None)
+        # conv layers: their windows ride beside the pool
+        # ({"kv", "conv"}); the bytes a row's windows hold, as the array
+        # stores them, or 0
+        conv = self._conv_state
+        kv = pages["kv"] if conv else pages
+        state_row_bytes = (int(pages["conv"].nbytes) // self._max_slots
+                           if conv else 0)
         # the window class of a two-class pool: its own allocator and
         # ring tables (entry p % ring names logical page p); wc is None
         # for every one-class configuration, whose loop is unchanged
@@ -1541,7 +1565,7 @@ class DecodeScheduler:
             n_layers_all = n_layers_w + int(pages["full"].shape[0])
         else:
             alloc_w = None
-            n_layers_all = int(getattr(pages, "shape", (0,))[0])
+            n_layers_all = int(getattr(kv, "shape", (0,))[0])
         # bytes one cached token holds over all attention layers, each
         # layer's in its page class as the pool's array stores it
         # (padding included; 0 for a test double's pool)
@@ -1549,7 +1573,7 @@ class DecodeScheduler:
             int(getattr(pool, "nbytes", 0)) // (count * page)
             for pool, count in (
                 ((pages["full"], n_pages), (pages["window"], n_wpages))
-                if wc else ((pages, n_pages),)))
+                if wc else ((kv, n_pages),)))
         with self._cond:
             # stats/gauges read the live pool through this reference;
             # a supervised restart rebuilds pool, allocator and radix
@@ -1713,6 +1737,8 @@ class DecodeScheduler:
                 tables_w[slot] = stream.table_w
             ready[slot] = True
             self._admitted_total += 1
+            if conv:
+                self._state_writes += 1
             if self._queue_hist is not None:
                 self._queue_hist.observe(
                     time.monotonic() - stream.enqueued_at)
@@ -2281,6 +2307,10 @@ class DecodeScheduler:
                     self._context_bytes += context * token_bytes
                     if wc:
                         self._window_skipped_tokens += skipped * n_layers_w
+                    if conv:
+                        # every live row reads its windows, whole
+                        self._state_bytes += (
+                            len(active_ids) * state_row_bytes)
                     # chaos hook: "scheduler.step" raise = loop death (the
                     # supervised-restart path), sleep = slow step, nan =
                     # poison one slot's logits row (the quarantine path),
