@@ -1192,6 +1192,9 @@ class InferenceServer:
             # the conv layers' windows (0 for a model without them)
             "tpu_scheduler_state_bytes_total": "state_bytes",
             "tpu_scheduler_state_writes_total": "state_writes",
+            # the flash prefill kernel's block pairs (0 on dense prefill)
+            "tpu_scheduler_flash_pairs_total": "flash_pairs",
+            "tpu_scheduler_flash_grid_pairs_total": "flash_grid_pairs",
         }
         samples = {name: [] for name in per_family}
         # the decode loop's seconds by phase, wall and off the CPU: the

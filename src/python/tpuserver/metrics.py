@@ -260,6 +260,19 @@ CATALOG = {
         "Rows of fixed-size per-sequence state (the conv layers' "
         "windows) that admissions wrote whole, one an admission, per "
         "model; 0 for a model without conv layers."),
+    "tpu_scheduler_flash_pairs_total": (
+        "counter",
+        "(query block, key block) pairs the flash prefill kernel folded "
+        "in the whole-prompt prefills dispatched, summed over attention "
+        "layers and query heads, per model (host side, from the "
+        "kernel's schedule at the prefill's padded length); 0 where "
+        "prefills attend densely."),
+    "tpu_scheduler_flash_grid_pairs_total": (
+        "counter",
+        "The pairs a square grid over the same tiles would have stepped "
+        "through in those prefills, per model.  Under it, "
+        "tpu_scheduler_flash_pairs_total is the share of grid steps the "
+        "causal (window, block-diagonal) schedule kept."),
     # -- routed (mixture-of-experts) layers --------------------------------
     "tpu_moe_layer_steps_total": (
         "counter",

@@ -67,11 +67,14 @@ class LlamaConfig:
     # collapsed long-context decode 3x — see bench_prefill_sweep.)
     decode_impl: str = "auto"
     # flash-kernel tile sizes (prefill): preferred tiles, tuned on v5e
-    # via tools/bench_prefill_sweep.py (256x512 = 55% MFU on the 3B at
-    # T=2048 vs 44% at 128x128); prompts not divisible by these fall
-    # back to 128-tiles, then to the dense path (_flash_blocks)
-    flash_block_q: int = 256
-    flash_block_k: int = 512
+    # via tools/bench_kernels.py once the kernel stepped through
+    # live block pairs alone: 512x1024 read 40% of the bf16 peak at
+    # T=2048 (32 heads of 128) against 26% at 256x512, and 56-64% at
+    # T=4096-8192 (48 heads of 128, 128 of 192/128) against 30-34%;
+    # prompts not divisible by these fall back to smaller tiles, then
+    # to the dense path (_flash_blocks)
+    flash_block_q: int = 512
+    flash_block_k: int = 1024
     # -- the block as data.  The defaults are the Llama / Mistral block:
     # rotary positions on every layer, full causal attention, two norms
     # a layer, a dense SwiGLU.  Everything below is read at trace time.
@@ -585,18 +588,44 @@ def quantize_params(params, quantize_embed=False):
 
 def _flash_blocks(T, cfg):
     """Largest usable (block_q, block_k) for a length-T flash prefill:
-    the preferred (tuned) tile when T divides by it, else 128-tiles,
-    else None (caller falls back to dense attention)."""
+    the preferred (tuned) tile when T divides by it, else the largest
+    smaller one that does (down to 128), else None (caller falls back
+    to dense attention).  Key heads under 128 wide and block diagonals
+    take at most 256 x 512, the tile their models' outputs were held to
+    the references at (PERF.md, open question 22)."""
+    pref_q, pref_k = cfg.flash_block_q, cfg.flash_block_k
+    width = cfg.mla.d_qk if cfg.mla is not None else cfg.head_dim
+    if width < 128 or cfg.block_len:
+        pref_q, pref_k = min(pref_q, 256), min(pref_k, 512)
     bq = next(
-        (b for b in (cfg.flash_block_q, 128) if b <= T and T % b == 0),
+        (b for b in (pref_q, 256, 128) if b <= T and T % b == 0),
         None,
     )
     bk = next(
-        (b for b in (cfg.flash_block_k, 256, 128)
-         if b <= T and T % b == 0),
+        (b for b in (pref_k, 512, 256, 128) if b <= T and T % b == 0),
         None,
     )
     return bq, bk
+
+
+def flash_prefill_pairs(cfg, T):
+    """``(pairs, grid_pairs)`` of a whole-prompt prefill of T positions:
+    the (q-block, k-block) pairs the flash kernel folds
+    (``ops.flash.flash_schedule`` at the tiles ``_flash_blocks`` gives
+    T), and those a square grid over the same tiles would step through,
+    summed over the attention layers and the query heads.  ``(0, 0)``
+    where such a prefill attends on the dense path."""
+    bq, bk = _flash_blocks(T, cfg)
+    if cfg.attn_impl != "pallas" or T < 2 or bq is None or bk is None:
+        return 0, 0
+    from tpuserver.ops.flash import flash_schedule
+
+    pairs = sum(
+        len(flash_schedule(T, T, bq, bk, True, cfg.layer_window(i) or None,
+                           cfg.block_len or None)[0])
+        for i in cfg.attn_layers)
+    grid = len(cfg.attn_layers) * (T // bq) * (T // bk)
+    return pairs * cfg.n_heads, grid * cfg.n_heads
 
 
 def named_partial(fn, **kwargs):
@@ -2680,6 +2709,7 @@ def make_scheduler_fns(cfg, max_seq, max_slots, mesh=None, quantized=False,
         "prefill": prefill_fn,
         "prefill_span": prefill_span_fn,
         "prefill_bucket": functools.partial(prefill_bucket, cfg, max_seq),
+        "flash_pairs": functools.partial(flash_prefill_pairs, cfg),
         "step": step,
         "admit": admit,
         "gather": gather,

@@ -1,14 +1,15 @@
 """Pallas flash-attention forward kernel for TPU.
 
 The hot op of the llama serving/training paths, hand-tiled for the MXU:
-the grid walks (batch*heads, query blocks, K/V blocks) with the K/V
-block dimension innermost, so VMEM only ever holds one [block_q, D]
-query tile and one [block_k, D] K/V tile — sequence length is bounded
-by HBM, not VMEM.  The online-softmax state (running max, normalizer,
-output accumulator) lives in VMEM scratch carried across the K/V grid
-steps; accumulation is fp32 (MXU-native via preferred_element_type)
-regardless of input dtype, and causal query blocks skip fully-masked
-K/V blocks via predication.
+the grid walks (batch*heads, live block pair), the pairs being the
+(query block, K/V block) pairs in which the mask leaves a score
+(:func:`flash_schedule`, a scalar-prefetched table), so VMEM only ever
+holds one [block_q, D] query tile and one [block_k, D] K/V tile —
+sequence length is bounded by HBM, not VMEM — and a K/V block wholly
+masked for a query block is never fetched.  The online-softmax state
+(running max, normalizer, output accumulator) lives in VMEM scratch
+carried across a query block's pairs; accumulation is fp32 (MXU-native
+via preferred_element_type) regardless of input dtype.
 
 The decode kernels (``decode_attention`` over a padded cache,
 ``paged_decode_attention`` over the page pool read in place) answer one
@@ -32,6 +33,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -94,48 +96,81 @@ def _fold_finish(o_ref, m_scr, l_scr, acc_scr):
     o_ref[:] = (acc_scr[:] / l[:, None]).astype(o_ref.dtype)
 
 
+# the flags of a (q-block, k-block) pair in :func:`flash_schedule`'s
+# table: the q-block's first pair (start its softmax state), its last
+# (normalize into the output block), and a block some score of which is
+# masked (only there is the mask built)
+FIRST, LAST, PARTIAL = 1, 2, 4
+
+
+@functools.lru_cache(maxsize=None)
+def flash_schedule(t, t_kv, block_q, block_k, causal=True, window=None,
+                   block_causal=None):
+    """The (q-block, k-block) pairs in which the mask leaves a score,
+    in the order :func:`flash_attention` folds them: q-blocks in order,
+    and within one its k-blocks ascending.
+
+    Query i sees key j iff ``lo(i) <= j <= hi(i)``: ``hi`` is ``i``
+    under ``causal`` (the end of i's block of ``block_causal``
+    positions under a block diagonal), else the last key; ``lo`` is
+    ``i - window + 1`` under a window, else 0.  Returns three read-only
+    int32 arrays of one entry a pair, ``(q_blocks, k_blocks, flags)``,
+    ``flags`` holding :data:`FIRST`, :data:`LAST` and :data:`PARTIAL`
+    bits.  Pure host arithmetic, cached by its arguments."""
+    nq, nk = t // block_q, t_kv // block_k
+    rows = np.arange(t)
+    if not causal:
+        hi = np.full(t, t_kv - 1)
+    elif block_causal:
+        hi = (rows // block_causal + 1) * block_causal - 1
+    else:
+        hi = rows
+    hi = np.minimum(hi, t_kv - 1).reshape(nq, block_q, 1)
+    lo = (np.maximum(rows - window + 1, 0) if window
+          else np.zeros(t, np.int64)).reshape(nq, block_q, 1)
+    first = np.arange(nk) * block_k
+    last = first + block_k - 1
+    # [nq, nk]: some row of the q-block sees some key of the k-block /
+    # every row sees every key
+    live = (np.maximum(lo, first) <= np.minimum(hi, last)).any(axis=1)
+    whole = ((lo <= first) & (hi >= last)).all(axis=1)
+    if not live.any(axis=1).all():
+        raise ValueError("a query block of {} sees no key of {}".format(
+            t, t_kv))
+    q_blocks, k_blocks = np.nonzero(live)
+    new_q = np.ones(len(q_blocks) + 1, bool)
+    new_q[1:-1] = q_blocks[1:] != q_blocks[:-1]
+    flags = (np.where(new_q[:-1], FIRST, 0) | np.where(new_q[1:], LAST, 0)
+             | np.where(whole[q_blocks, k_blocks], 0, PARTIAL))
+    table = tuple(a.astype(np.int32) for a in (q_blocks, k_blocks, flags))
+    for a in table:
+        a.flags.writeable = False
+    return table
+
+
 def _attn_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, causal,
-    block_q, block_k, window=None, block_causal=None):
-    """One (batch*head, q-block, k-block) program.
+    qb_ref, kb_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+    acc_scr, *, scale, block_q, block_k, window=None, block_causal=None):
+    """One (batch*head, pair) program: pair ``p`` of
+    :func:`flash_schedule`'s table, whose q-block ``qb_ref[p]`` and
+    k-block ``kb_ref[p]`` the index maps brought in.
 
     q_ref: [block_q, D]; k_ref: [block_k, D]; v_ref: [block_k, Dv];
     o_ref: [block_q, Dv]; scratch m/l: [block_q, 1] fp32, acc:
-    [block_q, Dv] fp32 — carried
-    across the (sequential) k-block grid dimension.  With ``window``
-    (causal only) query i sees keys j with 0 <= i - j < window, and K/V
-    blocks wholly behind the window are skipped like those above the
-    diagonal.  With ``block_causal`` = B (causal only) the diagonal is
-    one of blocks of B positions: query i sees key j iff
-    ``j < (i // B + 1) * B``, its own block in both directions.
+    [block_q, Dv] fp32 — carried across the (sequential) pairs of one
+    q-block.  Only a pair flagged :data:`PARTIAL` builds the mask: with
+    ``window`` query i sees keys j with 0 <= i - j < window; with
+    ``block_causal`` = B the diagonal is one of blocks of B positions,
+    query i seeing key j iff ``j < (i // B + 1) * B``.
     """
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    p = pl.program_id(1)
+    flags = flag_ref[p]
 
-    @pl.when(ki == 0)
+    @pl.when((flags & FIRST) != 0)
     def _init():
         _fold_init(m_scr, l_scr, acc_scr)
 
-    def last_seen(q_pos):
-        """The last key position a query at ``q_pos`` sees."""
-        if block_causal is None:
-            return q_pos
-        return (q_pos // block_causal + 1) * block_causal - 1
-
-    # causal: K/V blocks wholly above the diagonal contribute nothing
-    live = (
-        ki * block_k <= last_seen(qi * block_q + (block_q - 1))
-        if causal
-        else True
-    )
-    if window is not None:
-        # the block's last key is still inside the first query's window
-        live = jnp.logical_and(
-            live, (ki + 1) * block_k - 1 > qi * block_q - window)
-
-    @pl.when(live)
-    def _fold():
+    def fold(masked):
         # keep the matmul operands in the INPUT dtype: bf16 x bf16 with
         # fp32 accumulation is the MXU's native full-rate mode — an
         # explicit fp32 upcast before the dot would halve the peak.
@@ -144,22 +179,29 @@ def _attn_kernel(
         k = k_ref[:]
         v = v_ref[:]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        if masked:
+            q_pos = qb_ref[p] * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            k_pos = kb_ref[p] * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            seen = k_pos <= last_seen(q_pos)
+            last_seen = q_pos
+            if block_causal is not None:
+                last_seen = (q_pos // block_causal + 1) * block_causal - 1
+            seen = k_pos <= last_seen
             if window is not None:
                 seen = jnp.logical_and(seen, k_pos > q_pos - window)
             s = jnp.where(seen, s, -jnp.inf)
         _online_softmax_fold(
             s, m_scr, l_scr, acc_scr,
-            lambda p: jnp.dot(
-                p.astype(v.dtype), v,
+            lambda probs: jnp.dot(
+                probs.astype(v.dtype), v,
                 preferred_element_type=jnp.float32))
 
-    @pl.when(ki == nk - 1)
+    partial = (flags & PARTIAL) != 0
+    pl.when(partial)(functools.partial(fold, True))
+    pl.when(jnp.logical_not(partial))(functools.partial(fold, False))
+
+    @pl.when((flags & LAST) != 0)
     def _finish():
         _fold_finish(o_ref, m_scr, l_scr, acc_scr)
 
@@ -180,12 +222,12 @@ def flash_attention(
     ``block_q`` and ``block_k`` (pick smaller blocks for short or odd
     sequences).  ``interpret=None`` defers to :func:`kernel_interpret`.
     ``window`` (causal only): query i attends keys j with
-    0 <= i - j < window; K/V blocks wholly outside a query block's
-    window are neither folded nor fetched (their grid steps point at the
-    nearest live block, which is already resident).
-    ``block_causal`` = B (causal only; generation by diffusion over
-    blocks): query i attends keys j with ``j < (i // B + 1) * B``, every
-    earlier block and its own block whole.
+    0 <= i - j < window.  ``block_causal`` = B (causal only; generation
+    by diffusion over blocks): query i attends keys j with
+    ``j < (i // B + 1) * B``, every earlier block and its own block
+    whole.  The grid steps through :func:`flash_schedule`'s pairs alone:
+    a K/V block wholly masked for a q-block is neither fetched nor
+    folded, and only a block the mask's edge crosses is masked.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -198,46 +240,50 @@ def flash_attention(
         raise ValueError(
             "sequence lengths ({}, {}) must divide by block sizes "
             "({}, {})".format(t, t_kv, block_q, block_k))
+    if (window is not None or block_causal is not None) and not causal:
+        raise ValueError("a window or a block diagonal needs causal "
+                         "attention")
+    if window is not None and block_causal is not None:
+        raise ValueError("no window under a block diagonal")
 
     # [B, T, H, D] -> [B*H, T, D]: one grid row per (batch, head)
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kh = k.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * h, t_kv, d_v)
 
-    if (window is not None or block_causal is not None) and not causal:
-        raise ValueError("a window or a block diagonal needs causal "
-                         "attention")
-    if window is not None and block_causal is not None:
-        raise ValueError("no window under a block diagonal")
+    table = flash_schedule(t, t_kv, block_q, block_k, causal, window,
+                           block_causal)
     kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, window=window, block_causal=block_causal)
+        _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
+        window=window, block_causal=block_causal)
 
-    def kv_index(bh, i, j):
-        if window is None:
-            return (bh, j, 0)
-        first = jnp.maximum(i * block_q - window + 1, 0) // block_k
-        last = (i * block_q + block_q - 1) // block_k
-        return (bh, jnp.clip(j, first, last), 0)
+    def q_index(bh, p, qb_ref, kb_ref, flag_ref):
+        return (bh, qb_ref[p], 0)
 
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, t // block_q, t_kv // block_k),
+    def kv_index(bh, p, qb_ref, kb_ref, flag_ref):
+        return (bh, kb_ref[p], 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(table),
+        grid=(b * h, len(table[0])),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((None, block_q, d), q_index),
             pl.BlockSpec((None, block_k, d), kv_index),
             pl.BlockSpec((None, block_k, d_v), kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (None, block_q, d_v), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype),
+        out_specs=pl.BlockSpec((None, block_q, d_v), q_index),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * h, t, d_v), q.dtype),
         interpret=interpret,
-    )(qh, kh, vh)
+    )(*(jnp.asarray(a) for a in table), qh, kh, vh)
     return out.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
 
 

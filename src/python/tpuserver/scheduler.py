@@ -707,6 +707,13 @@ class DecodeScheduler:
         # layers)
         self._state_bytes = 0
         self._state_writes = 0
+        # what the whole-prompt prefills' flash kernel stepped through:
+        # the block pairs it folds and those a square grid would
+        # (llama.flash_prefill_pairs of the padded length; loop-written,
+        # grow-only, 0 where prefills attend densely)
+        self._flash_pairs_of = (fns or {}).get("flash_pairs")
+        self._flash_pairs = 0
+        self._flash_grid_pairs = 0
         # park-attach KV export hooks (tentpole 3 of ISSUE 12): a
         # disconnected resumable stream's gathered pages are handed to
         # ``kv_export(generation_id, cache, valid_pos)`` (the server
@@ -1238,6 +1245,8 @@ class DecodeScheduler:
                 "control_uploads": self._control_uploads,
                 "state_bytes": self._state_bytes,
                 "state_writes": self._state_writes,
+                "flash_pairs": self._flash_pairs,
+                "flash_grid_pairs": self._flash_grid_pairs,
                 "loop_seconds": dict(self._loop_seconds),
                 "loop_offcpu_seconds": dict(self._loop_offcpu_seconds),
                 # a fact of the build, not a rate: which decode
@@ -1965,6 +1974,10 @@ class DecodeScheduler:
                     # prefill, byte-for-byte (prefill_bucket keeps the
                     # kernel choice, padding rows stay masked)
                     bucket = fns["prefill_bucket"](suffix_len)
+                    if self._flash_pairs_of is not None:
+                        pairs, grid = self._flash_pairs_of(bucket)
+                        self._flash_pairs += pairs
+                        self._flash_grid_pairs += grid
                     if (stream.prompt_dev is not None and not replayed
                             and stream.resume_cache is None):
                         # zero-copy data plane: the prompt is already a

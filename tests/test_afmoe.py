@@ -209,7 +209,8 @@ def dense_window_attention(q, k, v, window):
 
 
 @pytest.mark.parametrize("window,block_q,block_k", [
-    (32, 64, 64), (100, 64, 128), (256, 128, 64), (1000, 64, 64)])
+    (32, 64, 64), (100, 64, 128), (256, 128, 64), (1000, 64, 64),
+    (100, 256, 64), (48, 32, 128), (66, 64, 64)])
 def test_flash_attention_window_matches_dense(window, block_q, block_k):
     rng = np.random.default_rng(5)
     t, h, d = 256, 4, 32

@@ -403,7 +403,8 @@ def dense_block_attention(q, k, v, block):
 
 
 @pytest.mark.parametrize("block,block_q,block_k", [
-    (4, 64, 64), (4, 64, 128), (8, 128, 64), (32, 64, 64), (1, 64, 64)])
+    (4, 64, 64), (4, 64, 128), (8, 128, 64), (32, 64, 64), (1, 64, 64),
+    (4, 256, 128), (4, 128, 256)])
 def test_flash_attention_block_causal_matches_dense(block, block_q, block_k):
     rng = np.random.default_rng(5)
     t, h, d = 256, 4, 32

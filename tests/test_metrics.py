@@ -181,6 +181,47 @@ def test_scheduler_families_are_all_exported():
                         model="llama_generate") == 4
 
 
+def test_a_flash_prefill_counts_its_block_pairs():
+    """One whole-prompt prefill of 128 positions through the paged
+    scheduler adds the flash kernel's block pairs to both families: under
+    32 x 64 tiles a layer-head's grid has 4 x 2 pairs, of which the
+    causal mask leaves 6 (the first two query blocks see no key of the
+    second key block); 2 layers x 8 heads."""
+    import dataclasses
+
+    from tpuserver.core import InferenceServer, InferRequest
+    from tpuserver.http_frontend import HttpFrontend
+    from tpuserver.models import llama
+    from tpuserver.models.llama_serving import LlamaGenerateModel
+
+    cfg = dataclasses.replace(llama.tiny(vocab=512), attn_impl="pallas",
+                              flash_block_q=32, flash_block_k=64)
+    core = InferenceServer([LlamaGenerateModel(
+        cfg=cfg, max_seq=256, max_slots=2)])
+    frontend = HttpFrontend(core, port=0).start()
+
+    def counts():
+        _, _, samples = parse_exposition(scrape(frontend.port))
+        return [sample_value(samples, "tpu_scheduler_flash_{}_total".format(
+            name), model="llama_generate") or 0 for name in ("pairs",
+                                                             "grid_pairs")]
+
+    try:
+        before = counts()
+        prompt = (np.arange(128) * 7 % 500).astype(np.int32)
+        tokens = list(core.infer_stream(InferRequest(
+            "llama_generate", inputs={"PROMPT_IDS": prompt,
+                                      "MAX_TOKENS": np.array([2], np.int32)})))
+        assert len(tokens) == 2
+        after = counts()
+    finally:
+        frontend.stop()
+        core.close()
+    layer_heads = cfg.n_layers * cfg.n_heads
+    assert [a - b for a, b in zip(after, before)] == [6 * layer_heads,
+                                                      8 * layer_heads]
+
+
 # -- replica + router: token counters, fleet aggregation, single source -----
 
 

@@ -264,7 +264,8 @@ def test_latent_decode_kernel_matches_dense_over_unequal_rows():
         np.testing.assert_allclose(got[r], want, atol=2e-5, err_msg=str(n))
 
 
-@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256)])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (128, 256),
+                                             (256, 128), (64, 256)])
 def test_flash_attention_with_a_value_size_of_its_own(block_q, block_k):
     """Keys of 24, values of 16 (the expanded form's 192 / 128)."""
     rng = np.random.default_rng(5)

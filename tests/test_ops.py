@@ -68,6 +68,105 @@ def test_flash_attention_block_divisibility_error():
         assert "divide" in str(e)
 
 
+def _seen(t, causal, window=None, block_causal=None):
+    """[t, t] bool, query i sees key j: the masks as the model defines
+    them, written apart from the schedule's arithmetic."""
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    if not causal:
+        return np.ones((t, t), bool)
+    seen = (j < (i // block_causal + 1) * block_causal if block_causal
+            else j <= i)
+    if window:
+        seen &= i - j < window
+    return seen
+
+
+@pytest.mark.parametrize("t,block_q,block_k,causal,window,block_causal", [
+    (128, 128, 128, True, None, None),      # one q-block, one pair
+    (1024, 256, 512, True, None, None),
+    (2048, 512, 256, True, None, None),
+    (8192, 256, 512, True, None, None),
+    (8192, 128, 256, True, 4096, None),     # Trinity's window layers
+    (1024, 128, 256, True, 100, None),      # a window of no whole blocks
+    (512, 256, 128, True, 1000, None),      # a window longer than T
+    (256, 64, 64, True, 66, None),          # a pair that sees ONE score
+    (1024, 128, 256, True, None, 4),        # SDAR's blocks of 4
+    (4096, 256, 128, True, None, 4),
+    (384, 128, 64, False, None, None),
+])
+def test_flash_schedule_is_the_live_block_pairs(t, block_q, block_k, causal,
+                                                window, block_causal):
+    """The kernel's grid is exactly the block pairs that hold a seen
+    score, each once, a q-block's k-blocks ascending; its first and last
+    pair flagged, and PARTIAL on exactly the pairs with a masked score."""
+    from tpuserver.ops import flash
+
+    q_blocks, k_blocks, flags = flash.flash_schedule(
+        t, t, block_q, block_k, causal, window, block_causal)
+    blocks = _seen(t, causal, window, block_causal).reshape(
+        t // block_q, block_q, t // block_k, block_k)
+    live, whole = blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
+    pairs = list(zip(q_blocks.tolist(), k_blocks.tolist()))
+    assert len(set(pairs)) == len(pairs)
+    assert set(pairs) == set(zip(*(a.tolist() for a in np.nonzero(live))))
+    assert np.all(np.diff(q_blocks) >= 0)
+    new_q = np.diff(q_blocks) != 0
+    assert np.all(np.diff(k_blocks)[~new_q] > 0)
+    first = np.r_[True, new_q]
+    last = np.r_[new_q, True]
+    assert np.array_equal((flags & flash.FIRST) != 0, first)
+    assert np.array_equal((flags & flash.LAST) != 0, last)
+    assert np.array_equal((flags & flash.PARTIAL) != 0,
+                          ~whole[q_blocks, k_blocks])
+
+
+@pytest.mark.parametrize("d,d_v,block_q,block_k", [
+    (64, 64, 256, 128),     # one q-block, bq > bk
+    (128, 128, 64, 128),    # bq < bk
+    (192, 128, 128, 64),    # the expanded latent form's head sizes
+])
+def test_flash_attention_head_sizes_match_dense(d, d_v, block_q, block_k):
+    """The served head sizes in interpret mode, causal, against dense
+    attention: a single q-block, and tiles either way round."""
+    rng = np.random.default_rng(7)
+    q, k = (rng.normal(size=(1, 256, 2, d)).astype(np.float32)
+            for _ in range(2))
+    v = rng.normal(size=(1, 256, 2, d_v)).astype(np.float32)
+    out = flash_attention(jnp.array(q), jnp.array(k), jnp.array(v),
+                          block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(np.asarray(out), _dense(q, k, v),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("fields,t,tiles", [
+    ({}, 2048, (512, 1024)),                       # Mistral's 128-wide heads
+    ({}, 1024, (512, 1024)),
+    ({}, 8192, (512, 1024)),
+    ({}, 1536, (512, 512)),                         # T does not divide by 1024
+    ({}, 384, (128, 128)),
+    ({}, 200, (None, None)),                        # dense path
+    ({"block_len": 4}, 4096, (256, 512)),           # a block diagonal
+    ({"d_head": 64}, 1024, (256, 512)),             # 64-wide heads
+    ({"d_head": 64}, 256, (256, 256)),
+    ({"mla": (128, 64)}, 8192, (512, 1024)),        # 192-wide expanded keys
+    ({"mla": (16, 8)}, 1024, (256, 512)),           # 24-wide
+    ({"flash_block_q": 32, "flash_block_k": 64}, 128, (32, 64)),
+])
+def test_flash_tiles_follow_the_length_and_the_head_width(fields, t, tiles):
+    """``_flash_blocks``: the preferred 512 x 1024 where T divides by it
+    and the keys are at least 128 wide, at most 256 x 512 for narrower
+    heads and block diagonals, smaller tiles where T asks for them."""
+    from tpuserver.models import llama
+
+    fields = dict(fields)
+    if "mla" in fields:
+        d_nope, d_rope = fields.pop("mla")
+        fields["mla"] = llama.MLAConfig(d_nope=d_nope, d_rope=d_rope)
+    cfg = llama.LlamaConfig(d_model=4096, n_heads=32, n_kv_heads=8,
+                            **fields)
+    assert llama._flash_blocks(t, cfg) == tiles
+
+
 def test_llama_forward_pallas_matches_xla():
     """The flagship model's single-shard forward agrees across attention
     implementations."""

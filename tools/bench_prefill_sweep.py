@@ -3,9 +3,9 @@
 The tuning companion to bench_full's config-5 rows: sweeps the arms that
 decide the serving defaults —
 
-- prefill: dense XLA vs the flash kernel at several (block_q, block_k)
-  tiles, plus an attention-IDENTITY arm (flash patched out) that
-  decomposes prefill time into "matmul+elementwise" vs "attention";
+- prefill: dense XLA vs the flash kernel at the configuration's tiles,
+  plus an attention-IDENTITY arm (flash patched out) that decomposes
+  prefill time into "matmul+elementwise" vs "attention";
 - decode: xla vs pallas vs auto at several contexts and chunk sizes,
   bf16 vs int8 weights.
 
@@ -121,21 +121,10 @@ def main():
         real_flash = ops_mod.flash_attention
         arms = [
             ("xla_dense", dict(attn_impl="xla"), None),
-            ("flash_128x128",
-             dict(attn_impl="pallas", flash_block_q=128,
-                  flash_block_k=128), None),
-            ("flash_256x256",
-             dict(attn_impl="pallas", flash_block_q=256,
-                  flash_block_k=256), None),
-            ("flash_512x512",
-             dict(attn_impl="pallas", flash_block_q=512,
-                  flash_block_k=512), None),
-            ("flash_256x512",
-             dict(attn_impl="pallas", flash_block_q=256,
-                  flash_block_k=512), None),
-            ("attention_identity",
-             dict(attn_impl="pallas", flash_block_q=128,
-                  flash_block_k=128),
+            # the flash kernel at the configuration's preferred tiles
+            # (tools/bench_kernels.py sweeps the kernel's tiles alone)
+            ("flash", dict(attn_impl="pallas"), None),
+            ("attention_identity", dict(attn_impl="pallas"),
              lambda q, k, v, **kw: q),
         ]
         for i, (name, overrides, patch) in enumerate(arms):
